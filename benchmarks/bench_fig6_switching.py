@@ -10,12 +10,10 @@ AUC on the next day after each training day.  Claims:
   C2c  pure async with the sync hyper-parameter set collapses.
 
 :func:`run_switching` is the GATED trajectory (suite ``switching`` in
-``benchmarks.run``): it spawns ``repro.launch.switch_driver`` as a
-4-host-device subprocess (the bench process's jax is already initialized
-single-device, so the mesh must live in a child) and reports the
-end-to-end switching rows — strained-cluster ``speedup_vs_sync`` (floor:
-may not shrink), ``switch_count`` and ``time_to_switch_steps`` (monotone:
-may not grow).  The sim clock is seeded-rng deterministic and independent
+``benchmarks.run``): it runs ``repro.launch.switch_driver`` over 4
+devices and reports the end-to-end switching rows — strained-cluster
+``speedup_vs_sync`` (floor: may not shrink), ``switch_count`` and
+``time_to_switch_steps`` (monotone: may not grow).  The sim clock is seeded-rng deterministic and independent
 of jitted-step wall time, so these columns gate exactly.
 """
 from __future__ import annotations
@@ -25,6 +23,7 @@ import os
 import subprocess
 import sys
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -36,6 +35,7 @@ from repro.data import make_clickstream
 from repro.models.recsys import init_recsys
 from repro.sim.cluster import ClusterSpec
 
+ROOT = Path(__file__).resolve().parents[1]
 CFG = CRITEO_DEEPFM
 MODES = ["gba", "hop_bs", "bsp", "hop_bw", "async", "async_setS"]
 
@@ -46,19 +46,27 @@ SWITCH_BATCHES = 240
 
 
 def _driver_json(plan: str) -> dict:
-    """One ``switch_driver`` subprocess run (auto + forced-sync legs on
-    the same plan); its last stdout line is the JSON result."""
+    """One ``switch_driver`` run (auto + forced-sync legs on the same
+    plan).  Where this process sees ``SWITCH_WORKERS`` devices (a 4-chip
+    host) it runs here, since this process holds the chips.  Otherwise a
+    child pinned to the CPU forces that many host devices; it never needs
+    the chip this process may hold.  The child's last stdout line is the
+    JSON result."""
+    argv = ["--workers", str(SWITCH_WORKERS),
+            "--batches", str(SWITCH_BATCHES), "--plan", plan,
+            "--mode", "auto", "--compare-sync", "--json"]
+    if jax.device_count() >= SWITCH_WORKERS:
+        from repro.launch import switch_driver
+        return switch_driver.main(argv)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = str(ROOT / "src") + os.pathsep + env.get(
+        "PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)          # the driver sets its own
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.switch_driver",
-         "--host-devices", str(SWITCH_WORKERS),
-         "--workers", str(SWITCH_WORKERS),
-         "--batches", str(SWITCH_BATCHES), "--plan", plan,
-         "--mode", "auto", "--compare-sync", "--json"],
-        capture_output=True, text=True, timeout=900, env=env)
+         "--host-devices", str(SWITCH_WORKERS), *argv],
+        capture_output=True, text=True, timeout=900, env=env, cwd=ROOT)
     if proc.returncode != 0:
         raise RuntimeError(
             f"switch_driver --plan {plan} failed:\n{proc.stderr[-2000:]}")

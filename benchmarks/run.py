@@ -192,6 +192,8 @@ def main() -> None:
                          "kernel/roofline rows (scripts/ci.sh)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_autoswitch, bench_convergence,
                             bench_decay_ablation,
                             bench_fig3_grad_distribution,

@@ -63,7 +63,7 @@ def abstract_mesh(m: int = AUDIT_M, axis: str = "data"):
     """Devices-free mesh: lets make_jaxpr trace shard_map'd steps at any
     worker count on a 1-CPU container."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh(((axis, m),))
+    return AbstractMesh((m,), (axis,))
 
 
 def probe_loss(params, batch):

@@ -14,7 +14,7 @@ provenance tag set drawn from a small lattice::
     ids        embedding-row indices
     param      optimizer state (params / accumulators / f32 master)
 
-and the interpreter walks every eqn — descending into ``pjit`` /
+and the interpreter walks every eqn — descending into ``jit`` /
 ``cond`` / ``scan`` / ``while`` / ``shard_map`` / ``custom_vjp`` /
 ``pallas_call`` sub-jaxprs — propagating tags by union plus three
 special transfer rules:
@@ -341,7 +341,7 @@ class _Interp:
         name = eqn.primitive.name
         params = eqn.params
 
-        if name in ("pjit", "closed_call", "core_call", "xla_call",
+        if name in ("jit", "closed_call", "core_call", "xla_call",
                     "custom_jvp_call", "custom_vjp_call",
                     "custom_vjp_call_jaxpr", "remat", "checkpoint",
                     "custom_lin", "remat2"):
@@ -398,10 +398,11 @@ class _Interp:
 
         if name == "shard_map":
             sub = params["jaxpr"]          # open Jaxpr
-            in_names = params.get("in_names", ())
+            in_specs = params["in_specs"]
             seeded = []
             for i, t in enumerate(ins):
-                split = i < len(in_names) and bool(in_names[i])
+                split = i < len(in_specs) and any(
+                    ax is not None for ax in in_specs[i])
                 seeded.append(t.drop_val() if split else t)
             return self.run(sub, (), seeded)
 
@@ -468,7 +469,7 @@ class _Interp:
         outs = [ref_env.get(v, EMPTY).drop_val()
                 for v in kvars[n_scalar + n_in:n_scalar + n_in + n_out]]
 
-        kname = str(params.get("name_and_src_info", ""))
+        kname = str(params.get("name") or "")
         if "quant" in kname and "dequant" not in kname:
             # the quantize kernel is the sanctioned residual producer:
             # only its payload-shaped f32 output carries the residual

@@ -38,7 +38,7 @@ def iter_eqns(jaxpr):
     """Depth-first walk over every eqn, descending into sub-jaxprs
     (pjit/closed_call/cond/scan/while/shard_map/custom_vjp/pallas_call)
     at their call site, so program order is preserved."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     closed = getattr(jaxpr, "jaxpr", None)
     if closed is not None and not isinstance(jaxpr, Jaxpr):

@@ -60,7 +60,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.gba import flat_buffer_push
 from repro.kernels.gba_apply import BLOCK_N
@@ -351,10 +350,10 @@ def make_sharded_apply(mesh: Mesh, layout: ShardedFlatLayout, *,
     from repro.kernels import ops
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P(axis), P(None, axis), P(), P(), P()),
         out_specs=(P(axis), P(axis)),
-        check_rep=False)
+        check_vma=False)
     def apply_shards(param_flat, accum_flat, grads, tokens, step, lr):
         return ops.gba_apply_flat(param_flat, accum_flat, grads, tokens,
                                   step, lr, iota=iota, eps=eps,

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.compression import CompressionPolicy
 from repro.core.flat_sharded import ShardedFlatLayout
@@ -59,10 +58,10 @@ def make_gba_psum_step(mesh: Mesh, loss_fn: Callable, optimizer,
     m = mesh.shape[axis]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P()),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     def grad_agg(params, batch, token, gstep):
         loss, g = jax.value_and_grad(loss_fn)(params, batch)
         w = threshold_decay(token.reshape(-1)[:1], gstep, iota)[0]
@@ -195,10 +194,10 @@ def make_gba_fused_psum_step(mesh: Mesh, loss_fn: Callable,
 
     if compress is None or not compress.stateful:
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
             out_specs=(P(axis), P(axis), P()),
-            check_rep=False)
+            check_vma=False)
         def step(param_flat, accum_flat, batch, token, gstep):
             params = gather_params(param_flat)
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -215,10 +214,10 @@ def make_gba_fused_psum_step(mesh: Mesh, loss_fn: Callable,
     wire_spec = {name: P(axis, None) for name in compress.state_names()}
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(), wire_spec),
         out_specs=(P(axis), P(axis), P(), wire_spec),
-        check_rep=False)
+        check_vma=False)
     def step(param_flat, accum_flat, batch, token, gstep, wire):
         params = gather_params(param_flat)
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)

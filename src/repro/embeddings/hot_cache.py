@@ -146,13 +146,15 @@ class HotIDCache:
 
 
 def fetch_rows(table: jnp.ndarray, ids: np.ndarray, *,
-               stream: StreamConfig | None = None) -> np.ndarray:
+               stream: StreamConfig | None = None,
+               dim: int | None = None) -> np.ndarray:
     """Fetch exact table rows through the DMA-streamed kernel: ids are
     shaped (n_pad, 1) so each output is a sum-pool over ONE element —
     i.e. the row itself.  The batch is padded to a power of two with the
     out-of-range sentinel id ``capacity`` (the kernel maps it to the
     no-DMA sentinel slot → zero row, sliced off here), bounding the set
-    of shapes the jitted kernel ever traces."""
+    of shapes the jitted kernel ever traces.  ``dim`` keeps the first
+    ``dim`` columns of a lane-dense table (``embedding_bag.lane_dense``)."""
     ids = np.asarray(ids).reshape(-1)
     n = ids.shape[0]
     s = stream or StreamConfig()
@@ -162,30 +164,33 @@ def fetch_rows(table: jnp.ndarray, ids: np.ndarray, *,
     rows = ops.pooled_lookup(jnp.asarray(padded), table,
                              block_v=s.block_v, block_d=s.block_d,
                              chunk_e=s.chunk_e, interpret=s.interpret)
-    return np.asarray(rows, np.float32)[:n]
+    return np.asarray(rows, np.float32)[:n, :dim]
 
 
 def cached_pooled_lookup(cache: HotIDCache | None, tbl: EmbeddingTable,
                          hashed_ids: np.ndarray, *,
                          version: int = 1,
-                         stream: StreamConfig | None = None) -> np.ndarray:
+                         stream: StreamConfig | None = None,
+                         dim: int | None = None) -> np.ndarray:
     """Sum-pooled lookup (B, F) -> (B, dim) through the hot-ID cache.
 
     Unique hit ids are served from the cache; misses fall through to
     :func:`fetch_rows` (the streamed kernel) and are inserted under
     ``version``.  A batch with zero unique misses performs ZERO kernel
     invocations.  Output is f32 numpy, bit-identical regardless of the
-    hit/miss mix (see module docstring)."""
+    hit/miss mix (see module docstring).  ``dim`` is the logical width
+    of a lane-dense ``tbl``."""
     ids = np.asarray(hashed_ids)
     B, F = ids.shape
     uniq, inv = np.unique(ids.reshape(-1), return_inverse=True)
     if cache is None:
-        rows = fetch_rows(tbl.table, uniq, stream=stream)
+        rows = fetch_rows(tbl.table, uniq, stream=stream, dim=dim)
     else:
         rows, found = cache.get_many(uniq)
         miss = ~found
         if miss.any():
-            fetched = fetch_rows(tbl.table, uniq[miss], stream=stream)
+            fetched = fetch_rows(tbl.table, uniq[miss], stream=stream,
+                                 dim=dim)
             rows[miss] = fetched
             cache.put_many(uniq[miss], fetched, version)
     return rows[inv].reshape(B, F, rows.shape[-1]).sum(axis=1,

@@ -4,15 +4,15 @@ Layout of the package:
 
 * ``embedding_bag``  — pooled lookup forward + **sorted-scatter** backward,
   both **DMA-streamed**: the (V, D) table and the sorted (E, D) gradient
-  rows live in HBM (``pltpu.ANY``) and move through double-buffered VMEM
+  rows live in HBM (``pl.ANY``) and move through double-buffered VMEM
   scratch blocks with ``pltpu.make_async_copy``, so VMEM residency is
   O(block_v * block_d + chunk_e * block_d) at any vocabulary size.  The
   B*F (id, row) pairs are sorted by id once, per-vocab-block segment
   boundaries come from a searchsorted, and the backward grid runs one
   program per disjoint (BLOCK_V, BLOCK_D) output tile — parallel,
   race-free, with per-ID contributor counts produced in the same pass
-  (Alg. 2 line 23).  The PR-1 whole-array-in-VMEM backward survives as
-  ``embedding_bag_grad_resident``, a bit-exactness regression oracle.
+  (Alg. 2 line 23).  ``embedding_bag_grad_resident`` keeps the sorted
+  arrays whole in VMEM: a bit-exactness oracle for the DMA transport.
 * ``gba_apply``      — the fused PS apply: token-decay aggregation over the
   flat (M, N_total) gradient buffer AND the Adagrad update in one VMEM
   pass; fed by ``repro.core.gba.FlatLayout`` (dense pytree leaves raveled
@@ -27,6 +27,11 @@ Layout of the package:
 
 Every kernel has an allclose oracle in ``ref`` and a parity sweep in
 ``tests/test_kernels.py`` (+ ``tests/test_embedding_stream.py`` for the
-streamed paths).  Remaining gap (ROADMAP "Open items"): the kernels have
-only been validated in interpret mode in this container, not on real TPUs.
+streamed paths), run in interpret mode on the CPU.
+``tests/test_tpu_compile.py`` compiles the main-path kernels at real
+widths for a TPU v5e, and ``chip_smoke.py`` runs ``gba_apply`` and the
+streamed embedding kernels compiled on a v5e chip against their ``ref``
+oracles (``gba_apply`` within 1.9e-9 on the params, the embedding
+forward within 3e-8, the backward and counts exact).  ``quantize`` and
+``fused_adagrad`` compile for the chip but no chip run has executed them.
 """

@@ -5,7 +5,7 @@ The compute hot-spot of the paper's recommendation workloads is the sparse
 module: per-batch gather of F rows per example (forward) and the per-ID
 normalized scatter-add (backward, Alg. 2 line 23).  Production vocabularies
 (10^6-10^8 hashed IDs) never fit a ``(V, D)`` VMEM block, so both kernels
-keep the big arrays in HBM (``pltpu.ANY`` memory space) and stream
+keep the big arrays in HBM (``pl.ANY`` memory space) and stream
 fixed-size blocks through a 2-deep VMEM scratch pipeline with
 ``pltpu.make_async_copy``: the DMA of block ``c+1`` overlaps the compute of
 block ``c``, and the VMEM footprint is O(block) — independent of the
@@ -17,30 +17,39 @@ vocabulary size ``V`` and the entry count ``E = B*F``.
   precomputed (block, chunk) step schedule drives one fused pipeline per
   ``BLOCK_D`` output tile: each step DMAs the next ``(BLOCK_V, BLOCK_D)``
   table tile (only when the block changes — empty blocks are never
-  streamed) and the next ``CHUNK_E`` entry chunk, then pools the current
-  chunk into the ``(B, BLOCK_D)`` accumulator as two MXU matmuls
-  (gather-as-matmul ``(E, V_blk) @ (V_blk, D_blk)`` followed by the
-  batch-row scatter ``(E, B)^T @ (E, D_blk)``) — no dynamic VMEM gathers.
-  The D tiling is the forward's only parallel grid axis; vocab blocks run
-  serially inside a program, hidden behind the DMA overlap — the kernel is
-  HBM-bound, so the pipeline, not program count, is the throughput lever
-  (the bench rows record ``grid_programs`` to keep this visible).
+  streamed) and the window holding the next ``CHUNK_E`` entries, then
+  pools the chunk into the ``(B, BLOCK_D)`` accumulator as two MXU
+  matmuls: the ``(B, V_blk)`` count matrix of the chunk's (batch row, id)
+  pairs, then ``counts @ tile`` — no dynamic VMEM gathers.  The D tiling
+  is the forward's only parallel grid axis; vocab blocks run serially
+  inside a program, hidden behind the DMA overlap.
 
 * backward: **sort-based segment reduce** over disjoint ``(BLOCK_V,
   BLOCK_D)`` output tiles (grid = vocab blocks x D blocks, race-free,
   fully parallel).  Each program streams its contiguous run of sorted
   (id, row) entries in ``CHUNK_E``-sized chunks through the double
   buffer and reduces them as a one-hot matmul
-  ``(CHUNK_E, BLOCK_V)^T @ (CHUNK_E, BLOCK_D)``; per-ID contributor
-  counts (Alg. 2 line 23) fall out of the same one-hot reduction.
+  ``(BLOCK_V, W) @ (W, BLOCK_D)``; per-ID contributor counts (Alg. 2
+  line 23) fall out of the same one-hot mask.
+
+TPU layout rules shape the streams.  The TPU DMAs HBM in whole 128-lane
+tiles, so ids travel lane-major (``(1, E)`` / ``(2, E)`` rows) and each
+chunk, which may start anywhere, moves as the lane-aligned window of
+``W = _window(CHUNK_E)`` entries that holds it, masked to the chunk.  D
+tiles are whole lane tiles too: Mosaic refuses a ``(BLOCK_V, 16)`` slice
+of an HBM table, so a narrow table (criteo's D=16) is padded to 128 lanes
+on every forward call, an O(V) copy until tables are stored lane-dense.
+The one-hot matmuls run at HIGHEST precision, so the MXU moves f32 table
+values exactly.
 
 Batch rows the caller padded (and any other out-of-range id) are mapped to
 a sentinel id ``>= V_pad`` that sorts past the last block boundary, so they
-issue no DMA traffic at all — previously they gathered row 0.
+issue no DMA traffic at all.
 
-``embedding_bag_grad_resident`` keeps the PR-1 whole-array-in-VMEM
-backward as a regression oracle: the streamed kernel reproduces it
-bit-for-bit on the old (VMEM-sized) configs.
+``embedding_bag_grad_resident`` keeps the whole sorted arrays in VMEM and
+shares the streamed kernel's windows and arithmetic: a regression oracle
+for the DMA transport, which the streamed kernel reproduces bit-for-bit on
+VMEM-sized configs.
 """
 from __future__ import annotations
 
@@ -59,6 +68,11 @@ from repro.kernels.launch_meta import (ANY, BlockMeta, LaunchMeta,
 BLOCK_V = 512      # vocab rows per streamed table tile / backward out block
 CHUNK_E = 256      # sorted (id, row) entries consumed per pipeline step
 BLOCK_D = 128      # embedding columns per output tile (wide-D streaming)
+LANE = 128         # TPU lane width: HBM DMA windows are whole lane tiles
+
+# one-hot matmuls must move table values exactly, so f32 contractions run
+# at full precision on the MXU (a no-op in interpret mode)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _round_up(x: int, m: int) -> int:
@@ -66,32 +80,50 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _block_d(d: int, block_d: int) -> int:
-    """Effective D tile: no padding for narrow tables (keeps the streamed
-    backward bit-identical to the resident kernel), BLOCK_D tiles else."""
-    return d if d <= block_d else block_d
+    """Effective D tile: ``min(d, block_d)`` rounded up to whole 128-lane
+    tiles.  Mosaic refuses HBM slices narrower than a lane tile, so a
+    narrow table (criteo's D=16) is padded to one tile — in interpret mode
+    too, so the CPU runs the chip's geometry."""
+    return _round_up(min(d, block_d), LANE)
+
+
+def lane_dense(table: jax.Array) -> jax.Array:
+    """``table`` with its minor axis padded to whole lane tiles: the
+    layout the kernels stream.  A caller that looks up the same table many
+    times pads it once here, and the kernels then skip their per-call pad;
+    the extra output columns are zero."""
+    d = table.shape[1]
+    return jnp.pad(table, ((0, 0), (0, _round_up(d, LANE) - d)))
+
+
+def _window(chunk_e: int) -> int:
+    """Entries per DMA window: a ``chunk_e`` chunk starts anywhere in the
+    sorted stream, so each step moves the lane-aligned window holding it
+    and masks the entries outside the chunk."""
+    return _round_up(chunk_e + LANE - 1, LANE)
 
 
 def stream_vmem_bytes(d: int, *, table_itemsize: int = 4,
                       row_itemsize: int = 4, block_v: int = BLOCK_V,
-                      block_d: int = BLOCK_D, chunk_e: int = CHUNK_E
-                      ) -> dict[str, int]:
+                      block_d: int = BLOCK_D, chunk_e: int = CHUNK_E) -> dict[str, int]:
     """Derived VMEM residency of the streamed pipelines (double-buffered
     scratch only — the V- and E-sized arrays stay in HBM).  This is the
     block-bounded footprint the bench rows record as ``vmem_bytes``."""
     bd = _block_d(d, block_d)
+    w = _window(chunk_e)
     return {
-        # 2 table tiles + 2 (id, batch_row) entry chunks
-        "fwd": 2 * block_v * bd * table_itemsize + 2 * 2 * chunk_e * 4,
-        # 2 gradient-row chunks + 2 id chunks
-        "bwd": 2 * chunk_e * bd * row_itemsize + 2 * chunk_e * 4,
+        # 2 table tiles + 2 (id, batch_row) entry windows
+        "fwd": 2 * block_v * bd * table_itemsize + 2 * 2 * w * 4,
+        # 2 gradient-row windows + 2 id windows
+        "bwd": 2 * w * bd * row_itemsize + 2 * w * 4,
         "block_d": bd,
     }
 
 
 def _entry_pad(e: int, chunk_e: int) -> int:
-    """Padded sorted-entry length: ``chunk_e``-wide slices never run off
-    the end (mirrors ``_sorted_entries``)."""
-    return e + ((-e) % chunk_e) + chunk_e
+    """Padded sorted-entry length: the DMA window of the last chunk never
+    runs off the end (mirrors ``_sorted_entries``)."""
+    return _round_up(e, LANE) + _window(chunk_e)
 
 
 def fwd_launch_meta(b: int, f: int, v: int, d: int, table_dtype=jnp.float32,
@@ -105,6 +137,7 @@ def fwd_launch_meta(b: int, f: int, v: int, d: int, table_dtype=jnp.float32,
     d_pad = _round_up(d, bd)
     v_rows = max(v, block_v)
     e_pad = _entry_pad(b * f, chunk_e)
+    w = _window(chunk_e)
     bp = _round_up(b, 8)
     vm = stream_vmem_bytes(d, table_itemsize=jnp.dtype(table_dtype).itemsize,
                            block_v=block_v, block_d=block_d, chunk_e=chunk_e)
@@ -123,7 +156,7 @@ def fwd_launch_meta(b: int, f: int, v: int, d: int, table_dtype=jnp.float32,
         ),
         scratch=(
             ScratchMeta("tile_buf", (2, block_v, bd), table_dtype),
-            ScratchMeta("ent_buf", (2, 2, chunk_e), jnp.int32),
+            ScratchMeta("ent_buf", (2, 2, w), jnp.int32),
         ),
         declared_vmem_bytes=vm["fwd"],
         vmem_counted=("tile_buf", "ent_buf"),
@@ -136,11 +169,13 @@ def bwd_launch_meta(b: int, f: int, v: int, d: int, row_dtype=jnp.float32,
     """Static launch geometry of the sorted-scatter backward: grid =
     (vocab blocks x D blocks), each program owns one disjoint
     (BLOCK_V, BLOCK_D) output tile and streams its sorted run through the
-    double-buffered chunk scratch."""
+    double-buffered window scratch.  Counts come out lane-major, one
+    ``(1, BLOCK_V)`` row per vocab block."""
     bd = _block_d(d, block_d)
     d_pad = _round_up(d, bd)
     cap_pad = _round_up(v, block_v)
     e_pad = _entry_pad(b * f, chunk_e)
+    w = _window(chunk_e)
     vm = stream_vmem_bytes(d, row_itemsize=jnp.dtype(row_dtype).itemsize,
                            block_v=block_v, block_d=block_d, chunk_e=chunk_e)
     return LaunchMeta(
@@ -148,19 +183,20 @@ def bwd_launch_meta(b: int, f: int, v: int, d: int, row_dtype=jnp.float32,
         grid=(cap_pad // block_v, d_pad // bd),
         num_scalar_prefetch=1,
         inputs=(
-            BlockMeta("sorted_ids", (e_pad,), jnp.int32, memory_space=ANY),
+            BlockMeta("sorted_ids", (1, e_pad), jnp.int32, memory_space=ANY),
             BlockMeta("sorted_rows", (e_pad, d_pad), row_dtype,
                       memory_space=ANY),
         ),
         outputs=(
             BlockMeta("gtable", (cap_pad, d_pad), jnp.float32,
                       (block_v, bd), lambda i, j, *_: (i, j)),
-            BlockMeta("counts", (cap_pad,), jnp.float32, (block_v,),
-                      lambda i, j, *_: (i,)),
+            BlockMeta("counts", (cap_pad // block_v, 1, block_v),
+                      jnp.float32, (1, 1, block_v),
+                      lambda i, j, *_: (i, 0, 0)),
         ),
         scratch=(
-            ScratchMeta("ids_buf", (2, chunk_e), jnp.int32),
-            ScratchMeta("rows_buf", (2, chunk_e, bd), row_dtype),
+            ScratchMeta("ids_buf", (2, 1, w), jnp.int32),
+            ScratchMeta("rows_buf", (2, w, bd), row_dtype),
         ),
         declared_vmem_bytes=vm["bwd"],
         vmem_counted=("ids_buf", "rows_buf"),
@@ -176,7 +212,7 @@ def _sorted_entries(ids: jax.Array, capacity: int, block_v: int,
     """Bucket the B*F flat ids into ``block_v``-row sorted runs.
 
     Returns ``(sorted_ids, order, offsets, cap_pad, nvb)``: ids sorted and
-    padded so ``chunk_e``-wide slices never run off the end, the argsort
+    padded so every chunk's DMA window stays in bounds, the argsort
     permutation (for gathering per-entry payloads), and per-block run
     boundaries.  Out-of-range ids — including any batch padding the caller
     added — map to the sentinel ``cap_pad``, which sorts past the last
@@ -191,10 +227,26 @@ def _sorted_entries(ids: jax.Array, capacity: int, block_v: int,
     nvb = cap_pad // block_v
     boundaries = jnp.arange(nvb + 1, dtype=jnp.int32) * block_v
     offsets = jnp.searchsorted(sorted_ids, boundaries).astype(jnp.int32)
-    e_pad = e + ((-e) % chunk_e) + chunk_e
-    sorted_ids = jnp.pad(sorted_ids, (0, e_pad - e),
+    sorted_ids = jnp.pad(sorted_ids, (0, _entry_pad(e, chunk_e) - e),
                          constant_values=cap_pad)
     return sorted_ids, order, offsets, cap_pad, nvb
+
+
+def _chunk_window(p0, end, chunk_e: int, w: int):
+    """``(start, mask)`` of the lane-aligned window holding the chunk
+    ``[p0, min(p0 + chunk_e, end))``: ``mask`` is ``(1, w)``, true on the
+    window lanes that belong to the chunk."""
+    start = pl.multiple_of(p0 - p0 % LANE, LANE)
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    return start, (pos >= p0) & (pos < jnp.minimum(p0 + chunk_e, end))
+
+
+def _cols(ref, j, bd: int):
+    """Column window of D tile ``j``: the whole minor axis when one tile
+    covers it, else a tile-aligned slice."""
+    if bd == ref.shape[-1]:
+        return slice(None)
+    return pl.ds(pl.multiple_of(j * bd, bd), bd)
 
 
 # ---------------------------------------------------------------------------
@@ -215,12 +267,18 @@ def _fwd_kernel(nsteps_ref, offsets_ref, sblk_ref, sp0_ref,
     table_hbm:   (V_pad, D_pad) HBM
     out_ref:     (B_pad, BLOCK_D) VMEM output tile
     tile_buf:    (2, BLOCK_V, BLOCK_D) VMEM — double-buffered table tiles
-    ent_buf:     (2, 2, CHUNK_E) VMEM       — double-buffered entry chunks
+    ent_buf:     (2, 2, W) VMEM             — double-buffered entry windows
+
+    Each step pools its chunk as ``C @ tile`` where ``C[b, v]`` counts the
+    chunk's entries of batch row ``b`` and tile-local id ``v`` (an NT
+    matmul of the two one-hot masks), so every operand stays lane-major.
     """
     j = pl.program_id(0)
     n = nsteps_ref[0]
     bp, bd = out_ref.shape
     v_rows = table_hbm.shape[0]
+    w = ent_buf.shape[-1]
+    cols = _cols(table_hbm, j, bd)
 
     def tile_start(blk):
         # the last block's tile is clamped instead of padding the table:
@@ -230,36 +288,36 @@ def _fwd_kernel(nsteps_ref, offsets_ref, sblk_ref, sp0_ref,
 
     def tile_dma(slot, blk):
         return pltpu.make_async_copy(
-            table_hbm.at[pl.ds(tile_start(blk), block_v), pl.ds(j * bd, bd)],
+            table_hbm.at[pl.ds(tile_start(blk), block_v), cols],
             tile_buf.at[slot], tile_sem.at[slot])
 
-    def ent_dma(slot, p0):
+    def ent_dma(slot, s):
+        p0 = sp0_ref[s]
+        start = pl.multiple_of(p0 - p0 % LANE, LANE)
         return pltpu.make_async_copy(
-            entries_hbm.at[:, pl.ds(p0, chunk_e)],
+            entries_hbm.at[:, pl.ds(start, w)],
             ent_buf.at[slot], ent_sem.at[slot])
 
     @pl.when(n > 0)
     def _():
         tile_dma(0, sblk_ref[0]).start()
-        ent_dma(0, sp0_ref[0]).start()
+        ent_dma(0, 0).start()
 
-    vids = jax.lax.broadcasted_iota(jnp.int32, (chunk_e, block_v), 1)
-    brows = jax.lax.broadcasted_iota(jnp.int32, (chunk_e, bp), 1)
-    pos_iota = jax.lax.broadcasted_iota(jnp.int32, (chunk_e, 1), 0)[:, 0]
+    vids = jax.lax.broadcasted_iota(jnp.int32, (block_v, w), 0)
+    brows = jax.lax.broadcasted_iota(jnp.int32, (bp, w), 0)
 
     def body(s, carry):
         acc, tslot, prev_blk = carry
         blk = sblk_ref[s]
-        p0 = sp0_ref[s]
         end = offsets_ref[blk + 1]
         load = blk != prev_blk
         tslot = jnp.where(load, 1 - tslot, tslot)
 
-        # prefetch step s+1 while step s computes: the entry chunk always,
+        # prefetch step s+1 while step s computes: the entry window always,
         # the table tile only when s+1 crosses into a new vocab block
         @pl.when(s + 1 < n)
         def _():
-            ent_dma((s + 1) % 2, sp0_ref[s + 1]).start()
+            ent_dma((s + 1) % 2, s + 1).start()
 
             @pl.when(sblk_ref[s + 1] != blk)
             def _():
@@ -268,22 +326,20 @@ def _fwd_kernel(nsteps_ref, offsets_ref, sblk_ref, sp0_ref,
         @pl.when(load)
         def _():
             tile_dma(tslot, blk).wait()
-        ent_dma(s % 2, p0).wait()
+        ent_dma(s % 2, s).wait()
 
-        idx = ent_buf[s % 2, 0, :] - tile_start(blk)     # tile-local ids
-        brow = ent_buf[s % 2, 1, :]
-        valid = (p0 + pos_iota) < end
-        onehot_v = ((idx[:, None] == vids)
-                    & valid[:, None]).astype(jnp.float32)  # (E, V_blk)
-        gathered = jax.lax.dot_general(                    # gather-as-matmul
-            onehot_v, tile_buf[tslot].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (E, D_blk)
-        onehot_b = ((brow[:, None] == brows)
-                    & valid[:, None]).astype(jnp.float32)  # (E, B)
-        acc = acc + jax.lax.dot_general(
-            onehot_b, gathered, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (B, D_blk)
+        _, valid = _chunk_window(sp0_ref[s], end, chunk_e, w)
+        ent = ent_buf[s % 2]                               # (2, W)
+        idx = ent[0:1, :] - tile_start(blk)                # tile-local ids
+        onehot_v = ((idx == vids) & valid).astype(jnp.float32)   # (V_blk, W)
+        onehot_b = ((ent[1:2, :] == brows) & valid).astype(jnp.float32)
+        counts = jax.lax.dot_general(                      # (B, V_blk)
+            onehot_b, onehot_v, (((1,), (1,)), ((), ())), precision=_EXACT,
+            preferred_element_type=jnp.float32)
+        acc = acc + jax.lax.dot_general(                   # (B, D_blk)
+            counts, tile_buf[tslot].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), precision=_EXACT,
+            preferred_element_type=jnp.float32)
         return acc, tslot, blk
 
     acc, _, _ = jax.lax.fori_loop(
@@ -303,7 +359,7 @@ def _embedding_bag_streamed(ids: jax.Array, table: jax.Array, *,
     d_pad = _round_up(d, bd)
     # tables keep their HBM layout: the last tile's DMA start is clamped in
     # the kernel, so padding is only needed for sub-block tables (rows) and
-    # wide non-multiple D (cols) — never for the production V >> block_v
+    # D that is not a whole number of tiles (cols)
     row_pad = block_v - v if v < block_v else 0
     if row_pad or d_pad != d:
         table = jnp.pad(table, ((0, row_pad), (0, d_pad - d)))
@@ -358,7 +414,7 @@ def embedding_bag(ids: jax.Array, table: jax.Array, *,
     """ids: (B, F) int32, table: (V, D) -> pooled (B, D).
 
     The table stays in HBM; VMEM holds 2 ``(block_v, block_d)`` tiles and
-    2 ``chunk_e``-entry chunks regardless of V (module docstring)."""
+    2 entry windows regardless of V (module docstring)."""
     return _embedding_bag_streamed(
         ids, table, block_v=block_v or BLOCK_V, block_d=block_d or BLOCK_D,
         chunk_e=chunk_e or CHUNK_E, interpret=runtime.resolve(interpret))
@@ -383,44 +439,59 @@ def _sorted_grad_rows(ids: jax.Array, grad_out: jax.Array, capacity: int,
     return sorted_ids, rows, offsets, cap_pad, nvb
 
 
+def _reduce_window(acc, cnt, ids, rows, valid, vids):
+    """Fold one window into the ``(BLOCK_V, D)`` segment sum and the
+    ``(1, BLOCK_V)`` contributor counts, as one-hot matmuls.
+
+    ids: (1, W) sorted ids; rows: (W, D) their gradient rows; valid: (1, W)
+    chunk mask; vids: (BLOCK_V, W) the output tile's global ids."""
+    onehot = ((ids == vids) & valid).astype(jnp.float32)       # (V, W)
+    acc = acc + jax.lax.dot_general(
+        onehot, rows.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=_EXACT, preferred_element_type=jnp.float32)  # (V, D)
+    cnt = cnt + jax.lax.dot_general(
+        jnp.ones_like(valid, jnp.float32), onehot, (((1,), (1,)), ((), ())),
+        precision=_EXACT, preferred_element_type=jnp.float32)  # (1, V)
+    return acc, cnt
+
+
 def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
                 ids_buf, rows_buf, ids_sem, rows_sem, *,
                 block_v: int, chunk_e: int):
     """Segment reduce for one (vocab block, D block) output tile.
 
     offsets_ref: (nvb+1,) SMEM — run boundaries in the sorted arrays
-    ids_hbm:     (E_pad,) HBM  — sorted ids
+    ids_hbm:     (1, E_pad) HBM — sorted ids
     rows_hbm:    (E_pad, D_pad) HBM — gradient rows in sorted-id order
     gtable_ref:  (BLOCK_V, BLOCK_D) VMEM output tile owned by this program
-    counts_ref:  (BLOCK_V,) contributor counts (recomputed per D block —
-                 every D block of a vocab block derives the same values)
-    ids_buf:     (2, CHUNK_E) / rows_buf: (2, CHUNK_E, BLOCK_D) —
-                 double-buffered chunk pipeline
+    counts_ref:  (1, 1, BLOCK_V) contributor counts (recomputed per D
+                 block — every D block of a vocab block derives the same)
+    ids_buf:     (2, 1, W) / rows_buf: (2, W, BLOCK_D) — double-buffered
+                 window pipeline
     """
     i = pl.program_id(0)
     j = pl.program_id(1)
     start = offsets_ref[i]
     end = offsets_ref[i + 1]
     bd = gtable_ref.shape[1]
+    w = ids_buf.shape[-1]
+    cols = _cols(rows_hbm, j, bd)
     nchunks = (end - start + chunk_e - 1) // chunk_e
 
     def dmas(slot, c):
-        p0 = start + c * chunk_e
+        w0, _ = _chunk_window(start + c * chunk_e, end, chunk_e, w)
         return (
-            pltpu.make_async_copy(ids_hbm.at[pl.ds(p0, chunk_e)],
+            pltpu.make_async_copy(ids_hbm.at[:, pl.ds(w0, w)],
                                   ids_buf.at[slot], ids_sem.at[slot]),
-            pltpu.make_async_copy(
-                rows_hbm.at[pl.ds(p0, chunk_e), pl.ds(j * bd, bd)],
-                rows_buf.at[slot], rows_sem.at[slot]))
+            pltpu.make_async_copy(rows_hbm.at[pl.ds(w0, w), cols],
+                                  rows_buf.at[slot], rows_sem.at[slot]))
 
     @pl.when(nchunks > 0)
     def _():
         for dma in dmas(0, 0):
             dma.start()
 
-    vids = i * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (chunk_e, block_v), 1)
-    pos_iota = jax.lax.broadcasted_iota(jnp.int32, (chunk_e, 1), 0)[:, 0]
+    vids = i * block_v + jax.lax.broadcasted_iota(jnp.int32, (block_v, w), 0)
 
     def body(c, carry):
         acc, cnt = carry
@@ -433,23 +504,16 @@ def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
 
         for dma in dmas(cur, c):
             dma.wait()
-        idx = ids_buf[cur]                                   # (CHUNK_E,)
-        rows = rows_buf[cur].astype(jnp.float32)
-        valid = (start + c * chunk_e + pos_iota) < end
-        onehot = ((idx[:, None] == vids)
-                  & valid[:, None]).astype(jnp.float32)      # (E, V)
-        acc = acc + jax.lax.dot_general(
-            onehot, rows, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (V, D)
-        cnt = cnt + jnp.sum(onehot, axis=0)
-        return acc, cnt
+        _, valid = _chunk_window(start + c * chunk_e, end, chunk_e, w)
+        return _reduce_window(acc, cnt, ids_buf[cur], rows_buf[cur], valid,
+                              vids)
 
     acc, cnt = jax.lax.fori_loop(
         0, nchunks, body,
         (jnp.zeros((block_v, bd), jnp.float32),
-         jnp.zeros((block_v,), jnp.float32)))
+         jnp.zeros((1, block_v), jnp.float32)))
     gtable_ref[...] = acc
-    counts_ref[...] = cnt
+    counts_ref[0] = cnt
 
 
 @functools.partial(
@@ -483,11 +547,11 @@ def _embedding_bag_grad_streamed(ids: jax.Array, grad_out: jax.Array,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((cap_pad, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((cap_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((nvb, 1, block_v), jnp.float32),
         ],
         interpret=interpret,
-    )(offsets, sorted_ids, sorted_rows)
-    return gtable[:capacity, :d], counts[:capacity]
+    )(offsets, sorted_ids.reshape(1, -1), sorted_rows)
+    return gtable[:capacity, :d], counts.reshape(-1)[:capacity]
 
 
 def embedding_bag_grad(ids: jax.Array, grad_out: jax.Array, capacity: int,
@@ -501,7 +565,7 @@ def embedding_bag_grad(ids: jax.Array, grad_out: jax.Array, capacity: int,
     ids: (B, F); grad_out: (B, D) -> (grad_table (V, D), counts (V,)).
 
     Sort once, then stream disjoint segments through the double-buffered
-    chunk pipeline in parallel over (vocab block x D block) — see the
+    window pipeline in parallel over (vocab block x D block) — see the
     module docstring for the design."""
     return _embedding_bag_grad_streamed(
         ids, grad_out, capacity, block_v=block_v or BLOCK_V,
@@ -515,38 +579,28 @@ def embedding_bag_grad(ids: jax.Array, grad_out: jax.Array, capacity: int,
 
 def _bwd_kernel_resident(offsets_ref, ids_ref, rows_ref, gtable_ref,
                          counts_ref):
-    """PR-1 segment reduce: the whole sorted (E_pad, D) array sits in VMEM
-    via a full-array BlockSpec (only viable for VMEM-sized configs)."""
+    """Resident segment reduce: the whole sorted ``(E_pad, D)`` array sits in
+    VMEM via a full-array BlockSpec (only viable for VMEM-sized configs).
+    Same windows and arithmetic as ``_bwd_kernel``; only the transport
+    differs."""
     i = pl.program_id(0)
-    v0 = i * BLOCK_V
     start = offsets_ref[i]
     end = offsets_ref[i + 1]
-    d = rows_ref.shape[1]
-    vids = v0 + jax.lax.broadcasted_iota(jnp.int32, (CHUNK_E, BLOCK_V), 1)
+    w = _window(CHUNK_E)
+    vids = i * BLOCK_V + jax.lax.broadcasted_iota(jnp.int32, (BLOCK_V, w), 0)
 
     def body(c, carry):
-        acc, cnt = carry
-        p0 = start + c * CHUNK_E
-        idx = ids_ref[pl.ds(p0, CHUNK_E)]                     # (CHUNK_E,)
-        rows = rows_ref[pl.ds(p0, CHUNK_E), :].astype(jnp.float32)
-        pos = p0 + jax.lax.broadcasted_iota(jnp.int32, (CHUNK_E, 1),
-                                            0)[:, 0]
-        valid = pos < end
-        onehot = ((idx[:, None] == vids)
-                  & valid[:, None]).astype(jnp.float32)       # (E, V)
-        acc = acc + jax.lax.dot_general(
-            onehot, rows, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (V, D)
-        cnt = cnt + jnp.sum(onehot, axis=0)
-        return acc, cnt
+        w0, valid = _chunk_window(start + c * CHUNK_E, end, CHUNK_E, w)
+        return _reduce_window(*carry, ids_ref[:, pl.ds(w0, w)],
+                              rows_ref[pl.ds(w0, w), :], valid, vids)
 
     nchunks = (end - start + CHUNK_E - 1) // CHUNK_E
     acc, cnt = jax.lax.fori_loop(
         0, nchunks, body,
-        (jnp.zeros((BLOCK_V, d), jnp.float32),
-         jnp.zeros((BLOCK_V,), jnp.float32)))
+        (jnp.zeros((BLOCK_V, rows_ref.shape[1]), jnp.float32),
+         jnp.zeros((1, BLOCK_V), jnp.float32)))
     gtable_ref[...] = acc
-    counts_ref[...] = cnt
+    counts_ref[0] = cnt
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))
@@ -554,8 +608,9 @@ def _embedding_bag_grad_resident(ids: jax.Array, grad_out: jax.Array,
                                  capacity: int, *, interpret: bool
                                  ) -> tuple[jax.Array, jax.Array]:
     d = grad_out.shape[1]
+    d_pad = _round_up(d, LANE)              # the streamed kernel's rows
     sorted_ids, sorted_rows, offsets, cap_pad, nvb = _sorted_grad_rows(
-        ids, grad_out, capacity, BLOCK_V, CHUNK_E, d)
+        ids, grad_out, capacity, BLOCK_V, CHUNK_E, d_pad)
     e_pad = sorted_ids.shape[0]
 
     gtable, counts = pl.pallas_call(
@@ -564,21 +619,21 @@ def _embedding_bag_grad_resident(ids: jax.Array, grad_out: jax.Array,
             num_scalar_prefetch=1,
             grid=(nvb,),
             in_specs=[
-                pl.BlockSpec((e_pad,), lambda i, *_: (0,)),
-                pl.BlockSpec((e_pad, d), lambda i, *_: (0, 0)),
+                pl.BlockSpec((1, e_pad), lambda i, *_: (0, 0)),
+                pl.BlockSpec((e_pad, d_pad), lambda i, *_: (0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((BLOCK_V, d), lambda i, *_: (i, 0)),
-                pl.BlockSpec((BLOCK_V,), lambda i, *_: (i,)),
+                pl.BlockSpec((BLOCK_V, d_pad), lambda i, *_: (i, 0)),
+                pl.BlockSpec((1, 1, BLOCK_V), lambda i, *_: (i, 0, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((cap_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((cap_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((cap_pad, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((nvb, 1, BLOCK_V), jnp.float32),
         ],
         interpret=interpret,
-    )(offsets, sorted_ids, sorted_rows)
-    return gtable[:capacity], counts[:capacity]
+    )(offsets, sorted_ids.reshape(1, -1), sorted_rows)
+    return gtable[:capacity, :d], counts.reshape(-1)[:capacity]
 
 
 def embedding_bag_grad_resident(ids: jax.Array, grad_out: jax.Array,

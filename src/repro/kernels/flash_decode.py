@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import runtime
 from repro.kernels.launch_meta import (BlockMeta, LaunchMeta, ScratchMeta,
                                        block_specs, scratch_shapes)
 
@@ -67,17 +68,6 @@ def launch_meta(b: int, l: int, kv: int, g: int, hd: int,
     )
 
 
-def _compiler_params():
-    """jax renamed TPUCompilerParams -> CompilerParams across versions;
-    fall back to no params (compiler defaults) rather than crashing when
-    neither name exists."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    return cls(dimension_semantics=("parallel", "arbitrary"))
-
-
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             *, blk: int):
     j = pl.program_id(1)
@@ -112,12 +102,16 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         o_ref[0] = (acc_ref[...] / l_ref[...][..., None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
-                 *, interpret: bool = True) -> jax.Array:
+                 *, interpret: bool | None = None) -> jax.Array:
     """q: (B, KV, G, hd) one-token queries grouped by kv head;
     k/v: (B, L, KV, hd) cache; pos: scalar int32 (last valid index).
     Returns (B, KV, G, hd)."""
+    return _flash_decode(q, k, v, pos, interpret=runtime.resolve(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _flash_decode(q, k, v, pos, *, interpret: bool) -> jax.Array:
     B, KV, G, hd = q.shape
     L = k.shape[1]
     blk = min(BLOCK_L, L)
@@ -133,7 +127,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
             scratch_shapes=scratch_shapes(meta.scratch),
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(pos, jnp.int32).reshape(1), q, k, v)
     return out

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import runtime
 from repro.kernels.launch_meta import (BlockMeta, LaunchMeta, block_specs,
                                        _round_up_static)
 
@@ -71,11 +72,17 @@ def _kernel(lr_ref, param_ref, grad_ref, accum_ref, new_param_ref,
     new_accum_ref[...] = a
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def fused_adagrad(param: jax.Array, grad: jax.Array, accum: jax.Array,
                   lr: jax.Array, *, eps: float = 1e-10,
-                  interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                  interpret: bool | None = None
+                  ) -> tuple[jax.Array, jax.Array]:
     """1-D fused update.  param/grad/accum: (N,) -> (new_param, new_accum)."""
+    return _fused_adagrad(param, grad, accum, lr, eps=eps,
+                          interpret=runtime.resolve(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _fused_adagrad(param, grad, accum, lr, *, eps: float, interpret: bool):
     n = param.shape[0]
     pad = (-n) % BLOCK
     if pad:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import runtime
 from repro.kernels.launch_meta import (BlockMeta, LaunchMeta, block_specs,
                                        _round_up_static)
 
@@ -61,22 +62,28 @@ def launch_meta(d: int, m: int, dtype=jnp.float32) -> LaunchMeta:
 
 
 def _kernel(tokens_ref, step_ref, iota_ref, grads_ref, out_ref):
-    """grads_ref: (M, BLOCK_D) VMEM block; tokens/step/iota in SMEM."""
+    """grads_ref: (M, BLOCK_D) VMEM block; tokens/step/iota in SMEM, read
+    as scalars (the scalar core cannot load SMEM vectors)."""
     m = grads_ref.shape[0]
-    tokens = tokens_ref[...]                       # (M,) int32
-    step = step_ref[0]
-    iota = iota_ref[0]
-    keep = (step - tokens) <= iota                 # Eq. (1)
-    w = keep.astype(jnp.float32) / jnp.float32(m)
-    g = grads_ref[...].astype(jnp.float32)         # (M, BLOCK_D)
-    out_ref[...] = jnp.sum(g * w[:, None], axis=0).astype(out_ref.dtype)
+    g = None
+    for k in range(m):
+        keep = (step_ref[0] - tokens_ref[k]) <= iota_ref[0]   # Eq. (1)
+        w = keep.astype(jnp.float32) / jnp.float32(m)
+        row = grads_ref[k, :].astype(jnp.float32) * w
+        g = row if g is None else g + row
+    out_ref[...] = g.astype(out_ref.dtype)
+
+
+def gba_aggregate(grads: jax.Array, tokens: jax.Array, step: jax.Array,
+                  *, iota: int, interpret: bool | None = None) -> jax.Array:
+    """grads: (M, D) -> (D,) decayed mean.  ``interpret=None`` resolves
+    through ``repro.kernels.runtime``."""
+    return _gba_aggregate(grads, tokens, step, iota=iota,
+                          interpret=runtime.resolve(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("iota", "interpret"))
-def gba_aggregate(grads: jax.Array, tokens: jax.Array, step: jax.Array,
-                  *, iota: int, interpret: bool = True) -> jax.Array:
-    """grads: (M, D) -> (D,) decayed mean.  ``interpret=True`` runs the
-    kernel body on CPU (this container); pass False on real TPUs."""
+def _gba_aggregate(grads, tokens, step, *, iota: int, interpret: bool):
     m, d = grads.shape
     pad = (-d) % BLOCK_D
     if pad:

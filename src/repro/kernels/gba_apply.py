@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import runtime
 from repro.kernels.launch_meta import (BlockMeta, LaunchMeta, block_specs,
                                        _round_up_static)
 
@@ -79,11 +80,17 @@ def launch_meta(n: int, m: int, param_dtype=jnp.float32,
 
 def _kernel(tokens_ref, step_ref, iota_ref, lr_ref, param_ref, accum_ref,
             buf_ref, new_param_ref, new_accum_ref, *, eps: float):
-    """buf: (M, BLOCK_N) VMEM; param/accum: (BLOCK_N,); scalars in SMEM."""
+    """buf: (M, BLOCK_N) VMEM; param/accum: (BLOCK_N,); scalars in SMEM.
+
+    The M decay weights are scalars read one by one from SMEM (the scalar
+    core cannot load SMEM vectors); the rows are summed in worker order."""
     m = buf_ref.shape[0]
-    keep = (step_ref[0] - tokens_ref[...]) <= iota_ref[0]     # Eq. (1)
-    w = keep.astype(jnp.float32) / jnp.float32(m)
-    g = jnp.sum(buf_ref[...].astype(jnp.float32) * w[:, None], axis=0)
+    g = None
+    for k in range(m):
+        keep = (step_ref[0] - tokens_ref[k]) <= iota_ref[0]   # Eq. (1)
+        w = keep.astype(jnp.float32) / jnp.float32(m)
+        row = buf_ref[k, :].astype(jnp.float32) * w
+        g = row if g is None else g + row
     a = accum_ref[...].astype(jnp.float32) + g * g
     p = param_ref[...].astype(jnp.float32)
     p = p - lr_ref[0] * g / (jnp.sqrt(a) + eps)
@@ -91,17 +98,23 @@ def _kernel(tokens_ref, step_ref, iota_ref, lr_ref, param_ref, accum_ref,
     new_accum_ref[...] = a
 
 
-@functools.partial(jax.jit, static_argnames=("iota", "eps", "interpret"))
 def gba_apply(param: jax.Array, accum: jax.Array, buffer: jax.Array,
               tokens: jax.Array, step: jax.Array, lr: jax.Array, *,
-              iota: int, eps: float = 1e-10, interpret: bool = True
+              iota: int, eps: float = 1e-10, interpret: bool | None = None
               ) -> tuple[jax.Array, jax.Array]:
     """Single-pass decay-aggregate + Adagrad apply.
 
     param/accum: (N,), buffer: (M, N), tokens: (M,) ->
-    (new_param (N,), new_accum (N,)).  ``interpret=True`` runs the kernel
-    body on CPU (this container); pass False on real TPUs.
+    (new_param (N,), new_accum (N,)).  ``interpret=None`` resolves
+    through ``repro.kernels.runtime``.
     """
+    return _gba_apply(param, accum, buffer, tokens, step, lr, iota=iota,
+                      eps=eps, interpret=runtime.resolve(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("iota", "eps", "interpret"))
+def _gba_apply(param, accum, buffer, tokens, step, lr, *, iota: int,
+               eps: float, interpret: bool):
     n = param.shape[0]
     m = buffer.shape[0]
     pad = (-n) % BLOCK_N

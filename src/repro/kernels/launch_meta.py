@@ -124,12 +124,11 @@ def block_specs(blocks: tuple[BlockMeta, ...]):
     """BlockMeta tuple -> the real pallas BlockSpec list (imports pallas
     lazily so the dataclasses stay importable without a TPU toolchain)."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     specs = []
     for bm in blocks:
         if bm.memory_space == ANY:
-            specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+            specs.append(pl.BlockSpec(memory_space=pl.ANY))
         else:
             specs.append(pl.BlockSpec(bm.block, bm.index_map))
     return specs

@@ -1,9 +1,9 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` resolves from the platform at first use — CPU/GPU containers
-interpret, real TPUs compile (``repro.kernels.runtime``).  Override the
-session default with the ``REPRO_INTERPRET`` env var or ``set_interpret``;
-every wrapper additionally honors a per-call ``interpret=`` override.
+``interpret=None`` resolves from the platform at first use — off a TPU the
+kernels interpret, on a TPU they compile (``repro.kernels.runtime``).
+Override the process-wide default with the ``REPRO_INTERPRET`` env var or
+``set_interpret``; every wrapper also takes a per-call ``interpret=``.
 The tree-level helpers apply the kernels across parameter pytrees; the
 pooled-lookup wrappers expose the streamed embedding kernels' capacity
 knobs (``block_v``/``block_d``/``chunk_e``).
@@ -15,7 +15,6 @@ from typing import Any
 
 import jax
 
-from repro.kernels import runtime
 from repro.kernels.embedding_bag import embedding_bag, embedding_bag_grad
 from repro.kernels.fused_adagrad import fused_adagrad
 from repro.kernels.gba_aggregate import gba_aggregate
@@ -38,12 +37,11 @@ def gba_aggregate_tree(grads_stacked: Any, tokens: jax.Array,
                        interpret: bool | None = None) -> Any:
     """Kernel-backed version of repro.core.gba.aggregate_dense: flattens
     each leaf to (M, -1), runs the fused kernel, restores shapes."""
-    itp = runtime.resolve(interpret)
-
     def per_leaf(g):
         m = g.shape[0]
         flat = g.reshape(m, -1)
-        out = gba_aggregate(flat, tokens, step, iota=iota, interpret=itp)
+        out = gba_aggregate(flat, tokens, step, iota=iota,
+                            interpret=interpret)
         return out.reshape(g.shape[1:])
 
     return jax.tree.map(per_leaf, grads_stacked)
@@ -57,7 +55,7 @@ def gba_apply_flat(param_flat: jax.Array, accum_flat: jax.Array,
     """Fused decay-aggregate + Adagrad over the flat (M, N) buffer — the
     single-launch PS apply path (see repro.core.gba.FlatLayout)."""
     return gba_apply(param_flat, accum_flat, buffer, tokens, step, lr,
-                     iota=iota, eps=eps, interpret=runtime.resolve(interpret))
+                     iota=iota, eps=eps, interpret=interpret)
 
 
 def quantize_wire(payload: jax.Array, *, tile: int, mode: str,
@@ -68,11 +66,10 @@ def quantize_wire(payload: jax.Array, *, tile: int, mode: str,
     ``mode="sign"``   -> ``(qvals, scale, residual)`` (no zero-point).
     See ``repro.kernels.quantize``.
     """
-    itp = runtime.resolve(interpret)
     if mode == "minmax":
-        return quantize_minmax(payload, tile=tile, interpret=itp)
+        return quantize_minmax(payload, tile=tile, interpret=interpret)
     if mode == "sign":
-        return quantize_sign(payload, tile=tile, interpret=itp)
+        return quantize_sign(payload, tile=tile, interpret=interpret)
     raise ValueError(f"unknown quantize mode {mode!r}")
 
 
@@ -82,17 +79,15 @@ def dequantize_wire(qvals: jax.Array, scale: jax.Array,
     """Reconstruct the f32 payload from routed wire arrays (see
     ``repro.kernels.quantize.dequantize``)."""
     return dequantize(qvals, scale, zero, tile=tile, mode=mode,
-                      interpret=runtime.resolve(interpret))
+                      interpret=interpret)
 
 
 def adagrad_apply_tree(params: Any, grads: Any, accums: Any, lr, *,
                        interpret: bool | None = None) -> tuple[Any, Any]:
     """Fused Adagrad over a pytree (flattening each leaf to 1-D)."""
-    itp = runtime.resolve(interpret)
-
     def per_leaf(p, g, a):
         np_, na = fused_adagrad(p.reshape(-1), g.reshape(-1), a.reshape(-1),
-                                lr, interpret=itp)
+                                lr, interpret=interpret)
         return np_.reshape(p.shape), na.reshape(a.shape)
 
     out = jax.tree.map(per_leaf, params, grads, accums)
@@ -110,8 +105,7 @@ def pooled_lookup(ids: jax.Array, table: jax.Array, *,
     O(block_v * block_d + chunk_e * block_d) scratch regardless of V."""
     kernel_calls["pooled_lookup"] += 1
     return embedding_bag(ids, table, block_v=block_v, block_d=block_d,
-                         chunk_e=chunk_e,
-                         interpret=runtime.resolve(interpret))
+                         chunk_e=chunk_e, interpret=interpret)
 
 
 def pooled_lookup_grad(ids: jax.Array, grad_out: jax.Array, capacity: int,
@@ -124,4 +118,4 @@ def pooled_lookup_grad(ids: jax.Array, grad_out: jax.Array, capacity: int,
     kernel_calls["pooled_lookup_grad"] += 1
     return embedding_bag_grad(ids, grad_out, capacity, block_v=block_v,
                               block_d=block_d, chunk_e=chunk_e,
-                              interpret=runtime.resolve(interpret))
+                              interpret=interpret)

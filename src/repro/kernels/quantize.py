@@ -21,10 +21,12 @@ feedback costs no extra launch and no extra HBM round-trip, and the
 residual is bit-exactly consistent with what ``dequantize`` reconstructs
 on the receiving shard (identical arithmetic, identical sideband).
 
-Per-tile scale/zero sidebands are ``(R, n_tiles)`` f32 arrays held fully
-VMEM-resident across the grid (constant index map — they are ~1/tile of
-the payload) while the payload streams through ``(R, tile)`` blocks; each
-grid step writes its own sideband column with a dynamic ``pl.ds`` store.
+Per-tile scale/zero sidebands are ``(R, n_tiles)`` f32 arrays.  Inside
+the launch they are laid out tile-major, ``(n_tiles, R, 1)``, so grid step
+``i`` owns the whole ``(1, R, 1)`` block ``i`` beside its ``(R, tile)``
+payload block: no step slices single lanes out of a shared block, which
+the TPU compiler refuses.  The wrappers transpose to and from the
+``(R, n_tiles)`` wire layout.
 Every launch exports a :class:`~repro.kernels.launch_meta.LaunchMeta`
 the real ``pallas_call`` builds its specs from, so the static auditor
 (``repro.analysis``) checks tiles/VMEM/grid of the launch that runs.
@@ -37,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import runtime
 from repro.kernels.launch_meta import BlockMeta, LaunchMeta, block_specs
 
 MODES = ("minmax", "sign")
@@ -54,28 +57,26 @@ def _check_geometry(r: int, c: int, tile: int) -> int:
 
 def quantize_vmem_bytes(r: int, c: int, tile: int, mode: str) -> int:
     """Per-grid-step VMEM residency of a quantize launch: payload in +
-    residual out f32 blocks, int8 code block, and the fully-resident
-    f32 sideband(s) (scale, plus zero-point for minmax)."""
-    n_tiles = _check_geometry(r, c, tile)
+    residual out f32 blocks, int8 code block, and this tile's f32
+    sideband block(s) (scale, plus zero-point for minmax)."""
+    _check_geometry(r, c, tile)
     sidebands = 2 if mode == "minmax" else 1
-    return r * tile * 4 + r * tile * 1 + r * tile * 4 \
-        + sidebands * r * n_tiles * 4
+    return r * tile * 4 + r * tile * 1 + r * tile * 4 + sidebands * r * 4
 
 
 def dequant_vmem_bytes(r: int, c: int, tile: int, mode: str) -> int:
     """Per-grid-step VMEM residency of a dequantize launch: int8 code
-    block + f32 out block + resident sideband(s)."""
-    n_tiles = _check_geometry(r, c, tile)
+    block + f32 out block + this tile's sideband block(s)."""
+    _check_geometry(r, c, tile)
     sidebands = 2 if mode == "minmax" else 1
-    return r * tile * 1 + r * tile * 4 + sidebands * r * n_tiles * 4
+    return r * tile * 1 + r * tile * 4 + sidebands * r * 4
 
 
 def _sideband_blocks(r: int, n_tiles: int, names: tuple[str, ...]
                      ) -> tuple[BlockMeta, ...]:
-    # constant index map: the whole (R, n_tiles) sideband stays VMEM-
-    # resident across the grid; grid step i owns column i
-    return tuple(BlockMeta(name, (r, n_tiles), jnp.float32, (r, n_tiles),
-                           lambda i: (0, 0))
+    # tile-major (n_tiles, R, 1): grid step i owns block i
+    return tuple(BlockMeta(name, (n_tiles, r, 1), jnp.float32, (1, r, 1),
+                           lambda i: (i, 0, 0))
                  for name in names)
 
 
@@ -129,7 +130,6 @@ def dequant_launch_meta(r: int, c: int, tile: int, mode: str) -> LaunchMeta:
 
 
 def _minmax_kernel(pay_ref, q_ref, sc_ref, zp_ref, res_ref):
-    i = pl.program_id(0)
     x = pay_ref[...]                                   # (R, tile) f32
     mn = jnp.min(x, axis=1, keepdims=True)             # (R, 1)
     mx = jnp.max(x, axis=1, keepdims=True)
@@ -138,8 +138,8 @@ def _minmax_kernel(pay_ref, q_ref, sc_ref, zp_ref, res_ref):
     code = jnp.clip(jnp.round((x - mn) / safe), 0.0, 255.0)
     q = (code - 128.0).astype(jnp.int8)
     q_ref[...] = q
-    sc_ref[:, pl.ds(i, 1)] = scale
-    zp_ref[:, pl.ds(i, 1)] = mn
+    sc_ref[0] = scale
+    zp_ref[0] = mn
     # same expression as _dequant_minmax_kernel -> residual is consistent
     # with the receiving shard's reconstruction
     deq = (q.astype(jnp.float32) + 128.0) * scale + mn
@@ -147,30 +147,36 @@ def _minmax_kernel(pay_ref, q_ref, sc_ref, zp_ref, res_ref):
 
 
 def _sign_kernel(pay_ref, q_ref, sc_ref, res_ref):
-    i = pl.program_id(0)
     x = pay_ref[...]
     scale = jnp.mean(jnp.abs(x), axis=1, keepdims=True)
     q = jnp.where(x >= 0.0, 1, -1).astype(jnp.int8)
     q_ref[...] = q
-    sc_ref[:, pl.ds(i, 1)] = scale
+    sc_ref[0] = scale
     deq = q.astype(jnp.float32) * scale
     res_ref[...] = x - deq
 
 
 def _dequant_minmax_kernel(q_ref, sc_ref, zp_ref, out_ref):
-    i = pl.program_id(0)
-    scale = sc_ref[:, pl.ds(i, 1)]
-    zp = zp_ref[:, pl.ds(i, 1)]
-    out_ref[...] = (q_ref[...].astype(jnp.float32) + 128.0) * scale + zp
+    out_ref[...] = (q_ref[...].astype(jnp.float32) + 128.0) * sc_ref[0] \
+        + zp_ref[0]
 
 
 def _dequant_sign_kernel(q_ref, sc_ref, out_ref):
-    i = pl.program_id(0)
-    out_ref[...] = q_ref[...].astype(jnp.float32) * sc_ref[:, pl.ds(i, 1)]
+    out_ref[...] = q_ref[...].astype(jnp.float32) * sc_ref[0]
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def quantize_minmax(payload: jax.Array, *, tile: int, interpret: bool = True
+def _tile_major(sideband: jax.Array) -> jax.Array:
+    """(R, n_tiles) wire layout -> (n_tiles, R, 1) launch layout."""
+    return sideband.T[:, :, None]
+
+
+def _wire(sideband: jax.Array) -> jax.Array:
+    """(n_tiles, R, 1) launch layout -> (R, n_tiles) wire layout."""
+    return sideband[:, :, 0].T
+
+
+def quantize_minmax(payload: jax.Array, *, tile: int,
+                    interpret: bool | None = None
                     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Min-max int8 quantize with fused error feedback.
 
@@ -179,64 +185,66 @@ def quantize_minmax(payload: jax.Array, *, tile: int, interpret: bool = True
     residual f32 (R, C))`` with ``residual == payload -
     dequantize(qvals, scale, zero)`` exactly.
     """
-    r, c = payload.shape
-    n_tiles = _check_geometry(r, c, tile)
-    meta = quantize_launch_meta(r, c, tile, "minmax")
-    return pl.pallas_call(
-        _minmax_kernel,
-        grid=meta.grid,
-        in_specs=block_specs(meta.inputs),
-        out_specs=block_specs(meta.outputs),
-        out_shape=[
-            jax.ShapeDtypeStruct((r, c), jnp.int8),
-            jax.ShapeDtypeStruct((r, n_tiles), jnp.float32),
-            jax.ShapeDtypeStruct((r, n_tiles), jnp.float32),
-            jax.ShapeDtypeStruct((r, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(payload.astype(jnp.float32))
+    q, sc, zp, res = _quantize(payload, tile=tile, mode="minmax",
+                               interpret=runtime.resolve(interpret))
+    return q, _wire(sc), _wire(zp), res
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def quantize_sign(payload: jax.Array, *, tile: int, interpret: bool = True
+def quantize_sign(payload: jax.Array, *, tile: int,
+                  interpret: bool | None = None
                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Sign (1-bit) quantize with per-tile mean-|x| norm and fused error
     feedback: payload (R, C) f32 -> ``(qvals int8 ±1, scale f32
     (R, C//tile), residual f32 (R, C))``."""
+    q, sc, res = _quantize(payload, tile=tile, mode="sign",
+                           interpret=runtime.resolve(interpret))
+    return q, _wire(sc), res
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "mode", "interpret"))
+def _quantize(payload: jax.Array, *, tile: int, mode: str, interpret: bool):
     r, c = payload.shape
     n_tiles = _check_geometry(r, c, tile)
-    meta = quantize_launch_meta(r, c, tile, "sign")
+    meta = quantize_launch_meta(r, c, tile, mode)
+    sideband = jax.ShapeDtypeStruct((n_tiles, r, 1), jnp.float32)
     return pl.pallas_call(
-        _sign_kernel,
+        _minmax_kernel if mode == "minmax" else _sign_kernel,
         grid=meta.grid,
         in_specs=block_specs(meta.inputs),
         out_specs=block_specs(meta.outputs),
         out_shape=[
             jax.ShapeDtypeStruct((r, c), jnp.int8),
-            jax.ShapeDtypeStruct((r, n_tiles), jnp.float32),
+            *[sideband] * (len(meta.outputs) - 2),
             jax.ShapeDtypeStruct((r, c), jnp.float32),
         ],
+        name=meta.kernel,
         interpret=interpret,
     )(payload.astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "mode", "interpret"))
 def dequantize(qvals: jax.Array, scale: jax.Array,
                zero: jax.Array | None = None, *, tile: int, mode: str,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Reconstruct the f32 payload from the routed wire arrays.
 
     qvals: (R, C) int8; scale (and, for ``mode="minmax"``, zero):
     (R, C//tile) f32 -> (R, C) f32.
     """
+    if mode == "minmax" and zero is None:
+        raise ValueError("minmax dequantize needs the zero-point array")
+    return _dequantize(qvals, scale, zero, tile=tile, mode=mode,
+                       interpret=runtime.resolve(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "mode", "interpret"))
+def _dequantize(qvals, scale, zero, *, tile: int, mode: str,
+                interpret: bool) -> jax.Array:
     r, c = qvals.shape
     _check_geometry(r, c, tile)
     if mode == "minmax":
-        if zero is None:
-            raise ValueError("minmax dequantize needs the zero-point array")
-        kernel, operands = _dequant_minmax_kernel, (qvals, scale, zero)
+        kernel, sidebands = _dequant_minmax_kernel, (scale, zero)
     elif mode == "sign":
-        kernel, operands = _dequant_sign_kernel, (qvals, scale)
+        kernel, sidebands = _dequant_sign_kernel, (scale,)
     else:
         raise ValueError(f"unknown dequantize mode {mode!r}")
     meta = dequant_launch_meta(r, c, tile, mode)
@@ -246,6 +254,7 @@ def dequantize(qvals: jax.Array, scale: jax.Array,
         in_specs=block_specs(meta.inputs),
         out_specs=block_specs(meta.outputs),
         out_shape=[jax.ShapeDtypeStruct((r, c), jnp.float32)],
+        name=meta.kernel,
         interpret=interpret,
-    )(*operands)
+    )(qvals, *map(_tile_major, sidebands))
     return out

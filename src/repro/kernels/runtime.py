@@ -1,9 +1,8 @@
 """Interpret-mode resolution shared by every kernel wrapper.
 
-The Pallas kernels take an ``interpret=`` flag; what it should default to
-depends on where the process runs: CPU/GPU containers (this repo's test
-environment) must interpret, real TPUs must compile.  Hard-coding ``True``
-(the pre-PR-2 state) silently interpreted on real TPUs.  Resolution order:
+Every kernel function takes ``interpret: bool | None = None`` and resolves
+``None`` here: on a TPU the kernels compile through Mosaic, anywhere else
+(the CPU test suite) they run in Pallas interpret mode.  Resolution order:
 
 1. an explicit per-call ``interpret=`` override (never resolved here),
 2. ``set_interpret(...)`` — programmatic override for launch scripts,
@@ -11,8 +10,9 @@ environment) must interpret, real TPUs must compile.  Hard-coding ``True``
    anything else interprets),
 4. the platform: ``jax.default_backend() != "tpu"``.
 
-The platform probe is deferred to first use so importing kernel modules
-never initializes the JAX backend.
+Steps 2 and 3 can force interpret mode on a TPU; ``chip_smoke.py`` refuses
+to run when they do.  The platform probe is deferred to first use so
+importing kernel modules never initializes the JAX backend.
 """
 from __future__ import annotations
 
@@ -33,7 +33,7 @@ def default_interpret() -> bool:
 
 
 def interpret_mode() -> bool:
-    """The session-wide interpret default (cached after first resolution)."""
+    """The process-wide interpret default (cached after first resolution)."""
     global _INTERPRET
     if _INTERPRET is None:
         _INTERPRET = default_interpret()
@@ -47,5 +47,5 @@ def set_interpret(value: bool | None) -> None:
 
 
 def resolve(override: bool | None) -> bool:
-    """Per-call resolution: explicit override wins, else the session mode."""
+    """Per-call resolution: explicit override wins, else the process-wide mode."""
     return interpret_mode() if override is None else override

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.launch.steps import make_decode_step
 from repro.models import transformer as T
@@ -76,6 +77,7 @@ def main() -> None:
                     help="subtree of the checkpoint holding the params "
                          "(e.g. 'params' for a full train state)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -94,6 +94,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import GBAConfig
 from repro.core.autoswitch import AutoSwitchController
 from repro.core.flat_sharded import ShardedFlatLayout
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.programs import build_programs
 from repro.sim.cluster import ClusterSpec
 from repro.sim.faults import FaultInjector, FaultPlan
@@ -925,11 +927,12 @@ def main(argv: list[str] | None = None) -> dict:
                     help="print the result as one JSON line (last line "
                          "of stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if jax.device_count() < args.workers:
         ap.error(f"need {args.workers} devices, have {jax.device_count()} "
                  f"(use --host-devices on CPU)")
-    mesh = jax.make_mesh((args.workers,), ("data",))
+    mesh = make_mesh((args.workers,), ("data",))
     params, loss_fn, group_by = demo_model()
     spec = ClusterSpec(num_workers=args.workers, base_speed=10_000.0,
                        jitter=0.05, allreduce_latency=0.005,

@@ -86,17 +86,20 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import GBAConfig
 from repro.data import make_lm_stream
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import (make_mesh, make_production_mesh,
+                               make_smoke_mesh)
 from repro.launch.programs import ARCH_OPTIMIZER, build_programs
 from repro.models import transformer as T
 from repro.optim import get_optimizer
 
 
-def run_embedding_smoke(args) -> None:
+def run_embedding_smoke(args) -> jax.Array:
     """Sparse-module smoke: a --vocab-row hashed table trained end-to-end
     through the streamed pooled-lookup kernels (forward tile stream +
     sorted-scatter backward) on the smoke mesh.  The (V, D) table lives in
-    HBM for both passes; VMEM holds only the double-buffered blocks."""
+    HBM for both passes; VMEM holds only the double-buffered blocks.
+    Returns the trained table."""
     from repro import embeddings
     from repro.kernels.embedding_bag import (BLOCK_D, BLOCK_V, CHUNK_E,
                                              stream_vmem_bytes)
@@ -138,6 +141,7 @@ def run_embedding_smoke(args) -> None:
             print(f"step {i:4d}  loss {float(loss):.4f}  "
                   f"{rate:,.0f} lookups/s")
     assert jnp.isfinite(loss), "embedding smoke diverged"
+    return table_arr
 
 
 def run_wire_train(args, cfg, mesh, gba, stream, params,
@@ -241,7 +245,7 @@ def run_autoswitch(args, cfg, mesh, params) -> None:
           f"final loss {res.losses[-1] if res.losses else float('nan'):.4f}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS,
                     help="LM architecture (required unless --vocab)")
@@ -309,7 +313,13 @@ def main() -> None:
                     help="embedding cols per output tile (0 = default)")
     ap.add_argument("--chunk-e", type=int, default=0,
                     help="sorted entries per pipeline step (0 = default)")
+    return ap
+
+
+def main() -> None:
+    ap = build_parser()
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.vocab:
         run_embedding_smoke(args)
@@ -330,7 +340,7 @@ def main() -> None:
             ap.error(f"--mesh {args.mesh} needs {shape[0] * shape[1]} "
                      f"devices, have {jax.device_count()} "
                      f"(use --host-devices on CPU)")
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
     elif args.reduced:
         mesh = make_smoke_mesh()
     else:

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.embeddings.hot_cache import HotIDCache, cached_pooled_lookup
 from repro.embeddings.table import EmbeddingTable, StreamConfig, hash_ids
+from repro.kernels.embedding_bag import lane_dense
 from repro.models.recsys import _mlp_fwd, _mlp_init
 from repro.serving.config import ServingConfig
 from repro.serving.sources import ParamSource, Snapshot, StaticSource
@@ -53,11 +54,11 @@ def init_scoring_params(key, capacity: int, dim: int,
 
 def _as_table(t: Any) -> EmbeddingTable:
     """Checkpoint round-trips turn the EmbeddingTable NamedTuple into a
-    plain tuple — normalize back."""
-    if isinstance(t, EmbeddingTable):
-        return t
+    plain tuple — normalize back.  The table is held lane-dense, padded
+    once per adopted version instead of once per miss fetch."""
     if isinstance(t, (tuple, list)):
-        return EmbeddingTable(jnp.asarray(t[0]), jnp.asarray(t[1]))
+        return EmbeddingTable(lane_dense(jnp.asarray(t[0])),
+                              jnp.asarray(t[1]))
     raise TypeError(f"expected EmbeddingTable, got {type(t)!r}")
 
 
@@ -78,13 +79,13 @@ class RecsysScoringEngine:
         self.config = config or ServingConfig()
         self.stream = stream
         snap = source.snapshot()
+        self.dim = int(snap.params["table"][0].shape[1])
         self._table = _as_table(snap.params["table"])
         self._mlp = snap.params["mlp"]
         self._version = snap.version
         self.param_step = snap.step
         self._n_mlp = sum(1 for k in self._mlp if k.startswith("w"))
-        dim = self._table.table.shape[1]
-        self.cache = (HotIDCache(self.config.cache_capacity, dim)
+        self.cache = (HotIDCache(self.config.cache_capacity, self.dim)
                       if self.config.cache_capacity else None)
         if self.cache is not None:
             self.cache.bump_version(snap.version)
@@ -129,7 +130,8 @@ class RecsysScoringEngine:
         hashed = np.asarray(hash_ids(jnp.asarray(raw_ids, jnp.int32),
                                      table.table.shape[0]))
         pooled = cached_pooled_lookup(self.cache, table, hashed,
-                                      version=version, stream=self.stream)
+                                      version=version, stream=self.stream,
+                                      dim=self.dim)
         out = np.asarray(self._tower(mlp, jnp.asarray(pooled)))
         self.requests += 1
         self.scored += out.shape[0]

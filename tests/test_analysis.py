@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.analysis import audit as AU
 from repro.analysis import dataflow as DF
@@ -105,8 +104,8 @@ def test_coll_001_trips_on_mismatched_layout():
 def test_coll_002_trips_on_vector_psum():
     mesh = AU.abstract_mesh(M)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"),),
-                       out_specs=P(), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"),),
+                       out_specs=P(), check_vma=False)
     def bad(x):
         return lax.psum(x, "data")
 
@@ -185,7 +184,7 @@ def test_dtype_001_ignores_f32_layouts():
 
 
 def test_dtype_002_trips_under_x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         jx = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(SDS((8,), jnp.float32))
     assert rules_of(JA.check_no_f64(jx, "t")) == ["GBA-DTYPE-002"]

@@ -12,7 +12,7 @@ from repro.kernels import runtime
 from repro.kernels.embedding_bag import (BLOCK_D, BLOCK_V, CHUNK_E,
                                          embedding_bag, embedding_bag_grad,
                                          embedding_bag_grad_resident,
-                                         stream_vmem_bytes)
+                                         lane_dense, stream_vmem_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -115,6 +115,20 @@ def test_streamed_fwd_custom_knobs():
     exp = ref.embedding_bag_ref(ids, table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_lane_dense_table_matches_narrow():
+    """A table padded once to whole lane tiles pools bit-identically to
+    the narrow table the kernel pads per call; the extra columns are 0."""
+    b, f, v, d = 40, 6, 3000, 16
+    key = jax.random.PRNGKey(11)
+    ids = jax.random.randint(key, (b, f), 0, v)
+    table = jax.random.normal(key, (v, d), jnp.float32)
+    wide = embedding_bag(ids, lane_dense(table))
+    assert wide.shape == (b, 128)
+    assert np.array_equal(np.asarray(wide[:, :d]),
+                          np.asarray(embedding_bag(ids, table)))
+    assert float(jnp.abs(wide[:, d:]).max()) == 0.0
 
 
 # ---------------------------------------------------------------------------

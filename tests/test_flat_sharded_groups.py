@@ -16,8 +16,10 @@ by Eq. (1) and non-tile-multiple leaves.
 """
 import functools
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -166,7 +168,8 @@ from repro.core.gba_shard_map import make_gba_fused_psum_step
 from repro.distributed import sharding as S
 
 out = {"devices": jax.device_count()}
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 key = jax.random.PRNGKey(7)
 # non-tile-multiple leaves across three layer groups, tile=256
 params = {"embed": jax.random.normal(key, (33, 9)),
@@ -242,8 +245,8 @@ def grouped_results():
         [sys.executable, "-c", _GROUPED_SCRIPT], capture_output=True,
         text=True,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo", timeout=540)
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parents[1], timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 

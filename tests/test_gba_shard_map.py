@@ -6,8 +6,10 @@ host devices (device count locks at first jax init); the sharded-flat
 cases share ONE 4-device subprocess via a module fixture so the suite
 pays the jax import + compiles once."""
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -19,8 +21,8 @@ def _run_forced(script: str, timeout: int = 540) -> dict:
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo", timeout=timeout)
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parents[1], timeout=timeout)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -35,7 +37,8 @@ from repro.core import aggregate_dense
 from repro.core.gba_shard_map import make_gba_psum_step
 from repro.optim import sgd
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 M = 8
 D = 16
 
@@ -108,7 +111,8 @@ from repro.distributed import sharding as S
 from repro.optim import adagrad
 
 out = {"devices": jax.device_count()}
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 key = jax.random.PRNGKey(7)
 # non-tile-multiple leaf sizes on purpose: 297, 41, 700 against tile=256
 params = {"w": jax.random.normal(key, (33, 9)),

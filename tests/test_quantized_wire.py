@@ -18,20 +18,24 @@ seeded tiny-DeepFM recsys smoke within a tolerance band of full
 precision.
 """
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.core.compression import CompressionPolicy
 from repro.core.flat_sharded import ShardedFlatLayout
 from repro.kernels import quantize as Q
 
-_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-        "JAX_PLATFORMS": "cpu"}
+_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+        "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
+_REPO = Path(__file__).resolve().parents[1]
 
 
 def _layout(num_shards=4, tile=256, grouped=True):
@@ -119,7 +123,7 @@ def test_wire_state_specs():
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as S
     lay = _layout(num_shards=1)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert S.wire_state_specs(lay, mesh, "none") == {}
     specs = S.wire_state_specs(lay, mesh, "onebit")
     assert specs == {"residual": P("data", None),
@@ -234,7 +238,8 @@ from repro.core.gba_shard_map import make_gba_fused_psum_step
 from repro.analysis import jaxpr_audit as JA
 
 out = {"devices": jax.device_count()}
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 key = jax.random.PRNGKey(7)
 params = {"embed": jax.random.normal(key, (33, 9)),
           "blocks": {"l0": {"w": jax.random.normal(
@@ -329,7 +334,7 @@ print(json.dumps(out))
 def wire_results():
     out = subprocess.run(
         [sys.executable, "-c", _WIRE_SCRIPT], capture_output=True,
-        text=True, env=dict(_ENV), cwd="/root/repo", timeout=540)
+        text=True, env=dict(_ENV), cwd=_REPO, timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -398,7 +403,8 @@ from repro.models import recsys as R
 cfg = RecsysConfig(name="tiny-deepfm", model="deepfm", num_fields=4,
                    hash_capacity=523, embed_dim=8, mlp_dims=(16,))
 params = R.init_deepfm(jax.random.PRNGKey(0), cfg)
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 m, iota, lr, B, steps = 4, 4, 0.4, 64, 40
 lay = ShardedFlatLayout.from_params(params, m, tile=256)
 teacher = jax.random.normal(jax.random.PRNGKey(99), (cfg.hash_capacity,))
@@ -448,7 +454,7 @@ print(json.dumps(out))
 def recsys_results():
     out = subprocess.run(
         [sys.executable, "-c", _RECSYS_SCRIPT], capture_output=True,
-        text=True, env=dict(_ENV), cwd="/root/repo", timeout=540)
+        text=True, env=dict(_ENV), cwd=_REPO, timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 

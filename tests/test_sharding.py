@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import GBAConfig, InputShape
 from repro.distributed import sharding as S
@@ -16,7 +17,7 @@ from repro.launch.steps import abstract_cache, abstract_params, build_step
 def _mesh22():
     if jax.device_count() < 4:
         pytest.skip("needs >=4 devices (run under forced host devices)")
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return make_mesh((2, 2), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -42,7 +43,7 @@ def test_divisibility_guard_replicates():
     """starcoder2's 24 heads don't divide model=16: heads spec must fall
     back to head_dim (or None), never an invalid axis."""
     cfg = get_config("starcoder2-3b")
-    mesh = jax.make_mesh((1, 16), ("data", "model")) \
+    mesh = make_mesh((1, 16), ("data", "model")) \
         if jax.device_count() >= 16 else None
     if mesh is None:
         pytest.skip("needs 16 devices")
@@ -174,14 +175,9 @@ def test_cache_specs_long_context_seq_sharding():
     """long_500k (batch=1): KV seq dim takes the data axis.  Uses an
     AbstractMesh so the production (16,16) geometry is testable on 1 CPU
     device (cache_specs only reads mesh.shape)."""
-    import inspect
     from jax.sharding import AbstractMesh
     cfg = get_config("gemma2-27b")
-    params = inspect.signature(AbstractMesh).parameters
-    if "shape_tuple" in params:      # jax<=0.4.x: one ((name, size), ...) arg
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
-    else:                            # jax>=0.5: (sizes, names)
-        mesh = AbstractMesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cache = abstract_cache(cfg, 1, 1024)
     specs = S.cache_specs(cache, cfg, mesh, batch=1)
     k_spec = specs["blocks"]["l1"]["attn"]["k"]  # global layer

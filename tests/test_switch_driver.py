@@ -22,14 +22,17 @@ holding the mode, and compression-warmup re-entry across repeated
 async entries.
 """
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.core.flat_sharded import ShardedFlatLayout
 from repro.launch.switch_driver import (GlobalStep, SwitchConfig,
                                         SwitchDriver, demo_batch_fn,
@@ -39,8 +42,9 @@ from repro.launch.switch_driver import (GlobalStep, SwitchConfig,
 from repro.sim.cluster import ClusterSpec
 from repro.sim.faults import FaultPlan
 
-_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-        "JAX_PLATFORMS": "cpu"}
+_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+        "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
+_REPO = Path(__file__).resolve().parents[1]
 
 
 def _params():
@@ -132,7 +136,7 @@ def test_demo_plan_strained_shape():
 # ---------------------------------------------------------------------------
 
 def _driver_1w(cfg=None, plan=None, spec=None):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params, loss_fn, group_by = demo_model()
     cfg = cfg or SwitchConfig(local_batch=8, sync_impl="fused")
     return SwitchDriver(
@@ -144,7 +148,7 @@ def _driver_1w(cfg=None, plan=None, spec=None):
 
 
 def test_driver_rejects_mismatched_workers():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params, loss_fn, group_by = demo_model()
     with pytest.raises(ValueError):
         SwitchDriver(mesh, loss_fn, params,
@@ -157,7 +161,7 @@ def test_driver_rejects_mismatched_workers():
 
 def test_driver_rejects_bad_batch_fn():
     """batch_fn yielding a different leading dim than cfg.local_batch."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params, loss_fn, group_by = demo_model()
     with pytest.raises(ValueError):
         SwitchDriver(mesh, loss_fn, params,
@@ -190,6 +194,21 @@ def test_run_rejects_unknown_mode_and_bad_schedule():
         drv.run_schedule([GlobalStep((0, 0), (0, 1))], ["sync"])
 
 
+def test_switching_bench_without_jax_platforms(monkeypatch):
+    """The switching suite decides from the devices this process sees,
+    not from JAX_PLATFORMS: with one CPU device and the variable unset it
+    still runs, through a child that forces 4 host devices."""
+    monkeypatch.syspath_prepend(str(_REPO))
+    from benchmarks import bench_fig6_switching as fig6
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(fig6, "SWITCH_BATCHES", 32)
+    assert jax.device_count() < fig6.SWITCH_WORKERS
+    rows = fig6.run_switching()
+    assert [r.split(",")[0] for r in rows] == [
+        "fig6.switch_driver.strained", "fig6.switch_driver.quiet"]
+    assert all("speedup_vs_sync=" in r for r in rows)
+
+
 # ---------------------------------------------------------------------------
 # slow: 4-device swap parity (subprocess)
 # ---------------------------------------------------------------------------
@@ -207,7 +226,8 @@ from repro.sim.cluster import ClusterSpec
 from repro.sim.faults import FaultPlan
 
 out = {"devices": jax.device_count()}
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 params, loss_fn, group_by = demo_model()
 spec = ClusterSpec(num_workers=4)
 plan = FaultPlan.quiet(4)
@@ -268,7 +288,7 @@ print(json.dumps(out))
 def parity_results():
     out = subprocess.run(
         [sys.executable, "-c", _PARITY_SCRIPT], capture_output=True,
-        text=True, env=dict(_ENV), cwd="/root/repo", timeout=540)
+        text=True, env=dict(_ENV), cwd=_REPO, timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -310,7 +330,7 @@ def strained_results():
          "--host-devices", "4", "--workers", "4", "--batches", "240",
          "--plan", "strained", "--mode", "auto", "--compare-sync",
          "--json"],
-        capture_output=True, text=True, env=dict(_ENV), cwd="/root/repo",
+        capture_output=True, text=True, env=dict(_ENV), cwd=_REPO,
         timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -362,7 +382,8 @@ from repro.sim.cluster import ClusterSpec
 from repro.sim.faults import FaultPlan, ScrapeDropout, StragglerWindow
 
 out = {}
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 params, loss_fn, group_by = demo_model()
 spec = ClusterSpec(num_workers=4, jitter=0.05, seed=0)
 
@@ -412,7 +433,7 @@ print(json.dumps(out))
 def chaos_results():
     out = subprocess.run(
         [sys.executable, "-c", _CHAOS_SCRIPT], capture_output=True,
-        text=True, env=dict(_ENV), cwd="/root/repo", timeout=540)
+        text=True, env=dict(_ENV), cwd=_REPO, timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 

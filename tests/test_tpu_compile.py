@@ -1,0 +1,105 @@
+"""Compile rehearsal: every main-path Pallas kernel at real widths, lowered
+with ``interpret=False`` and compiled for a described (not attached) TPU
+v5e.  Mosaic legality — tile-aligned slices and DMAs, SMEM scalar loads,
+VMEM limits — is checked here at no chip time; interpret-mode parity
+lives in test_kernels.py / test_embedding_stream.py.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this module.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import embedding_bag, fused_adagrad, gba_apply, quantize
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    # only a missing TPU library skips; any other failure to describe the
+    # topology is a regression and fails the tests
+    pytest.importorskip("libtpu")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _sds(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_gba_apply_compiles(one_chip):
+    m, n = 16, 65536
+    txt = _compile_text(
+        lambda p, a, b, t, s, lr: gba_apply.gba_apply(
+            p, a, b, t, s, lr, iota=4, interpret=False),
+        _sds(one_chip, (n,)), _sds(one_chip, (n,)),
+        _sds(one_chip, (m, n)), _sds(one_chip, (m,), jnp.int32),
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, ()))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_adagrad_compiles(one_chip):
+    n = 65536
+    txt = _compile_text(
+        lambda p, g, a, lr: fused_adagrad.fused_adagrad(
+            p, g, a, lr, interpret=False),
+        _sds(one_chip, (n,)), _sds(one_chip, (n,)), _sds(one_chip, (n,)),
+        _sds(one_chip, ()))
+    assert "tpu_custom_call" in txt
+
+
+# criteo-deepfm's width (D=16) at a 1M-row table, 512 x 26 ids
+V, D, B, F = 1_000_000, 16, 512, 26
+
+
+def test_embedding_bag_compiles(one_chip):
+    txt = _compile_text(
+        lambda ids, t: embedding_bag.embedding_bag(ids, t, interpret=False),
+        _sds(one_chip, (B, F), jnp.int32), _sds(one_chip, (V, D)))
+    assert "tpu_custom_call" in txt
+
+
+def test_embedding_bag_grad_compiles(one_chip):
+    txt = _compile_text(
+        lambda ids, g: embedding_bag.embedding_bag_grad(
+            ids, g, V, interpret=False),
+        _sds(one_chip, (B, F), jnp.int32), _sds(one_chip, (B, D)))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("mode", quantize.MODES)
+def test_quantize_compiles(one_chip, mode):
+    fn = quantize.quantize_minmax if mode == "minmax" \
+        else quantize.quantize_sign
+    txt = _compile_text(lambda x: fn(x, tile=2048, interpret=False),
+                        _sds(one_chip, (4, 1 << 16)))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("mode", quantize.MODES)
+def test_dequantize_compiles(one_chip, mode):
+    sb = _sds(one_chip, (4, (1 << 16) // 2048))
+    txt = _compile_text(
+        lambda q, s, z: quantize.dequantize(
+            q, s, z if mode == "minmax" else None, tile=2048, mode=mode,
+            interpret=False),
+        _sds(one_chip, (4, 1 << 16), jnp.int8), sb, sb)
+    assert "tpu_custom_call" in txt
