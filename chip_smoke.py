@@ -173,6 +173,9 @@ def phase_recsys(cfg=None, *, num_batches: int = 256, workers: int = 16,
     losses = np.asarray(stats.losses)
     for k in range(0, len(losses), 4):
         print(f"  step {k:3d}  loss {losses[k]:.4f}")
+    print(f"  {stats.stacked_steps} of {stats.applied_steps} steps stacked "
+          f"parameter versions; step variants (gba, m, shared_src) built "
+          f"by step {stats.step_builds}")
     check(stats.applied_steps >= 8,
           f"{stats.applied_steps} global steps applied (>= 8); kept "
           f"{stats.kept_slots} slots, dropped {stats.dropped_slots}")

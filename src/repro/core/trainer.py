@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.recsys import RecsysConfig
 from repro.data.clickstream import ClickStream
 from repro.embeddings.table import StreamConfig, presence_counts
@@ -49,6 +50,11 @@ class ReplayStats:
     dropped_slots: int = 0
     history_clamps: int = 0
     embed_rows_rescued: int = 0     # per-ID relaxation kept a stale slot's row
+    stacked_steps: int = 0          # steps that stacked M parameter versions
+    # step variant (gba, m, shared_src) built -> the step (applied_steps)
+    # whose call built it
+    step_builds: dict[tuple[bool, int, bool], int] = field(
+        default_factory=dict)
     losses: list[float] = field(default_factory=list)
 
 
@@ -114,6 +120,10 @@ class GBATrainer:
         ``shared_src``: every slot dispatched at the same parameter version
         (sync-like schedules) — the gradients vmap over batches only, with
         the params broadcast, skipping the M-way parameter stack.
+
+        The GBA aggregation runs under ``jax.named_scope("aggregate")`` and
+        the optimizer and ``last_update`` stamp under ``"apply"``; the
+        models name their own ``embedding`` and ``dense`` ops.
         """
         cap = self.cfg.hash_capacity
         iota = self.iota
@@ -121,12 +131,8 @@ class GBATrainer:
         grad_fn = self._loss_grad_fn
         in_axes = (None, 0) if shared_src else (0, 0)
 
-        def step(src_params, params, opt_state, batches, tokens, weights,
-                 step_k, last_update):
-            losses, grads = jax.vmap(grad_fn, in_axes=in_axes)(
-                src_params, batches)
-            sparse_g, dense_g = _split_tree(grads)
-
+        def aggregate(sparse_g, dense_g, batches, tokens, weights, step_k,
+                      last_update):
             # dense module: Alg. 2 line 22 — weighted sum / N_a (= m)
             wm = (weights / m).astype(jnp.float32)
             agg = jax.tree.map(
@@ -187,10 +193,22 @@ class GBATrainer:
             for name, g in emb_num.items():
                 full_grads[name] = g / (cntc[:, None] if g.ndim > 1
                                         else cntc)
-            params, opt_state = opt_update(params, full_grads, opt_state)
-            if sparse_g:
-                touched = emb_cnt > 0
-                last_update = jnp.where(touched, step_k, last_update)
+            return full_grads, emb_cnt, rescued
+
+        def step(src_params, params, opt_state, batches, tokens, weights,
+                 step_k, last_update):
+            losses, grads = jax.vmap(grad_fn, in_axes=in_axes)(
+                src_params, batches)
+            sparse_g, dense_g = _split_tree(grads)
+            with jax.named_scope("aggregate"):
+                full_grads, emb_cnt, rescued = aggregate(
+                    sparse_g, dense_g, batches, tokens, weights, step_k,
+                    last_update)
+            with jax.named_scope("apply"):
+                params, opt_state = opt_update(params, full_grads, opt_state)
+                if sparse_g:
+                    touched = emb_cnt > 0
+                    last_update = jnp.where(touched, step_k, last_update)
             return params, opt_state, last_update, losses, rescued
 
         return jax.jit(step)
@@ -216,36 +234,50 @@ class GBATrainer:
         gba = schedule.mode == "gba" and self.per_id_embedding_decay
 
         for k, slots in enumerate(schedule.steps):
-            ring.put(k, params)
             m = len(slots)
-            srcs = []
-            for slot in slots:
-                src, clamped = ring.get(slot.dispatch_step)
-                stats.history_clamps += int(clamped)
-                srcs.append(src)
-            shared_src = all(s.dispatch_step == slots[0].dispatch_step
-                             for s in slots)
-            if shared_src:
-                src_params = srcs[0]
-            else:
-                src_params = jax.tree.map(lambda *xs: jnp.stack(xs), *srcs)
-            raw = [stream.batch(day, slot.batch_index) for slot in slots]
-            batches = {key: jnp.asarray(np.stack([b[key] for b in raw]))
-                       for key in raw[0]}
-            tokens = jnp.asarray([s.token for s in slots], jnp.int32)
-            weights = jnp.asarray([s.weight for s in slots], jnp.float32)
-            step_fn = self._get_step(gba, m, shared_src)
-            params, opt_state, last_update, losses, rescued = step_fn(
-                src_params, params, opt_state, batches, tokens, weights,
-                jnp.int32(k), last_update)
-            for slot in slots:
-                if slot.weight > 0:
-                    stats.kept_slots += 1
-                else:
-                    stats.dropped_slots += 1
-            stats.embed_rows_rescued += int(rescued)
-            stats.applied_steps += 1
-            stats.losses.append(float(jnp.mean(losses)))
+            versions = {s.dispatch_step for s in slots}
+            shared_src = len(versions) == 1
+            with tracing.span("replay.step", day=day, k=k,
+                              stacked=len(versions)):
+                with tracing.span("replay.versions", day=day, k=k):
+                    ring.put(k, params)
+                    srcs = []
+                    for slot in slots:
+                        src, clamped = ring.get(slot.dispatch_step)
+                        stats.history_clamps += int(clamped)
+                        srcs.append(src)
+                    if shared_src:
+                        src_params = srcs[0]
+                    else:
+                        src_params = jax.tree.map(
+                            lambda *xs: jnp.stack(xs), *srcs)
+                        stats.stacked_steps += 1
+                with tracing.span("replay.inputs", day=day, k=k):
+                    raw = [stream.batch(day, slot.batch_index)
+                           for slot in slots]
+                    batches = {key: jnp.asarray(np.stack([b[key]
+                                                          for b in raw]))
+                               for key in raw[0]}
+                    tokens = jnp.asarray([s.token for s in slots], jnp.int32)
+                    weights = jnp.asarray([s.weight for s in slots],
+                                          jnp.float32)
+                with tracing.span("replay.dispatch", day=day, k=k):
+                    variant = (gba, m, shared_src)
+                    if variant not in self._step_cache:
+                        stats.step_builds[variant] = stats.applied_steps
+                    step_fn = self._get_step(*variant)
+                    params, opt_state, last_update, losses, rescued = \
+                        step_fn(src_params, params, opt_state, batches,
+                                tokens, weights, jnp.int32(k), last_update)
+                for slot in slots:
+                    if slot.weight > 0:
+                        stats.kept_slots += 1
+                    else:
+                        stats.dropped_slots += 1
+                with tracing.span("replay.readback", day=day, k=k):
+                    stats.embed_rows_rescued += int(rescued)
+                    stats.losses.append(float(jnp.mean(losses)))
+                stats.applied_steps += 1
         return params, opt_state, last_update, stats
 
 

@@ -5,7 +5,10 @@ function of ``(params, batch) -> logit`` where ``batch`` is a dict of hashed
 categorical IDs (+ label).  The sparse module is the hashed embedding table
 (``params["embed"]`` and, for DeepFM, ``params["linear"]``); everything else
 is the dense module — exactly the paper's sparse/dense split, which GBA's
-per-ID staleness decay relies on.
+per-ID staleness decay relies on.  The table lookups run under
+``jax.named_scope("embedding")`` and the rest of each forward pass and the
+loss under ``"dense"``, so the compiled step's ops (backward ones as
+``transpose(jvp(embedding))``) name the module they belong to.
 
 Batch layout (from repro.data.clickstream):
   fields:   (B, num_fields) int32   hashed categorical features
@@ -66,22 +69,35 @@ def init_deepfm(key, cfg: RecsysConfig) -> Params:
 
 def deepfm_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
     ids = batch["fields"]                               # (B, F)
-    e = params["embed"][ids]                            # (B, F, D)
-    # first order
-    first = params["linear"][ids].sum(axis=1)           # (B,)
-    # FM second order: 0.5 * ((sum e)^2 - sum e^2)
-    s = e.sum(axis=1)
-    fm = 0.5 * (jnp.square(s) - jnp.square(e).sum(axis=1)).sum(axis=-1)
-    # deep
-    deep_in = e.reshape(e.shape[0], -1)
-    n = len(cfg.mlp_dims) + 1
-    deep = _mlp_fwd(params["mlp"], deep_in, n)[:, 0]
-    return params["bias"] + first + fm + deep
+    with jax.named_scope("embedding"):
+        e = params["embed"][ids]                        # (B, F, D)
+        lin = params["linear"][ids]                     # (B, F)
+    with jax.named_scope("dense"):
+        # first order
+        first = lin.sum(axis=1)                         # (B,)
+        # FM second order: 0.5 * ((sum e)^2 - sum e^2)
+        s = e.sum(axis=1)
+        fm = 0.5 * (jnp.square(s) - jnp.square(e).sum(axis=1)).sum(axis=-1)
+        # deep
+        deep_in = e.reshape(e.shape[0], -1)
+        n = len(cfg.mlp_dims) + 1
+        deep = _mlp_fwd(params["mlp"], deep_in, n)[:, 0]
+        return params["bias"] + first + fm + deep
 
 
 # ---------------------------------------------------------------------------
 # YouTubeDNN (Private task)
 # ---------------------------------------------------------------------------
+
+def _lookup(params: Params, batch: dict
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Field, behaviour-sequence and target rows of the shared table:
+    (B, F, D), (B, L, D), (B, D)."""
+    with jax.named_scope("embedding"):
+        return (params["embed"][batch["fields"]],
+                params["embed"][batch["behavior"]],
+                params["embed"][batch["target"]])
+
 
 def init_youtubednn(key, cfg: RecsysConfig) -> Params:
     k1, k2 = jax.random.split(key)
@@ -96,14 +112,14 @@ def init_youtubednn(key, cfg: RecsysConfig) -> Params:
 
 def youtubednn_logit(params: Params, cfg: RecsysConfig, batch: dict
                      ) -> jax.Array:
-    e_fields = params["embed"][batch["fields"]]         # (B, F, D)
-    e_beh = params["embed"][batch["behavior"]]          # (B, L, D)
-    e_tgt = params["embed"][batch["target"]]            # (B, D)
-    pooled = e_beh.mean(axis=1)
-    x = jnp.concatenate(
-        [e_fields.reshape(e_fields.shape[0], -1), pooled, e_tgt], axis=-1)
-    n = len(cfg.mlp_dims) + 1
-    return _mlp_fwd(params["mlp"], x, n)[:, 0]
+    e_fields, e_beh, e_tgt = _lookup(params, batch)
+    with jax.named_scope("dense"):
+        pooled = e_beh.mean(axis=1)
+        x = jnp.concatenate(
+            [e_fields.reshape(e_fields.shape[0], -1), pooled, e_tgt],
+            axis=-1)
+        n = len(cfg.mlp_dims) + 1
+        return _mlp_fwd(params["mlp"], x, n)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -155,18 +171,18 @@ def init_dien(key, cfg: RecsysConfig) -> Params:
 
 
 def dien_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
-    e_fields = params["embed"][batch["fields"]]
-    e_beh = params["embed"][batch["behavior"]]          # (B, L, D)
-    e_tgt = params["embed"][batch["target"]]            # (B, D)
-    hs = _gru_scan(params["gru"], e_beh)                # (B, L, D)
-    # target-conditioned attention over interest states
-    att = jnp.einsum("bld,de,be->bl", hs, params["att_w"], e_tgt)
-    att = jax.nn.softmax(att, axis=-1)
-    interest = jnp.einsum("bl,bld->bd", att, hs)
-    x = jnp.concatenate(
-        [e_fields.reshape(e_fields.shape[0], -1), interest, e_tgt], axis=-1)
-    n = len(cfg.mlp_dims) + 1
-    return _mlp_fwd(params["mlp"], x, n)[:, 0]
+    e_fields, e_beh, e_tgt = _lookup(params, batch)
+    with jax.named_scope("dense"):
+        hs = _gru_scan(params["gru"], e_beh)            # (B, L, D)
+        # target-conditioned attention over interest states
+        att = jnp.einsum("bld,de,be->bl", hs, params["att_w"], e_tgt)
+        att = jax.nn.softmax(att, axis=-1)
+        interest = jnp.einsum("bl,bld->bd", att, hs)
+        x = jnp.concatenate(
+            [e_fields.reshape(e_fields.shape[0], -1), interest, e_tgt],
+            axis=-1)
+        n = len(cfg.mlp_dims) + 1
+        return _mlp_fwd(params["mlp"], x, n)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -190,8 +206,9 @@ def recsys_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
 def bce_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
     logit = recsys_logit(params, cfg, batch)
     label = batch["label"]
-    return jnp.mean(jnp.maximum(logit, 0) - logit * label
-                    + jnp.log1p(jnp.exp(-jnp.abs(logit))))
+    with jax.named_scope("dense"):
+        return jnp.mean(jnp.maximum(logit, 0) - logit * label
+                        + jnp.log1p(jnp.exp(-jnp.abs(logit))))
 
 
 def sparse_dense_split(params: Params) -> tuple[set[str], set[str]]:

@@ -103,3 +103,62 @@ def test_dequantize_compiles(one_chip, mode):
             interpret=False),
         _sds(one_chip, (4, 1 << 16), jnp.int8), sb, sb)
     assert "tpu_custom_call" in txt
+
+
+def test_replay_step_names_its_phases(one_chip):
+    """The DeepFM GBA replay step at a small table: every program scope is
+    in the optimized HLO's ``op_name`` metadata, the count kernel keeps the
+    name the roofline reader matches, and the benchmark's op -> phase map
+    gives a phase to nearly every instruction that runs."""
+    import json
+    import re
+    from pathlib import Path
+
+    from chipbench import phases
+
+    root = Path(__file__).resolve().parents[1] / "chipbench"
+    cfg = json.loads((root / "configs" / "deepfm-criteo.json").read_text())
+    traffic = json.loads((root / "traffic" / "gba_strained.json").read_text())
+    cfg = dict(cfg, hash_capacity=20000)
+    traffic = dict(traffic, workers=4, buffer_size=4, local_batch=128)
+    texts = phases.compiled_texts(cfg, traffic, one_chip)
+    assert len(texts) == 2          # versions shared, and stacked
+    cap, dim, m = cfg["hash_capacity"], cfg["embed_dim"], traffic["workers"]
+    for txt in texts:
+        scopes = {phases.scope_of(n)
+                  for n in re.findall(r'op_name="([^"]*)"', txt)}
+        assert set(phases.SCOPES) <= scopes
+        assert re.search(r"%_embedding_bag_grad_streamed[.\d]* = ", txt)
+        assert phases.coverage(txt) >= 0.9
+
+        # known ops land in their phase
+        phase_of = phases.module_phases(txt)
+        comps, entry = phases._computations(txt)
+
+        def runs(ins, seen=()):
+            """The ops an instruction runs, its own and those of the
+            computations it calls."""
+            out = {ins["op"]}
+            for name in re.findall(r"\b(?:calls|to_apply)=%([\w.\-]+)",
+                                   ins["rest"]):
+                if name not in seen:
+                    for inner in comps.get(name, ()):
+                        out |= runs(inner, (*seen, name))
+            return out
+
+        def phases_of(pick):
+            got = [phase_of[i["key"]] for i in comps[entry] if pick(i)]
+            assert got
+            return set(got)
+
+        # the count kernel
+        assert phases_of(lambda i: i["name"].startswith(
+            "_embedding_bag_grad_streamed")) == {"aggregate"}
+        # each slot's table gradient, scattered into (D, M * cap)
+        assert phases_of(lambda i: i["key"][1] == f"f32[{dim},{m * cap}]"
+                         and "scatter" in runs(i)) == {"embedding"}
+        # Adam's update of the table: a fusion that takes a square root
+        # and writes (cap, D)
+        assert phases_of(lambda i: i["op"] == "fusion"
+                         and f"f32[{cap},{dim}]" in i["key"][1]
+                         and "sqrt" in runs(i)) == {"apply"}
