@@ -16,6 +16,15 @@ inside the compiled step.  The previous implementation dispatched M
 sequential ``value_and_grad`` calls per step and accumulated masks in
 Python; the batched step removes that host round-trip from the PS hot loop.
 
+The stacked versions come from a second compiled program,
+``_stack_versions``: the M slots' source trees (a version shared by
+several slots appears once per slot) go in, the ``(M, ...)`` tree comes
+out, one dispatch a step instead of an eager ``jnp.stack`` per leaf (M+1
+programs each).  It is a copy, bit-identical to the eager stack; the step
+takes its result as the ``src_params`` argument.  A step whose slots all
+share one version (``shared_src``) stacks nothing: the step broadcasts
+that version.
+
 This gives the accuracy experiments (paper Figs. 2/6/7/8) exact parameter-
 server staleness semantics while remaining deterministic and laptop-fast.
 """
@@ -76,6 +85,12 @@ class VersionRing:
             return self._ring[version], False
         oldest = next(iter(self._ring))
         return self._ring[oldest], True
+
+
+# jit keys this on M and the leaves' shapes, so it compiles once per M.
+# Nothing is donated: the ring keeps its versions.
+_stack_versions = jax.jit(
+    lambda srcs: jax.tree.map(lambda *xs: jnp.stack(xs), *srcs))
 
 
 def _split_tree(grads: Params) -> tuple[Params, Params]:
@@ -249,8 +264,7 @@ class GBATrainer:
                     if shared_src:
                         src_params = srcs[0]
                     else:
-                        src_params = jax.tree.map(
-                            lambda *xs: jnp.stack(xs), *srcs)
+                        src_params = _stack_versions(tuple(srcs))
                         stats.stacked_steps += 1
                 with tracing.span("replay.inputs", day=day, k=k):
                     raw = [stream.batch(day, slot.batch_index)

@@ -1,4 +1,6 @@
 """Replay-trainer integration: PS semantics, mode parity, per-ID rescue."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,7 +109,6 @@ def test_streamed_presence_counts_match_default_path():
     through the DMA-streamed sorted-scatter kernel; the replayed parameters
     must match the XLA one-hot-scatter path exactly (same counts, same
     masks, same updates)."""
-    import dataclasses
     from repro.embeddings import StreamConfig
 
     cfg = dataclasses.replace(CRITEO_DEEPFM, name="criteo-deepfm-tiny",
@@ -136,3 +137,81 @@ def test_streamed_presence_counts_match_default_path():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7,
                                    err_msg=str(path))
+
+
+TINY = dataclasses.replace(CRITEO_DEEPFM, name="criteo-deepfm-tiny",
+                           hash_capacity=2048, mlp_dims=(32, 16))
+# 16 slots a step over 2-5 distinct versions each, as a strained GBA step
+# holds them: slot i of step k was dispatched LAG[k][i] steps back
+LAG = [[0] * 16,
+       [i % 2 for i in range(16)],
+       [(0, 1, 2, 0)[i % 4] for i in range(16)],
+       [min(i // 3, 4) for i in range(16)],
+       [(3, 0, 0, 1, 0, 2)[i % 6] for i in range(16)]]
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+def _tiny_stream():
+    return make_clickstream(TINY, seed=0, batches_per_day=128, batch_size=32)
+
+
+def _gba_lagged_schedule() -> Schedule:
+    return Schedule("gba", 32, [
+        [Slot(16 * k + i, max(0, k - lag), max(0, k - lag), 1.0)
+         for i, lag in enumerate(lags)]
+        for k, lags in enumerate(LAG)])
+
+
+def test_version_stack_matches_eager_stack_bitwise():
+    """The compiled stacker copies: the (M, ...) tree it returns equals the
+    eager per-leaf ``jnp.stack`` bit for bit, for 16 slots over 2-5
+    distinct versions (each version's buffer passed once per slot), and a
+    GBA replay through the trainer takes it."""
+    from repro.core.trainer import _stack_versions
+
+    versions = [init_recsys(jax.random.PRNGKey(s), TINY) for s in range(5)]
+    for distinct in (2, 3, 5):
+        srcs = [versions[(i * 7) % distinct] for i in range(16)]
+        got = _stack_versions(tuple(srcs))
+        want = jax.tree.map(lambda *xs: jnp.stack(xs), *srcs)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree.leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype, path
+            assert np.array_equal(np.asarray(g), np.asarray(w)), path
+
+    opt = get_optimizer("sgd", 0.05)
+    params = init_recsys(jax.random.PRNGKey(3), TINY)
+    _, _, _, stats = GBATrainer(TINY, opt, iota=2).replay(
+        params, opt.init(params), _gba_lagged_schedule(), _tiny_stream(),
+        day=0)
+    assert stats.stacked_steps == len(LAG) - 1 > 0
+
+
+def test_second_gba_replay_traces_and_compiles_nothing():
+    """Replaying the same GBA schedule again on the same trainer builds no
+    program: the stacker and both step variants are cached from the first
+    replay, so nothing retraces from step to step."""
+    stream = _tiny_stream()
+    opt = get_optimizer("sgd", 0.05)
+    params = init_recsys(jax.random.PRNGKey(4), TINY)
+    trainer = GBATrainer(TINY, opt, iota=2)
+    sched = _gba_lagged_schedule()
+    params, state, lu, stats = trainer.replay(params, opt.init(params),
+                                              sched, stream, day=0)
+    events = []
+
+    def on_event(event: str, duration: float, **_) -> None:
+        if event in COMPILE_EVENTS:
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        _, _, _, stats = trainer.replay(params, state, sched, stream, day=0,
+                                        last_update=lu, stats=stats)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert stats.applied_steps == 2 * len(LAG)
+    assert stats.stacked_steps == 2 * (len(LAG) - 1)
+    assert events == []
