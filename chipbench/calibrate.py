@@ -5,7 +5,8 @@
 
 In one process, for each seed: the cell's set-up and checked steps through
 the program, the float32 reference, and the control (the reference one
-step below the configuration's precision: ``reference.train.control``).
+step below the configuration's precision: ``reference.train.control``,
+named under ``control_name``).
 For each fault seed also the program with each planted fault the cell can
 have (``FAULTS``): half of every slot batch left out, the loss and
 gradient the mean over the other half; every GBA token one lower than the
@@ -73,6 +74,7 @@ FAULTS = {"half_batch": (half_batch, ("gba", "sync")),
 def readings(cfg: dict, traffic: dict, seed: int, *, hook=None,
              control: bool = True) -> dict:
     from chipbench import check
+    from chipbench.reference import train
     from chipbench.runners.recsys_replay import Cell
     cell = Cell(cfg, traffic, seed, trainer_hook=hook)
     cell.setup()
@@ -84,6 +86,7 @@ def readings(cfg: dict, traffic: dict, seed: int, *, hook=None,
            "relaxed_rows": ref["relaxed_rows"]}
     sides = {"ref": ref, "program": cell.prog}
     if control:
+        out["control_name"] = train.control_name(cfg)
         sides["control"] = ctl = cell.reference(control=True)
         c = check.compare(ctl, ref, cell.names, cfg)
         out["control"], out["control_by_step"] = c["numbers"], c["by_step"]

@@ -15,7 +15,11 @@ the next step's versions, inputs and dispatch counts in all four.
 
 Device side.  The compiled replay step carries the program's
 ``jax.named_scope`` phases (``embedding``, ``dense``, ``aggregate``,
-``apply``) in each instruction's ``op_name``.  Each step variant the cell
+``apply``, and the scopes a configuration lists under ``phases``, each
+nested inside one of the four) in each instruction's ``op_name``; the
+innermost one it names is its phase, so ops under ``dense/interest``
+count as ``interest`` where the configuration lists it, and ``dense``
+keeps the rest.  Each step variant the cell
 runs is compiled again, from the same abstract arguments as ``rehearse.py``
 under the configuration's matmul precision (a hit in the checkout's
 compilation cache), and every instruction is keyed by its name and result
@@ -26,7 +30,8 @@ fusion without one takes the most common phase of the computing
 instructions it fuses, where they have one.  A key that two variants give different phases is left out.
 
 Both sides return ``None`` where the program has nothing to read: no span
-store, no spans in the window, or no scopes in the compiled step.  The
+store, no spans in the window, no scopes in the compiled step, or no
+instruction in it that carries the phase asked for.  The
 device side also returns ``None`` where the phased ops cover less than
 ``MIN_PHASED`` of the window's busy time: the map no longer fits the
 executable the window ran (a recompile that differs, clashing keys,
@@ -36,11 +41,13 @@ gain.
 from __future__ import annotations
 
 import collections
+import functools
 import re
 import statistics
 
 from chipbench import trace as T
 
+# the program's phases; a configuration may add scopes nested in them
 SCOPES = ("embedding", "dense", "aggregate", "apply")
 STEP_SPAN = "chipbench.step"
 ANCHOR = "replay.inputs"
@@ -52,7 +59,6 @@ MOVES = frozenset({"parameter", "constant", "tuple", "get-tuple-element",
                    "bitcast", "reshape", "transpose", "copy", "broadcast",
                    "convert", "slice", "concatenate", "pad", "iota"})
 
-_SCOPE = re.compile(r"\b(" + "|".join(SCOPES) + r")\b")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+) = (?P<shape>.*?) "
                     r"(?P<op>[a-z][a-z0-9\-]*)\((?P<rest>.*)$")
 _COMP = re.compile(r"^(?P<entry>ENTRY )?%(?P<name>[\w.\-]+) .*\{\s*$")
@@ -163,13 +169,25 @@ def op_key(text: str) -> Key | None:
     return m["name"], _LAYOUT.sub("", m["shape"])
 
 
-def scope_of(op_name: str) -> str | None:
-    """The innermost program phase named in an ``op_name``."""
-    found = _SCOPE.findall(op_name)
+def scopes_of(cfg: dict) -> tuple[str, ...]:
+    """The phases of a configuration's step: the program's four and the
+    configuration's own ``phases``."""
+    return SCOPES + tuple(cfg.get("phases", ()))
+
+
+@functools.lru_cache(maxsize=None)
+def _scope_pattern(scopes: tuple[str, ...]) -> re.Pattern:
+    return re.compile(r"\b(" + "|".join(map(re.escape, scopes)) + r")\b")
+
+
+def scope_of(op_name: str, scopes: tuple[str, ...] = SCOPES) -> str | None:
+    """The innermost of ``scopes`` named in an ``op_name``."""
+    found = _scope_pattern(scopes).findall(op_name)
     return found[-1] if found else None
 
 
-def _computations(text: str) -> tuple[dict[str, list[dict]], str | None]:
+def _computations(text: str, scopes: tuple[str, ...] = SCOPES
+                  ) -> tuple[dict[str, list[dict]], str | None]:
     comps: dict[str, list[dict]] = {}
     entry, current = None, None
     for line in text.splitlines():
@@ -187,7 +205,8 @@ def _computations(text: str) -> tuple[dict[str, list[dict]], str | None]:
             "name": m["name"], "key": (m["name"],
                                        _LAYOUT.sub("", m["shape"])),
             "op": m["op"], "rest": m["rest"],
-            "phase": scope_of(op_name.group(1)) if op_name else None})
+            "phase": scope_of(op_name.group(1), scopes) if op_name
+            else None})
     return comps, entry
 
 
@@ -246,12 +265,13 @@ def _called(ins: dict) -> list[str]:
     return out
 
 
-def module_phases(text: str) -> dict[Key, str | None]:
+def module_phases(text: str, scopes: tuple[str, ...] = SCOPES
+                  ) -> dict[Key, str | None]:
     """Phase (or ``None``) of every instruction that runs as an op of its
     own in one compiled module: the entry computation's and, from there,
     those of loop bodies, conditions and branches, which fall back on the
     phase of the instruction that runs them."""
-    comps, entry = _computations(text)
+    comps, entry = _computations(text, scopes)
     out: dict[Key, str | None] = {}
     todo, seen = [(entry, None)], set()
     while todo:
@@ -270,15 +290,17 @@ def module_phases(text: str) -> dict[Key, str | None]:
     return out
 
 
-def op_phases(texts: list[str]) -> dict[Key, str] | None:
+def op_phases(texts: list[str], scopes: tuple[str, ...] = SCOPES
+              ) -> dict[Key, str] | None:
     """One key -> phase map over every step variant's compiled text;
-    ``None`` when no instruction carries a program scope."""
-    if not any(_SCOPE.search(m) for t in texts for m in _OP_NAME.findall(t)):
+    ``None`` when no instruction carries one of ``scopes``."""
+    pattern = _scope_pattern(scopes)
+    if not any(pattern.search(m) for t in texts for m in _OP_NAME.findall(t)):
         return None
     out: dict[Key, str] = {}
     clash: set[Key] = set()
     for text in texts:
-        for key, phase in module_phases(text).items():
+        for key, phase in module_phases(text, scopes).items():
             if phase is None:
                 continue
             if out.get(key, phase) != phase:
@@ -289,9 +311,9 @@ def op_phases(texts: list[str]) -> dict[Key, str] | None:
     return out
 
 
-def coverage(text: str) -> float:
+def coverage(text: str, scopes: tuple[str, ...] = SCOPES) -> float:
     """Share of a module's executed instructions that get a phase."""
-    phases = list(module_phases(text).values())
+    phases = list(module_phases(text, scopes).values())
     return sum(p is not None for p in phases) / max(len(phases), 1)
 
 
@@ -390,7 +412,9 @@ def idle_share(rec, span: str) -> float | None:
 
 def device_ms(rec, phase: str) -> float | None:
     """Device milliseconds per global step in ops of ``phase``; ``None``
-    where the phases cover less than ``MIN_PHASED`` of the busy time."""
+    where no instruction of the compiled step carries ``phase`` (a scope
+    the program lost would otherwise read as a gain), or where the phases
+    cover less than ``MIN_PHASED`` of the busy time."""
     if rec.trace is None or not rec.steps:
         return None
 
@@ -398,13 +422,14 @@ def device_ms(rec, phase: str) -> float | None:
         import json
         cell = json.dumps([rec.cfg, rec.traffic], sort_keys=True)
         if cell not in _MAPS:
-            _MAPS[cell] = op_phases(compiled_texts(rec.cfg, rec.traffic))
+            _MAPS[cell] = op_phases(compiled_texts(rec.cfg, rec.traffic),
+                                    scopes_of(rec.cfg))
         phase_of = _MAPS[cell]
         if phase_of is None or phased_share(rec.trace, phase_of) < MIN_PHASED:
             return None
-        return phase_seconds(rec.trace, phase_of)
+        return phase_of, phase_seconds(rec.trace, phase_of)
 
-    seconds = _once("device", rec, compute)
-    if seconds is None:
+    found = _once("device", rec, compute)
+    if found is None or phase not in found[0].values():
         return None
-    return 1e3 * seconds.get(phase, 0.0) / rec.steps
+    return 1e3 * found[1].get(phase, 0.0) / rec.steps

@@ -12,8 +12,12 @@ import math
 import jax
 import jax.numpy as jnp
 
+from chipbench.reference.precision import dot
+
 # the sparse module: the hashed tables, aggregated row by row
 SPARSE = ("embed", "linear")
+# every matrix product goes through precision.dot
+MATMULS_VIA_DOT = True
 
 
 def mlp_init(key, dims) -> dict:
@@ -28,7 +32,7 @@ def mlp_init(key, dims) -> dict:
 
 def mlp(p: dict, x: jax.Array, n: int) -> jax.Array:
     for i in range(n):
-        x = x @ p[f"w{i}"] + p[f"b{i}"]
+        x = dot(x, p[f"w{i}"]) + p[f"b{i}"]
         if i < n - 1:
             x = jax.nn.relu(x)
     return x

@@ -4,8 +4,8 @@
 It follows GBA (arXiv:2205.11048, Alg. 2) as the configuration and the
 traffic state it, one slot at a time, in ``jax.numpy``:
 
-- each slot's gradient of the mean binary cross-entropy, taken at the
-  parameter version of the slot's dispatch step;
+- each slot's gradient of the model's loss, taken at the parameter
+  version of the slot's dispatch step;
 - the dense module: the weighted sum of the slot gradients over M, where
   the weight is the schedule's Eq. (1) decision (0 or 1);
 - the sparse module (the model's ``SPARSE`` leaves): in GBA mode a slot
@@ -16,11 +16,24 @@ traffic state it, one slot at a time, in ``jax.numpy``:
 - Adam on every leaf, and ``last_update`` stamped with the step on every
   row some slot gave.
 
+The loss is the model module's own ``loss(p, cfg, batch)`` where it
+defines one, else the binary cross-entropy of its ``logit``.
+
 Nothing of the program is imported.  The reference runs float32 at
 ``highest`` matmul precision.  Its control (:func:`control`) runs one step
-below what the configuration states, float32 at the default precision:
-bfloat16, which casts the parameters, the model's arithmetic and the
-aggregation to it and keeps Adam in float32.
+below the precision the configuration states, emulated so that the CPU and
+the TPU compute the same thing (``CONTROLS``):
+
+- ``default``: bfloat16, which casts the parameters, the model's
+  arithmetic and the aggregation to it and keeps Adam in float32;
+- ``high``: every matrix product in one bfloat16 pass with float32 sums
+  (``precision.py``'s ``bf16``), as ``default`` runs on the TPU;
+- ``highest``: every matrix product as three bfloat16 passes
+  (``bf16_3x``), as ``high`` runs on the TPU.
+
+The last two need a module whose products all go through
+``precision.dot`` (``MATMULS_VIA_DOT``); for any other module the control
+would equal the reference, so :func:`control` refuses it.
 """
 from __future__ import annotations
 
@@ -30,15 +43,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.reference import model_module
+from chipbench.reference import model_module, precision
+
+# the matmul precision a float32 configuration states -> its control's
+# arguments to Reference
+CONTROLS = {"default": {"dtype": jnp.bfloat16},
+            "high": {"matmuls": "bf16"},
+            "highest": {"matmuls": "bf16_3x"}}
 
 
 def control(cfg: dict) -> dict:
     """The control: one step below the configuration's precision."""
-    if (cfg["dtype"], cfg["matmul_precision"]) != ("float32", "default"):
-        raise ValueError("no control is defined for "
-                         f"{cfg['dtype']} at {cfg['matmul_precision']}")
-    return {"dtype": jnp.bfloat16}
+    stated = cfg["matmul_precision"]
+    if cfg["dtype"] != "float32" or stated not in CONTROLS:
+        raise ValueError(f"no control is defined for {cfg['dtype']} at "
+                         f"{stated}")
+    kwargs = CONTROLS[stated]
+    if "matmuls" in kwargs and not getattr(model_module(cfg),
+                                           "MATMULS_VIA_DOT", False):
+        raise ValueError(
+            f"model {cfg['model']!r} states {stated} but does not compute "
+            "its products through precision.dot: its control would equal "
+            "the reference")
+    return dict(kwargs)
+
+
+def control_name(cfg: dict) -> str:
+    """What the control computes in bfloat16: ``bfloat16`` (everything)
+    or the matmul emulation's name."""
+    kwargs = control(cfg)
+    return kwargs.get("matmuls") or jnp.dtype(kwargs["dtype"]).name
 
 
 def bce(logit: jax.Array, label: jax.Array) -> jax.Array:
@@ -63,7 +97,11 @@ def adam(params, grads, state, opt: dict):
 class Reference:
     """Runs the first steps of a schedule and keeps what is compared."""
 
-    def __init__(self, cfg: dict, traffic: dict, dtype=jnp.float32):
+    def __init__(self, cfg: dict, traffic: dict, dtype=jnp.float32,
+                 matmuls: str | None = None):
+        """``dtype``: of the parameters and the model's arithmetic;
+        ``matmuls``: the emulation (``precision.PARTS``) every product of
+        the model traces under, ``None`` for plain products."""
         self.cfg = cfg
         self.traffic = traffic
         self.dtype = dt = jnp.dtype(dtype)
@@ -74,8 +112,11 @@ class Reference:
 
         def loss(p, batch):
             p = jax.tree.map(lambda x: x.astype(dt), p)
-            return bce(mod.logit(p, cfg, batch).astype(jnp.float32),
-                       batch["label"])
+            with precision.emulate(matmuls):
+                if hasattr(mod, "loss"):
+                    return mod.loss(p, cfg, batch).astype(jnp.float32)
+                return bce(mod.logit(p, cfg, batch).astype(jnp.float32),
+                           batch["label"])
 
         def accumulate(acc, version, batch, weight, slot_ok, token,
                        last_update, m):

@@ -317,3 +317,86 @@ def test_probe_step_busy_time_gets_a_phase(probe):
     # the version stack's own programs run outside the step and count in
     # no phase
     assert seconds[None] > 0
+
+
+# -- a configuration's own phases --------------------------------------------
+
+@pytest.fixture(scope="module")
+def nested_hlo() -> str:
+    """The CPU-compiled text of a small vmapped gradient step with a scope
+    ``interest`` nested inside ``dense``, as a model would nest its own
+    mechanism."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(w, x):
+        with jax.named_scope("dense"):
+            h = jnp.tanh(x @ w)
+            with jax.named_scope("interest"):
+                h = h + jnp.sin(h @ w)
+            return jnp.sum(h * h)
+
+    step = jax.jit(jax.vmap(jax.grad(loss), in_axes=(None, 0)))
+    return step.lower(jnp.ones((8, 8)), jnp.ones((4, 3, 8))).compile(
+    ).as_text()
+
+
+def test_a_configured_phase_takes_the_ops_nested_in_it(nested_hlo):
+    scopes = P.scopes_of({"phases": ["interest"]})
+    assert scopes == (*P.SCOPES, "interest")
+    own, four = P.op_phases([nested_hlo], scopes), P.op_phases([nested_hlo])
+    assert own.keys() == four.keys()
+    nested = {k for k, v in own.items() if v == "interest"}
+    assert nested and {four[k] for k in nested} == {"dense"}
+    assert {own[k] for k in own.keys() - nested} == {"dense"}
+    assert "interest" not in four.values()
+    assert P.scope_of("jit(step)/vmap(transpose(jvp(dense)))/interest/mul",
+                      scopes) == "interest"
+    assert P.scope_of("jit(step)/vmap(transpose(jvp(dense)))/interest/mul"
+                      ) == "dense"
+
+
+def test_device_ms_is_silent_for_a_phase_no_instruction_carries(
+        nested_hlo, monkeypatch):
+    monkeypatch.setattr(P, "_MAPS", {})
+    monkeypatch.setattr(P, "_DONE", {})
+    monkeypatch.setattr(P, "compiled_texts", lambda cfg, traffic:
+                        [nested_hlo])
+    declared = {"name": "nested", "phases": ["interest", "absent"]}
+    phase_of = P.op_phases([nested_hlo], P.scopes_of(declared))
+    # one op of each phase the step carries, a millisecond each
+    keys = {v: k for k, v in phase_of.items()}
+    ops = [T.Op(f"%{name} = {shape} fusion()", i * MS, (i + 1) * MS)
+           for i, (name, shape) in enumerate(keys.values())]
+    assert set(keys) == {"dense", "interest"}
+
+    def rec(cfg):
+        trace = T.Trace((0.0, 10 * MS), {"/device:TPU:0": ops})
+        return SimpleNamespace(trace=trace, steps=1, cfg=cfg,
+                               traffic={"mode": "sync"})
+
+    own, four = rec(declared), rec({"name": "nested"})
+    assert P.device_ms(own, "interest") == pytest.approx(1.0)
+    assert P.device_ms(own, "dense") == pytest.approx(1.0)
+    # declared, or one of the four, but carried by no instruction
+    assert P.device_ms(own, "absent") is None
+    assert P.device_ms(own, "embedding") is None
+    # without the configuration's list the nested ops are dense
+    assert P.device_ms(four, "interest") is None
+    assert P.device_ms(four, "dense") == pytest.approx(2.0)
+
+
+def test_probe_map_is_unchanged_by_configured_phases(probe):
+    """The probe's map, as the four fixed scopes gave it (577 keys, the
+    sha256 of their sorted JSON), for a configuration without ``phases``
+    and for one that lists a scope the probe's step does not carry."""
+    import hashlib
+    _, _, texts = probe
+    maps = [P.op_phases(texts), P.op_phases(texts, P.scopes_of({})),
+            P.op_phases(texts, P.scopes_of({"phases": ["interest"]}))]
+    assert maps[0] == maps[1] == maps[2]
+    items = json.dumps(sorted([list(k), v] for k, v in maps[0].items()))
+    assert len(maps[0]) == 577
+    assert hashlib.sha256(items.encode()).hexdigest() == (
+        "ca4734fd78db126592f2a22f860095fbfeae0175008fe9ea8001b042e81ec7d2")
+    assert [P.coverage(t) for t in texts] == [1.0, 1.0]
