@@ -112,9 +112,9 @@ class GBATrainer:
     embed_stream: StreamConfig | None = None
 
     def __post_init__(self):
+        # the model's own training loss (DIEN's carries its auxiliary term)
         self._loss_grad_fn = jax.value_and_grad(
-            lambda p, b: R.bce_loss(p, self.cfg, b))
-        self._loss_grad = jax.jit(self._loss_grad_fn)
+            lambda p, b: R.recsys_loss(p, self.cfg, b))
         # jitted batched-step cache keyed by (gba, m, shared_src); shapes
         # are fixed per (config, stream) so each key compiles once
         self._step_cache: dict[tuple, Any] = {}

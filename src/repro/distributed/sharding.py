@@ -106,8 +106,6 @@ def _leaf_spec(names: list[str], shape: tuple[int, ...], mesh: Mesh) -> P:
                     _maybe(core[1], mesh, "data"))
     if name == "conv_w":
         return spec(None, _maybe(core[1], mesh, "model"))
-    if name in ("wx", "wh"):                                # recsys GRU
-        return spec(None, None)
     # norms, biases, A_log, dt_bias, D_skip, scalars
     return spec(*([None] * len(core)))
 

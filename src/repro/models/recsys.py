@@ -7,12 +7,15 @@ categorical IDs (+ label).  The sparse module is the hashed embedding table
 is the dense module — exactly the paper's sparse/dense split, which GBA's
 per-ID staleness decay relies on.  The table lookups run under
 ``jax.named_scope("embedding")`` and the rest of each forward pass and the
-loss under ``"dense"``, so the compiled step's ops (backward ones as
+loss under ``"dense"`` (DIEN's interest layers under ``"dense/interest"``),
+so the compiled step's ops (backward ones as
 ``transpose(jvp(embedding))``) name the module they belong to.
+``recsys_loss`` is the loss each model trains on.
 
 Batch layout (from repro.data.clickstream):
   fields:   (B, num_fields) int32   hashed categorical features
-  behavior: (B, behavior_len) int32 hashed behavior-sequence IDs (DIEN/YTB)
+  behavior: (B, behavior_len) int32 hashed behavior-sequence IDs (DIEN/YTB;
+                                    DIEN: (item, category) pairs interleaved)
   target:   (B,) int32              hashed target-item ID (DIEN/YTB)
   label:    (B,) float32            click label
 """
@@ -123,66 +126,148 @@ def youtubednn_logit(params: Params, cfg: RecsysConfig, batch: dict
 
 
 # ---------------------------------------------------------------------------
-# DIEN (Alimama task) — GRU interest extraction + attention evolution (lite)
+# DIEN (Alimama task): Zhou et al., arXiv:1809.03672
 # ---------------------------------------------------------------------------
+#
+# A behaviour is an (item, category) pair, i_t = [e(item_t), e(cat_t)] of
+# H = 2D columns; ``behavior`` holds the pairs' ids interleaved, ``target``
+# the ad's item and ``fields[:, 1]`` its category (the other fields are the
+# user's profile).  The interest extractor is a GRU in the paper's gate
+# convention, h_t = (1 - u_t) h_{t-1} + u_t h~_t, trained also by the
+# auxiliary loss (Eq. 6): each state against the next clicked behaviour and
+# a negative, here the next behaviour of example (b + 1) mod B of the same
+# batch (the paper samples negatives).  Bilinear attention
+# a_t = softmax_t(h_t^T W e_a) scales the update gate of the AUGRU (the
+# interest evolution layer), whose last state joins the MLP input
+# [e(user), e_a, sum_t i_t, e_a * sum_t i_t, h'_T], as the authors' code
+# builds it; the MLP's hidden layers use Dice.  The authors' two-way
+# softmax output equals one logit under the binary cross-entropy.  The
+# extractor, the auxiliary loss, the attention and the AUGRU run under
+# ``jax.named_scope("interest")`` inside ``dense``.
 
-def _gru_init(key, d_in: int, d_h: int) -> Params:
+AUX_WEIGHT = 1.0    # alpha of the auxiliary loss, as the authors' code
+DICE_EPS = 1e-9     # the authors' ``dice`` epsilon
+
+
+def _dien_dims(cfg: RecsysConfig) -> tuple[int, int]:
+    """(T behaviour pairs, H = 2D), or raises where the batch layout
+    cannot hold DIEN's inputs."""
+    if cfg.behavior_len < 4 or cfg.behavior_len % 2:
+        raise ValueError("DIEN's behavior_len counts (item, category) ids "
+                         "of at least two pairs: an even number >= 4, got "
+                         f"{cfg.behavior_len}")
+    if cfg.num_fields < 2:
+        raise ValueError("DIEN reads the user from field 0 and the ad's "
+                         f"category from field 1; num_fields is "
+                         f"{cfg.num_fields}")
+    return cfg.behavior_len // 2, 2 * cfg.embed_dim
+
+
+def _gru_init(key, d: int) -> Params:
+    """Input d, hidden d; the 3d columns are the update gate, the reset
+    gate and the candidate state."""
     k1, k2 = jax.random.split(key)
     return {
-        "wx": jax.random.normal(k1, (d_in, 3 * d_h), jnp.float32)
-        / math.sqrt(d_in),
-        "wh": jax.random.normal(k2, (d_h, 3 * d_h), jnp.float32)
-        / math.sqrt(d_h),
-        "b": jnp.zeros((3 * d_h,), jnp.float32),
+        "w": jax.random.normal(k1, (d, 3 * d), jnp.float32) / math.sqrt(d),
+        "u": jax.random.normal(k2, (d, 3 * d), jnp.float32) / math.sqrt(d),
+        "b": jnp.zeros((3 * d,), jnp.float32),
     }
 
 
-def _gru_scan(p: Params, xs: jax.Array) -> jax.Array:
-    """xs: (B, L, Din) -> hidden states (B, L, Dh)."""
-    d_h = p["wh"].shape[0]
-    B = xs.shape[0]
+def _gru_scan(p: Params, xs: jax.Array, att: jax.Array | None = None
+              ) -> jax.Array:
+    """xs: (B, T, H) -> states (B, T, H), from h_0 = 0.  With ``att``
+    (B, T), an AUGRU: the update gate is scaled by a_t."""
+    d = p["u"].shape[0]
+    gx = jnp.moveaxis(xs @ p["w"] + p["b"], 1, 0)           # (T, B, 3H)
 
-    def step(h, x):
-        gx = x @ p["wx"] + p["b"]
-        gh = h @ p["wh"]
-        r = jax.nn.sigmoid(gx[:, :d_h] + gh[:, :d_h])
-        z = jax.nn.sigmoid(gx[:, d_h:2 * d_h] + gh[:, d_h:2 * d_h])
-        n = jnp.tanh(gx[:, 2 * d_h:] + r * gh[:, 2 * d_h:])
-        h = (1 - z) * n + z * h
+    def step(h, inp):
+        g, a_t = (inp, None) if att is None else inp
+        gh = h @ p["u"]
+        u = jax.nn.sigmoid(g[:, :d] + gh[:, :d])
+        r = jax.nn.sigmoid(g[:, d:2 * d] + gh[:, d:2 * d])
+        cand = jnp.tanh(g[:, 2 * d:] + r * gh[:, 2 * d:])
+        if att is not None:
+            u = a_t[:, None] * u
+        h = (1 - u) * h + u * cand
         return h, h
 
-    _, hs = lax.scan(step, jnp.zeros((B, d_h), jnp.float32),
-                     jnp.moveaxis(xs, 1, 0))
+    h0 = jnp.zeros((xs.shape[0], d), xs.dtype)
+    _, hs = lax.scan(step, h0, gx if att is None else (gx, att.T))
     return jnp.moveaxis(hs, 0, 1)
 
 
+def _aux_loss(hs: jax.Array, beh: jax.Array) -> jax.Array:
+    """Eq. 6: -mean over (b, t < T) of log s(<h_t, i_{t+1}>) +
+    log(1 - s(<h_t, i^_{t+1}>)), the negative from example (b + 1) mod B."""
+    h = hs[:, :-1]
+    pos = jnp.sum(h * beh[:, 1:], axis=-1)
+    neg = jnp.sum(h * jnp.roll(beh, -1, axis=0)[:, 1:], axis=-1)
+    return -jnp.mean(jax.nn.log_sigmoid(pos) + jax.nn.log_sigmoid(-neg))
+
+
+def _dice(x: jax.Array, alpha: jax.Array) -> jax.Array:
+    """Dice over the batch's own statistics, as the authors' ``dice``
+    computes them in training."""
+    mean = jnp.mean(x, axis=0)
+    std = jnp.sqrt(jnp.mean(jnp.square(x - mean), axis=0) + DICE_EPS)
+    p = jax.nn.sigmoid((x - mean) / (std + DICE_EPS))
+    return p * x + (1 - p) * alpha * x
+
+
 def init_dien(key, cfg: RecsysConfig) -> Params:
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    D = cfg.embed_dim
-    mlp_in = cfg.num_fields * D + D + D   # fields + final interest + target
-    dims = (mlp_in, *cfg.mlp_dims, 1)
+    _, h = _dien_dims(cfg)
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    d = cfg.embed_dim
+    dims = ((cfg.num_fields - 1) * d + 4 * h, *cfg.mlp_dims, 1)
+    mlp = _mlp_init(k5, dims) | {
+        f"dice{i}": jnp.zeros((n,), jnp.float32)
+        for i, n in enumerate(cfg.mlp_dims)}
     return {
-        "embed": jax.random.normal(k1, (cfg.hash_capacity, D),
+        "embed": jax.random.normal(k1, (cfg.hash_capacity, d),
                                    jnp.float32) * 0.01,
-        "gru": _gru_init(k2, D, D),
-        "att_w": jax.random.normal(k3, (D, D), jnp.float32) / math.sqrt(D),
-        "mlp": _mlp_init(k4, dims),
+        "gru": _gru_init(k2, h),
+        "att": jax.random.normal(k3, (h, h), jnp.float32) / math.sqrt(h),
+        "augru": _gru_init(k4, h),
+        "mlp": mlp,
     }
 
 
-def dien_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
+def _dien(params: Params, cfg: RecsysConfig, batch: dict
+          ) -> tuple[jax.Array, jax.Array]:
+    """(logit (B,), auxiliary loss ())."""
+    t, h = _dien_dims(cfg)
     e_fields, e_beh, e_tgt = _lookup(params, batch)
     with jax.named_scope("dense"):
-        hs = _gru_scan(params["gru"], e_beh)            # (B, L, D)
-        # target-conditioned attention over interest states
-        att = jnp.einsum("bld,de,be->bl", hs, params["att_w"], e_tgt)
-        att = jax.nn.softmax(att, axis=-1)
-        interest = jnp.einsum("bl,bld->bd", att, hs)
-        x = jnp.concatenate(
-            [e_fields.reshape(e_fields.shape[0], -1), interest, e_tgt],
-            axis=-1)
-        n = len(cfg.mlp_dims) + 1
-        return _mlp_fwd(params["mlp"], x, n)[:, 0]
+        b = e_fields.shape[0]
+        beh = e_beh.reshape(b, t, h)                        # i_t
+        ad = jnp.concatenate([e_tgt, e_fields[:, 1]], axis=-1)
+        user = jnp.concatenate([e_fields[:, :1], e_fields[:, 2:]], axis=1)
+        with jax.named_scope("interest"):
+            hs = _gru_scan(params["gru"], beh)
+            aux = _aux_loss(hs, beh)
+            q = ad @ params["att"].T                        # W e_a
+            att = jax.nn.softmax(jnp.sum(hs * q[:, None], axis=-1), axis=-1)
+            final = _gru_scan(params["augru"], hs, att)[:, -1]
+        pooled = beh.sum(axis=1)
+        x = jnp.concatenate([user.reshape(b, -1), ad, pooled, ad * pooled,
+                             final], axis=-1)
+        p, n = params["mlp"], len(cfg.mlp_dims) + 1
+        for i in range(n):
+            x = x @ p[f"w{i}"] + p[f"b{i}"]
+            if i < n - 1:
+                x = _dice(x, p[f"dice{i}"])
+        return x[:, 0], aux
+
+
+def dien_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
+    return _dien(params, cfg, batch)[0]
+
+
+def dien_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
+    logit, aux = _dien(params, cfg, batch)
+    with jax.named_scope("dense"):
+        return _bce(logit, batch["label"]) + AUX_WEIGHT * aux
 
 
 # ---------------------------------------------------------------------------
@@ -203,12 +288,24 @@ def recsys_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
     return _LOGIT[cfg.model](params, cfg, batch)
 
 
+def _bce(logit: jax.Array, label: jax.Array) -> jax.Array:
+    return jnp.mean(jnp.maximum(logit, 0) - logit * label
+                    + jnp.log1p(jnp.exp(-jnp.abs(logit))))
+
+
 def bce_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
     logit = recsys_logit(params, cfg, batch)
-    label = batch["label"]
     with jax.named_scope("dense"):
-        return jnp.mean(jnp.maximum(logit, 0) - logit * label
-                        + jnp.log1p(jnp.exp(-jnp.abs(logit))))
+        return _bce(logit, batch["label"])
+
+
+# models whose training loss is more than the cross-entropy of the logit
+_LOSS = {"dien": dien_loss}
+
+
+def recsys_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
+    """The mean training loss: ``bce_loss``, plus DIEN's auxiliary loss."""
+    return _LOSS.get(cfg.model, bce_loss)(params, cfg, batch)
 
 
 def sparse_dense_split(params: Params) -> tuple[set[str], set[str]]:

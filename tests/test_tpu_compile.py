@@ -109,7 +109,8 @@ def test_replay_step_names_its_phases(one_chip):
     """The DeepFM GBA replay step at a small table: every program scope is
     in the optimized HLO's ``op_name`` metadata, the count kernel keeps the
     name the roofline reader matches, and the benchmark's op -> phase map
-    gives a phase to nearly every instruction that runs."""
+    gives a phase to nearly every instruction that runs; DIEN's step
+    carries its ``interest`` scope inside ``dense``."""
     import json
     import re
     from pathlib import Path
@@ -162,3 +163,22 @@ def test_replay_step_names_its_phases(one_chip):
         assert phases_of(lambda i: i["op"] == "fusion"
                          and f"f32[{cap},{dim}]" in i["key"][1]
                          and "sqrt" in runs(i)) == {"apply"}
+
+    # DIEN's step: its interest layers (GRU, auxiliary loss, attention,
+    # AUGRU) carry a scope nested in dense, which the configuration names
+    # a phase of its own, and the map still covers the step; one variant
+    # (sync) shows it, as the recurrences make each compile slow
+    cfg = json.loads((root / "configs" / "dien-alimama.json").read_text())
+    cfg = dict(cfg, hash_capacity=20000)
+    traffic = json.loads((root / "traffic" / "sync_quiet.json").read_text())
+    traffic = dict(traffic, workers=4, local_batch=32)
+    scopes = phases.scopes_of(cfg)
+    assert "interest" in scopes
+    for txt in phases.compiled_texts(cfg, traffic, one_chip):
+        names = re.findall(r'op_name="([^"]*)"', txt)
+        nested = [n for n in names if re.search(r"\binterest/", n)]
+        assert nested and all(re.search(r"\bdense\)*/interest/", n)
+                              for n in nested)
+        assert {phases.scope_of(n, scopes) for n in names} >= set(scopes)
+        assert phases.coverage(txt, scopes) >= 0.9
+        assert "interest" in phases.module_phases(txt, scopes).values()
