@@ -266,6 +266,7 @@ def kernel_metas():
         gba_aggregate.launch_meta(1 << 16, 8),
         embedding_bag.fwd_launch_meta(32, 26, 100_000, 128),
         embedding_bag.bwd_launch_meta(32, 26, 100_000, 128),
+        embedding_bag.scatter_rows_launch_meta(32 * 26, 100_000, 18),
         flash_decode.launch_meta(4, 32_768, 8, 4, 128),
         quantize.quantize_launch_meta(8, 1 << 14, 2048, "minmax"),
         quantize.quantize_launch_meta(8, 1 << 14, 2048, "sign"),

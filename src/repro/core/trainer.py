@@ -12,7 +12,11 @@ per-slot gradients come from a single ``vmap`` over stacked parameter
 versions, and the whole aggregate — token-decay weighting of the dense
 module, per-ID mask/count accumulation for the sparse module, contributor
 normalization, optimizer update and ``last_update`` stamping — happens
-inside the compiled step.  The previous implementation dispatched M
+inside the compiled step.  A slot's loss is differentiated by the dense
+leaves and by the table rows the slot gathered, never by the table, so the
+sparse module's gradient is one weighted scatter of the step's M x n_ids
+rows into one table-sized array per leaf: work that grows with the ids,
+not with M copies of the table.  The previous implementation dispatched M
 sequential ``value_and_grad`` calls per step and accumulated masks in
 Python; the batched step removes that host round-trip from the PS hot loop.
 
@@ -41,7 +45,8 @@ import numpy as np
 from repro import tracing
 from repro.configs.recsys import RecsysConfig
 from repro.data.clickstream import ClickStream
-from repro.embeddings.table import StreamConfig, presence_counts
+from repro.embeddings.table import (StreamConfig, presence_counts,
+                                    scatter_rows, sparse_grads_to_dense)
 from repro.metrics import StreamingAUC
 from repro.models import recsys as R
 from repro.optim import Optimizer
@@ -49,7 +54,7 @@ from repro.sim.cluster import Schedule
 
 Params = Any
 
-EMBED_KEYS = ("embed", "linear")   # the sparse module (DESIGN.md §2)
+EMBED_KEYS = R.SPARSE_KEYS   # the sparse module
 
 
 @dataclass
@@ -59,6 +64,7 @@ class ReplayStats:
     dropped_slots: int = 0
     history_clamps: int = 0
     embed_rows_rescued: int = 0     # per-ID relaxation kept a stale slot's row
+    scattered_rows: int = 0         # (slot, id) rows the row scatter summed
     stacked_steps: int = 0          # steps that stacked M parameter versions
     # step variant (gba, m, shared_src) built -> the step (applied_steps)
     # whose call built it
@@ -93,12 +99,6 @@ _stack_versions = jax.jit(
     lambda srcs: jax.tree.map(lambda *xs: jnp.stack(xs), *srcs))
 
 
-def _split_tree(grads: Params) -> tuple[Params, Params]:
-    sparse = {k: v for k, v in grads.items() if k in EMBED_KEYS}
-    dense = {k: v for k, v in grads.items() if k not in EMBED_KEYS}
-    return sparse, dense
-
-
 @dataclass
 class GBATrainer:
     cfg: RecsysConfig
@@ -106,15 +106,16 @@ class GBATrainer:
     iota: int = 4
     per_id_embedding_decay: bool = True   # Alg. 2 lines 21/23
     history: int = 64
-    # production-capacity knob: when set, the per-slot presence counts come
-    # from the streamed sorted-scatter kernel (O(block) VMEM at any
-    # hash_capacity) instead of an XLA one-hot scatter per slot
+    # production-capacity knob: when set, the per-slot presence counts and
+    # the row scatter of the sparse gradient run through the streamed
+    # sorted-scatter kernel (O(block) VMEM at any hash_capacity) instead
+    # of XLA scatters
     embed_stream: StreamConfig | None = None
 
     def __post_init__(self):
-        # the model's own training loss (DIEN's carries its auxiliary term)
-        self._loss_grad_fn = jax.value_and_grad(
-            lambda p, b: R.recsys_loss(p, self.cfg, b))
+        # a slot's (loss, gradients); an attribute, so that a test can wrap
+        # it before a step is built
+        self._loss_grad_fn = self._slot_loss_grads
         # jitted batched-step cache keyed by (gba, m, shared_src); shapes
         # are fixed per (config, stream) so each key compiles once
         self._step_cache: dict[tuple, Any] = {}
@@ -123,11 +124,20 @@ class GBATrainer:
 
     def _flat_ids(self, batches: dict, m: int) -> jax.Array:
         """All hashed IDs each slot touched: (M, n_ids)."""
-        parts = [batches["fields"].reshape(m, -1)]
-        if "behavior" in batches:
-            parts.append(batches["behavior"].reshape(m, -1))
-            parts.append(batches["target"].reshape(m, -1))
-        return jnp.concatenate(parts, axis=1)
+        return jax.vmap(R.flat_ids)(batches).reshape(m, -1)
+
+    def _slot_loss_grads(self, params: Params, batch: dict):
+        """One slot's loss of the model's own training objective (DIEN's
+        carries its auxiliary term) and ``(dense, ids, rows)``: the
+        gradients of the dense leaves, the ids the slot looked up
+        (``R.flat_ids``) and the gradients of the rows it gathered for
+        them (``R.lookup``)."""
+        rows = R.lookup(params, batch)
+        dense = {k: v for k, v in params.items() if k not in EMBED_KEYS}
+        loss, (dense_g, row_g) = jax.value_and_grad(
+            lambda d, r: R.recsys_loss_from_rows(d, self.cfg, r, batch),
+            argnums=(0, 1))(dense, rows)
+        return loss, (dense_g, R.flat_ids(batch), row_g)
 
     def _make_step(self, gba: bool, m: int, shared_src: bool):
         """Build the jitted per-global-step function.
@@ -136,17 +146,26 @@ class GBATrainer:
         (sync-like schedules) — the gradients vmap over batches only, with
         the params broadcast, skipping the M-way parameter stack.
 
-        The GBA aggregation runs under ``jax.named_scope("aggregate")`` and
-        the optimizer and ``last_update`` stamp under ``"apply"``; the
-        models name their own ``embedding`` and ``dense`` ops.
+        The sparse module's gradient is the sum over every (slot, id) row
+        of the step of the row's gradient times its factor, Alg. 2 at that
+        pair: under GBA 1 for a slot within iota, else whether the id is
+        untouched since the slot's token (the per-ID relaxation); in the
+        other modes the slot's weight.  The sum is one scatter of the
+        sparse leaves side by side: the streamed sorted-scatter kernel
+        with ``embed_stream`` set, else one XLA scatter-add.  It runs
+        under ``jax.named_scope("embedding")``, the rest of the GBA
+        aggregation under ``"aggregate"``, and the optimizer and
+        ``last_update`` stamp under ``"apply"``; the models name their own
+        ``embedding`` and ``dense`` ops.
         """
         cap = self.cfg.hash_capacity
         iota = self.iota
         opt_update = self.optimizer.update
         grad_fn = self._loss_grad_fn
+        stream = self.embed_stream
         in_axes = (None, 0) if shared_src else (0, 0)
 
-        def aggregate(sparse_g, dense_g, batches, tokens, weights, step_k,
+        def aggregate(dense_g, batches, ids, tokens, weights, step_k,
                       last_update):
             # dense module: Alg. 2 line 22 — weighted sum / N_a (= m)
             wm = (weights / m).astype(jnp.float32)
@@ -157,7 +176,7 @@ class GBATrainer:
 
             # sparse module: per-ID treatment (Alg. 2 lines 21/23)
             ids_all = self._flat_ids(batches, m)
-            if self.embed_stream is not None:
+            if stream is not None:
                 # streamed counts: offsetting slot i's ids by i*cap turns
                 # the M per-slot histograms into ONE sorted-scatter kernel
                 # launch over an (M*cap)-row id space — a single sort, no
@@ -165,7 +184,7 @@ class GBATrainer:
                 slot_offset = (jnp.arange(m, dtype=jnp.int32) * cap)[:, None]
                 present = presence_counts(
                     ids_all + slot_offset, m * cap,
-                    stream=self.embed_stream).reshape(m, cap)
+                    stream=stream).reshape(m, cap)
             else:
                 present = jax.vmap(
                     lambda ids: jnp.zeros((cap,),
@@ -184,46 +203,64 @@ class GBATrainer:
                 rescued = jnp.sum(
                     ((~slot_ok) & (jnp.sum(row_mask, axis=1) > 0)
                      ).astype(jnp.int32))
-                emb_num = {
-                    name: jnp.sum(
-                        g * (row_mask[..., None] if g.ndim == 3
-                             else row_mask), axis=0)
-                    for name, g in sparse_g.items()
-                }
                 emb_cnt = jnp.sum(row_mask, axis=0)
+                # keep_row at each (slot, id) row (a row is touched); a
+                # step with no slot past iota keeps every row and reads
+                # no last_update
+                factor = jax.lax.cond(
+                    jnp.all(slot_ok),
+                    lambda: jnp.ones(ids.shape, jnp.float32),
+                    lambda: jnp.where(
+                        slot_ok[:, None], 1.0,
+                        (last_update[ids] <= tokens[:, None]
+                         ).astype(jnp.float32)))
             else:
                 # same denominator semantics as the GBA path: an ID's
                 # contributor count is the number of SLOTS that touched
                 # it (Alg. 2 line 23), not its occurrence count
-                emb_num = {
-                    name: jnp.tensordot(weights, g, axes=(0, 0))
-                    for name, g in sparse_g.items()
-                }
                 emb_cnt = jnp.sum(touched01 * weights[:, None], axis=0)
+                factor = jnp.broadcast_to(weights[:, None], ids.shape)
+            return agg, factor, emb_cnt, rescued
 
-            # embedding aggregate: divide by #slots that touched the ID
-            # (Alg. 2 line 23); baselines divide by the same rule for parity
-            full_grads = dict(agg)
-            cntc = jnp.maximum(emb_cnt, 1.0)
-            for name, g in emb_num.items():
-                full_grads[name] = g / (cntc[:, None] if g.ndim > 1
-                                        else cntc)
-            return full_grads, emb_cnt, rescued
+        def scatter(ids, factor, row_g, params):
+            """Each sparse leaf's sum of factor x row gradient, as one
+            scatter of the leaves' rows side by side.  ``ids`` is
+            (M, B, n_ids), each leaf's rows ``ids.shape + leaf row``."""
+            cols = {k: g.reshape(ids.shape + (-1,)) for k, g in row_g.items()}
+            rows = jnp.concatenate(list(cols.values()), axis=-1) * \
+                factor.reshape(ids.shape + (1,))
+            if stream is None:
+                table, _ = sparse_grads_to_dense(ids, rows, cap)
+            else:
+                table = scatter_rows(ids, rows, cap, stream=stream)
+            out, start = {}, 0
+            for k, c in cols.items():
+                width = c.shape[-1]
+                out[k] = table[:, start:start + width].reshape(
+                    params[k].shape)
+                start += width
+            return out
 
         def step(src_params, params, opt_state, batches, tokens, weights,
                  step_k, last_update):
-            losses, grads = jax.vmap(grad_fn, in_axes=in_axes)(
-                src_params, batches)
-            sparse_g, dense_g = _split_tree(grads)
+            losses, (dense_g, ids, row_g) = jax.vmap(
+                grad_fn, in_axes=in_axes)(src_params, batches)
             with jax.named_scope("aggregate"):
-                full_grads, emb_cnt, rescued = aggregate(
-                    sparse_g, dense_g, batches, tokens, weights, step_k,
-                    last_update)
+                grads, factor, emb_cnt, rescued = aggregate(
+                    dense_g, batches, ids.reshape(m, -1), tokens, weights,
+                    step_k, last_update)
+            with jax.named_scope("embedding"):
+                table_g = scatter(ids, factor, row_g, params)
+            with jax.named_scope("aggregate"):
+                # divide by #slots that touched the ID (Alg. 2 line 23);
+                # baselines divide by the same rule for parity
+                cntc = jnp.maximum(emb_cnt, 1.0)
+                for name, g in table_g.items():
+                    grads[name] = g / (cntc[:, None] if g.ndim > 1
+                                       else cntc)
             with jax.named_scope("apply"):
-                params, opt_state = opt_update(params, full_grads, opt_state)
-                if sparse_g:
-                    touched = emb_cnt > 0
-                    last_update = jnp.where(touched, step_k, last_update)
+                params, opt_state = opt_update(params, grads, opt_state)
+                last_update = jnp.where(emb_cnt > 0, step_k, last_update)
             return params, opt_state, last_update, losses, rescued
 
         return jax.jit(step)
@@ -247,13 +284,14 @@ class GBATrainer:
             last_update = jnp.zeros((self.cfg.hash_capacity,), jnp.int32)
         ring = VersionRing(self.history)
         gba = schedule.mode == "gba" and self.per_id_embedding_decay
+        slot_rows = schedule.local_batch * R.ids_per_example(self.cfg)
 
         for k, slots in enumerate(schedule.steps):
             m = len(slots)
             versions = {s.dispatch_step for s in slots}
             shared_src = len(versions) == 1
             with tracing.span("replay.step", day=day, k=k,
-                              stacked=len(versions)):
+                              stacked=len(versions), rows=m * slot_rows):
                 with tracing.span("replay.versions", day=day, k=k):
                     ring.put(k, params)
                     srcs = []
@@ -288,6 +326,7 @@ class GBATrainer:
                         stats.kept_slots += 1
                     else:
                         stats.dropped_slots += 1
+                stats.scattered_rows += m * slot_rows
                 with tracing.span("replay.readback", day=day, k=k):
                     stats.embed_rows_rescued += int(rescued)
                     stats.losses.append(float(jnp.mean(losses)))

@@ -21,7 +21,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels import embedding_bag, ops
 
 Params = dict[str, Any]
 
@@ -126,6 +126,19 @@ def presence_counts(hashed_ids: jax.Array, capacity: int, *,
         ids2d, zero_rows, capacity, block_v=s.block_v, block_d=s.block_d,
         chunk_e=s.chunk_e, interpret=s.interpret)
     return counts
+
+
+def scatter_rows(ids: jax.Array, rows: jax.Array, capacity: int, *,
+                 stream: StreamConfig | None = None) -> jax.Array:
+    """Each id's sum of the rows sent to it: ids (...) int32, rows
+    ``ids.shape + (D,)`` -> (capacity, D), through the streamed
+    sorted-scatter kernel: one sort, O(block) VMEM at any capacity, work
+    that grows with the ids and not with any per-row copy of the
+    table."""
+    s = stream or StreamConfig()
+    return embedding_bag.scatter_rows(
+        ids, rows, capacity, block_v=s.block_v, chunk_e=s.chunk_e,
+        interpret=s.interpret)
 
 
 def sparse_grads_to_dense(ids: jax.Array, rows: jax.Array, capacity: int

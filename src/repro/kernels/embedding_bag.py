@@ -32,6 +32,15 @@ vocabulary size ``V`` and the entry count ``E = B*F``.
   ``(BLOCK_V, W) @ (W, BLOCK_D)``; per-ID contributor counts (Alg. 2
   line 23) fall out of the same one-hot mask.
 
+* row scatter (``scatter_rows``): the backward with one row an id and
+  no counts, D-major in and out: the sorted rows stream as ``(D, W)``
+  windows, entries on lanes as the ids travel, each folded in as
+  ``rows_t @ onehot^T`` in three one-pass bfloat16 products, into the
+  ``(D, V)`` gradient; a narrow table pads D to 16 sublanes, not 128
+  lanes.  A trainer that differentiates by gathered rows sums a step's
+  rows into the table gradient with it, under a jitted entry of its own
+  name.
+
 TPU layout rules shape the streams.  The TPU DMAs HBM in whole 128-lane
 tiles, so ids travel lane-major (``(1, E)`` / ``(2, E)`` rows) and each
 chunk, which may start anywhere, moves as the lane-aligned window of
@@ -69,6 +78,7 @@ BLOCK_V = 512      # vocab rows per streamed table tile / backward out block
 CHUNK_E = 256      # sorted (id, row) entries consumed per pipeline step
 BLOCK_D = 128      # embedding columns per output tile (wide-D streaming)
 LANE = 128         # TPU lane width: HBM DMA windows are whole lane tiles
+ROW_SUBLANES = 16  # the row scatter's D pad: whole bfloat16 sublane tiles
 
 # one-hot matmuls must move table values exactly, so f32 contractions run
 # at full precision on the MXU (a no-op in interpret mode)
@@ -203,6 +213,41 @@ def bwd_launch_meta(b: int, f: int, v: int, d: int, row_dtype=jnp.float32,
     )
 
 
+def scatter_rows_launch_meta(e: int, v: int, d: int, *,
+                             block_v: int = BLOCK_V,
+                             chunk_e: int = CHUNK_E) -> LaunchMeta:
+    """Static launch geometry of the row scatter: one program per vocab
+    block, streaming its sorted run of ids and D-major rows
+    (``(D_pad, E_pad)``, D on sublanes, the entries on lanes as the ids
+    travel) and writing its ``(D_pad, BLOCK_V)`` block of the D-major
+    table gradient.  D pads to whole bfloat16 sublane tiles only."""
+    d_pad = _round_up(d, ROW_SUBLANES)
+    cap_pad = _round_up(v, block_v)
+    e_pad = _entry_pad(e, chunk_e)
+    w = _window(chunk_e)
+    vmem = 2 * w * 4 + 2 * d_pad * w * 4
+    return LaunchMeta(
+        kernel="embedding_bag_scatter_rows",
+        grid=(cap_pad // block_v,),
+        num_scalar_prefetch=1,
+        inputs=(
+            BlockMeta("sorted_ids", (1, e_pad), jnp.int32, memory_space=ANY),
+            BlockMeta("sorted_rows_t", (d_pad, e_pad), jnp.float32,
+                      memory_space=ANY),
+        ),
+        outputs=(
+            BlockMeta("gtable_t", (d_pad, cap_pad), jnp.float32,
+                      (d_pad, block_v), lambda i, *_: (0, i)),
+        ),
+        scratch=(
+            ScratchMeta("ids_buf", (2, 1, w), jnp.int32),
+            ScratchMeta("rows_buf", (2, d_pad, w), jnp.float32),
+        ),
+        declared_vmem_bytes=vmem,
+        vmem_counted=("ids_buf", "rows_buf"),
+    )
+
+
 # ---------------------------------------------------------------------------
 # shared XLA-side sort machinery
 # ---------------------------------------------------------------------------
@@ -222,8 +267,10 @@ def _sorted_entries(ids: jax.Array, capacity: int, block_v: int,
     flat = ids.reshape(-1).astype(jnp.int32)
     cap_pad = _round_up(capacity, block_v)
     flat = jnp.where((flat >= 0) & (flat < capacity), flat, cap_pad)
-    order = jnp.argsort(flat)
-    sorted_ids = flat[order]
+    # the sort carries the permutation along, so the sorted ids need no
+    # gather of their own (argsort's stable order)
+    sorted_ids, order = jax.lax.sort(
+        (flat, jnp.arange(e, dtype=jnp.int32)), num_keys=1, is_stable=True)
     nvb = cap_pad // block_v
     boundaries = jnp.arange(nvb + 1, dtype=jnp.int32) * block_v
     offsets = jnp.searchsorted(sorted_ids, boundaries).astype(jnp.int32)
@@ -455,27 +502,23 @@ def _reduce_window(acc, cnt, ids, rows, valid, vids):
     return acc, cnt
 
 
-def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
-                ids_buf, rows_buf, ids_sem, rows_sem, *,
-                block_v: int, chunk_e: int):
-    """Segment reduce for one (vocab block, D block) output tile.
+def _stream_run(offsets_ref, ids_hbm, ids_buf, ids_sem, rows_window,
+                rows_buf, rows_sem, fold, init, *, block_v: int,
+                chunk_e: int):
+    """Fold this program's sorted run (vocab block ``i``) into ``init``,
+    window by window: ``fold(carry, ids, rows, valid, vids)``.
 
     offsets_ref: (nvb+1,) SMEM — run boundaries in the sorted arrays
     ids_hbm:     (1, E_pad) HBM — sorted ids
-    rows_hbm:    (E_pad, D_pad) HBM — gradient rows in sorted-id order
-    gtable_ref:  (BLOCK_V, BLOCK_D) VMEM output tile owned by this program
-    counts_ref:  (1, 1, BLOCK_V) contributor counts (recomputed per D
-                 block — every D block of a vocab block derives the same)
-    ids_buf:     (2, 1, W) / rows_buf: (2, W, BLOCK_D) — double-buffered
-                 window pipeline
+    rows_window: ``(w0, w)`` -> the HBM slice of the rows of sorted
+                 entries ``[w0, w0 + w)``
+    ids_buf:     (2, 1, W) / rows_buf: (2, ...) — double-buffered window
+                 pipeline
     """
     i = pl.program_id(0)
-    j = pl.program_id(1)
     start = offsets_ref[i]
     end = offsets_ref[i + 1]
-    bd = gtable_ref.shape[1]
     w = ids_buf.shape[-1]
-    cols = _cols(rows_hbm, j, bd)
     nchunks = (end - start + chunk_e - 1) // chunk_e
 
     def dmas(slot, c):
@@ -483,7 +526,7 @@ def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
         return (
             pltpu.make_async_copy(ids_hbm.at[:, pl.ds(w0, w)],
                                   ids_buf.at[slot], ids_sem.at[slot]),
-            pltpu.make_async_copy(rows_hbm.at[pl.ds(w0, w), cols],
+            pltpu.make_async_copy(rows_window(w0, w),
                                   rows_buf.at[slot], rows_sem.at[slot]))
 
     @pl.when(nchunks > 0)
@@ -494,7 +537,6 @@ def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
     vids = i * block_v + jax.lax.broadcasted_iota(jnp.int32, (block_v, w), 0)
 
     def body(c, carry):
-        acc, cnt = carry
         cur = c % 2
 
         @pl.when(c + 1 < nchunks)
@@ -505,17 +547,72 @@ def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
         for dma in dmas(cur, c):
             dma.wait()
         _, valid = _chunk_window(start + c * chunk_e, end, chunk_e, w)
-        return _reduce_window(acc, cnt, ids_buf[cur], rows_buf[cur], valid,
-                              vids)
+        return fold(carry, ids_buf[cur], rows_buf[cur], valid, vids)
 
-    acc, cnt = jax.lax.fori_loop(
-        0, nchunks, body,
+    return jax.lax.fori_loop(0, nchunks, body, init)
+
+
+def _bwd_kernel(offsets_ref, ids_hbm, rows_hbm, gtable_ref, counts_ref,
+                ids_buf, rows_buf, ids_sem, rows_sem, *,
+                block_v: int, chunk_e: int):
+    """Segment reduce for one (vocab block, D block) output tile.
+
+    rows_hbm:    (E_pad, D_pad) HBM — gradient rows in sorted-id order
+    gtable_ref:  (BLOCK_V, BLOCK_D) VMEM output tile owned by this program
+    counts_ref:  (1, 1, BLOCK_V) contributor counts (recomputed per D
+                 block — every D block of a vocab block derives the same)
+    rows_buf:    (2, W, BLOCK_D)
+    """
+    bd = gtable_ref.shape[1]
+    cols = _cols(rows_hbm, pl.program_id(1), bd)
+    acc, cnt = _stream_run(
+        offsets_ref, ids_hbm, ids_buf, ids_sem,
+        lambda w0, w: rows_hbm.at[pl.ds(w0, w), cols], rows_buf, rows_sem,
+        lambda carry, *window: _reduce_window(*carry, *window),
         (jnp.zeros((block_v, bd), jnp.float32),
-         jnp.zeros((1, block_v), jnp.float32)))
+         jnp.zeros((1, block_v), jnp.float32)),
+        block_v=block_v, chunk_e=chunk_e)
     gtable_ref[...] = acc
     counts_ref[0] = cnt
 
 
+def _scatter_rows_kernel(offsets_ref, ids_hbm, rows_t_hbm, gtable_t_ref,
+                         ids_buf, rows_buf, ids_sem, rows_sem, *,
+                         block_v: int, chunk_e: int):
+    """The row scatter for one vocab block, D-major in and out.
+
+    rows_t_hbm:   (D_pad, E_pad) HBM — the rows in sorted-id order, one
+                  lane per entry
+    gtable_t_ref: (D_pad, BLOCK_V) VMEM block of the (D_pad, V) gradient
+    rows_buf:     (2, D_pad, W)
+
+    A window folds in as ``rows_t @ onehot^T``.  A float32 row is the sum
+    of three bfloat16 parts, and a 0/1 one-hot times a bfloat16 part is
+    exact, so three one-pass products with float32 sums move the rows
+    exactly."""
+
+    def fold(acc, ids, rows_t, valid, vids):
+        onehot = ((ids == vids) & valid).astype(jnp.bfloat16)  # (V, W)
+        rest = rows_t
+        for _ in range(3):
+            part = rest.astype(jnp.bfloat16)
+            acc = acc + jax.lax.dot_general(
+                part, onehot, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32)            # (D, V)
+            rest = rest - part.astype(jnp.float32)
+        return acc
+
+    gtable_t_ref[...] = _stream_run(
+        offsets_ref, ids_hbm, ids_buf, ids_sem,
+        lambda w0, w: rows_t_hbm.at[:, pl.ds(w0, w)], rows_buf, rows_sem,
+        fold, jnp.zeros(gtable_t_ref.shape, jnp.float32),
+        block_v=block_v, chunk_e=chunk_e)
+
+
+# A compiled program names each kernel call after the jitted entry that
+# made it, so the pooled backward (and the presence counts) and the row
+# scatter stand apart in a trace.
 @functools.partial(
     jax.jit,
     static_argnames=("capacity", "block_v", "block_d", "chunk_e",
@@ -552,6 +649,55 @@ def _embedding_bag_grad_streamed(ids: jax.Array, grad_out: jax.Array,
         interpret=interpret,
     )(offsets, sorted_ids.reshape(1, -1), sorted_rows)
     return gtable[:capacity, :d], counts.reshape(-1)[:capacity]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("capacity", "block_v", "chunk_e", "interpret"))
+def _scatter_rows_streamed(ids: jax.Array, rows: jax.Array, capacity: int,
+                           *, block_v: int, chunk_e: int, interpret: bool
+                           ) -> jax.Array:
+    d = rows.shape[-1]
+    sorted_ids, order, offsets, cap_pad, nvb = _sorted_entries(
+        ids, capacity, block_v, chunk_e)
+    # gathered where they lie, with no flattening copy, and turned
+    # D-major: the entries on lanes, as the ids travel
+    rows_t = rows[jnp.unravel_index(order, ids.shape)].astype(jnp.float32).T
+    meta = scatter_rows_launch_meta(ids.size, capacity, d, block_v=block_v,
+                                    chunk_e=chunk_e)
+    d_pad = meta.inputs[1].array_shape[0]
+    rows_t = jnp.pad(rows_t, ((0, d_pad - d),
+                              (0, sorted_ids.shape[0] - rows_t.shape[1])))
+    (gtable_t,) = pl.pallas_call(
+        functools.partial(_scatter_rows_kernel, block_v=block_v,
+                          chunk_e=chunk_e),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=meta.num_scalar_prefetch,
+            grid=meta.grid,
+            in_specs=block_specs(meta.inputs),
+            out_specs=block_specs(meta.outputs),
+            scratch_shapes=scratch_shapes(meta.scratch) + [
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(o.array_shape, o.dtype)
+                   for o in meta.outputs],
+        interpret=interpret,
+    )(offsets, sorted_ids.reshape(1, -1), rows_t)
+    return gtable_t[:d, :capacity].T
+
+
+def scatter_rows(ids: jax.Array, rows: jax.Array, capacity: int, *,
+                 block_v: int | None = None, chunk_e: int | None = None,
+                 interpret: bool | None = None) -> jax.Array:
+    """Sum rows into a table: ids (...), rows ``ids.shape + (D,)`` ->
+    (capacity, D), each row the sum of the rows whose id it is.
+
+    The backward's sort and streamed segment reduce with one row an id,
+    D-major throughout.  Out-of-range ids are dropped."""
+    return _scatter_rows_streamed(
+        ids, rows, capacity, block_v=block_v or BLOCK_V,
+        chunk_e=chunk_e or CHUNK_E, interpret=runtime.resolve(interpret))
 
 
 def embedding_bag_grad(ids: jax.Array, grad_out: jax.Array, capacity: int,

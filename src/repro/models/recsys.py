@@ -5,12 +5,18 @@ function of ``(params, batch) -> logit`` where ``batch`` is a dict of hashed
 categorical IDs (+ label).  The sparse module is the hashed embedding table
 (``params["embed"]`` and, for DeepFM, ``params["linear"]``); everything else
 is the dense module — exactly the paper's sparse/dense split, which GBA's
-per-ID staleness decay relies on.  The table lookups run under
-``jax.named_scope("embedding")`` and the rest of each forward pass and the
-loss under ``"dense"`` (DIEN's interest layers under ``"dense/interest"``),
-so the compiled step's ops (backward ones as
-``transpose(jvp(embedding))``) name the module they belong to.
-``recsys_loss`` is the loss each model trains on.
+per-ID staleness decay relies on.
+
+Each model is split at its table lookup.  ``lookup`` gathers a batch's
+rows, in the order ``flat_ids`` lists the ids; the ``*_from_rows``
+functions compute the rest of the model and its loss from those rows and
+the dense module alone, so a trainer can differentiate the loss by the
+gathered rows and never by the table.  ``recsys_logit`` and
+``recsys_loss`` (the loss each model trains on) compose the two.  The
+lookup runs under ``jax.named_scope("embedding")`` and the rest of each
+forward pass and the loss under ``"dense"`` (DIEN's interest layers under
+``"dense/interest"``), so the compiled step's ops (backward ones as
+``transpose(jvp(dense))``) name the module they belong to.
 
 Batch layout (from repro.data.clickstream):
   fields:   (B, num_fields) int32   hashed categorical features
@@ -52,6 +58,40 @@ def _mlp_fwd(p: Params, x: jax.Array, n: int, final_act: bool = False
     return x
 
 
+SPARSE_KEYS = ("embed", "linear")   # the sparse module's tables
+
+
+def flat_ids(batch: dict) -> jax.Array:
+    """The ids each example looks up, (B, n_ids): its fields, then the
+    behaviour sequence and the target where the batch has them."""
+    parts = [batch["fields"]]
+    if "behavior" in batch:
+        parts += [batch["behavior"], batch["target"][:, None]]
+    return jnp.concatenate(parts, axis=1)
+
+
+def ids_per_example(cfg: RecsysConfig) -> int:
+    """``flat_ids``' n_ids for a batch drawn for ``cfg``."""
+    return cfg.num_fields + (cfg.behavior_len + 1 if cfg.behavior_len
+                             else 0)
+
+
+def lookup(params: Params, batch: dict) -> Params:
+    """The batch's rows of each table, in ``flat_ids`` order: ``embed``
+    (B, n_ids, D) and, for DeepFM, ``linear`` (B, n_ids)."""
+    ids = flat_ids(batch)
+    with jax.named_scope("embedding"):
+        return {k: params[k][ids] for k in SPARSE_KEYS if k in params}
+
+
+def _split_rows(cfg: RecsysConfig, e: jax.Array
+                ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Field, behaviour-sequence and target rows of ``lookup``'s
+    ``embed``: (B, F, D), (B, L, D), (B, D)."""
+    f, n = cfg.num_fields, cfg.behavior_len
+    return e[:, :f], e[:, f:f + n], e[:, f + n]
+
+
 # ---------------------------------------------------------------------------
 # DeepFM (Criteo task)
 # ---------------------------------------------------------------------------
@@ -70,11 +110,9 @@ def init_deepfm(key, cfg: RecsysConfig) -> Params:
     }
 
 
-def deepfm_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
-    ids = batch["fields"]                               # (B, F)
-    with jax.named_scope("embedding"):
-        e = params["embed"][ids]                        # (B, F, D)
-        lin = params["linear"][ids]                     # (B, F)
+def deepfm_logit_from_rows(params: Params, cfg: RecsysConfig, rows: Params
+                           ) -> jax.Array:
+    e, lin = rows["embed"], rows["linear"]              # (B, F, D), (B, F)
     with jax.named_scope("dense"):
         # first order
         first = lin.sum(axis=1)                         # (B,)
@@ -92,16 +130,6 @@ def deepfm_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
 # YouTubeDNN (Private task)
 # ---------------------------------------------------------------------------
 
-def _lookup(params: Params, batch: dict
-            ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Field, behaviour-sequence and target rows of the shared table:
-    (B, F, D), (B, L, D), (B, D)."""
-    with jax.named_scope("embedding"):
-        return (params["embed"][batch["fields"]],
-                params["embed"][batch["behavior"]],
-                params["embed"][batch["target"]])
-
-
 def init_youtubednn(key, cfg: RecsysConfig) -> Params:
     k1, k2 = jax.random.split(key)
     mlp_in = (cfg.num_fields + 2) * cfg.embed_dim  # fields + pooled + target
@@ -113,10 +141,10 @@ def init_youtubednn(key, cfg: RecsysConfig) -> Params:
     }
 
 
-def youtubednn_logit(params: Params, cfg: RecsysConfig, batch: dict
-                     ) -> jax.Array:
-    e_fields, e_beh, e_tgt = _lookup(params, batch)
+def youtubednn_logit_from_rows(params: Params, cfg: RecsysConfig,
+                               rows: Params) -> jax.Array:
     with jax.named_scope("dense"):
+        e_fields, e_beh, e_tgt = _split_rows(cfg, rows["embed"])
         pooled = e_beh.mean(axis=1)
         x = jnp.concatenate(
             [e_fields.reshape(e_fields.shape[0], -1), pooled, e_tgt],
@@ -233,12 +261,12 @@ def init_dien(key, cfg: RecsysConfig) -> Params:
     }
 
 
-def _dien(params: Params, cfg: RecsysConfig, batch: dict
-          ) -> tuple[jax.Array, jax.Array]:
+def _dien_from_rows(params: Params, cfg: RecsysConfig, rows: Params
+                    ) -> tuple[jax.Array, jax.Array]:
     """(logit (B,), auxiliary loss ())."""
     t, h = _dien_dims(cfg)
-    e_fields, e_beh, e_tgt = _lookup(params, batch)
     with jax.named_scope("dense"):
+        e_fields, e_beh, e_tgt = _split_rows(cfg, rows["embed"])
         b = e_fields.shape[0]
         beh = e_beh.reshape(b, t, h)                        # i_t
         ad = jnp.concatenate([e_tgt, e_fields[:, 1]], axis=-1)
@@ -260,12 +288,14 @@ def _dien(params: Params, cfg: RecsysConfig, batch: dict
         return x[:, 0], aux
 
 
-def dien_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
-    return _dien(params, cfg, batch)[0]
+def dien_logit_from_rows(params: Params, cfg: RecsysConfig, rows: Params
+                         ) -> jax.Array:
+    return _dien_from_rows(params, cfg, rows)[0]
 
 
-def dien_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
-    logit, aux = _dien(params, cfg, batch)
+def dien_loss_from_rows(params: Params, cfg: RecsysConfig, rows: Params,
+                        batch: dict) -> jax.Array:
+    logit, aux = _dien_from_rows(params, cfg, rows)
     with jax.named_scope("dense"):
         return _bce(logit, batch["label"]) + AUX_WEIGHT * aux
 
@@ -276,16 +306,22 @@ def dien_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
 
 _INIT = {"deepfm": init_deepfm, "youtubednn": init_youtubednn,
          "dien": init_dien}
-_LOGIT = {"deepfm": deepfm_logit, "youtubednn": youtubednn_logit,
-          "dien": dien_logit}
+_LOGIT_FROM_ROWS = {"deepfm": deepfm_logit_from_rows,
+                    "youtubednn": youtubednn_logit_from_rows,
+                    "dien": dien_logit_from_rows}
 
 
 def init_recsys(key, cfg: RecsysConfig) -> Params:
     return _INIT[cfg.model](key, cfg)
 
 
+def recsys_logit_from_rows(params: Params, cfg: RecsysConfig, rows: Params
+                           ) -> jax.Array:
+    return _LOGIT_FROM_ROWS[cfg.model](params, cfg, rows)
+
+
 def recsys_logit(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
-    return _LOGIT[cfg.model](params, cfg, batch)
+    return recsys_logit_from_rows(params, cfg, lookup(params, batch))
 
 
 def _bce(logit: jax.Array, label: jax.Array) -> jax.Array:
@@ -293,23 +329,40 @@ def _bce(logit: jax.Array, label: jax.Array) -> jax.Array:
                     + jnp.log1p(jnp.exp(-jnp.abs(logit))))
 
 
-def bce_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
-    logit = recsys_logit(params, cfg, batch)
+def bce_loss_from_rows(params: Params, cfg: RecsysConfig, rows: Params,
+                       batch: dict) -> jax.Array:
+    logit = recsys_logit_from_rows(params, cfg, rows)
     with jax.named_scope("dense"):
         return _bce(logit, batch["label"])
 
 
 # models whose training loss is more than the cross-entropy of the logit
-_LOSS = {"dien": dien_loss}
+_LOSS_FROM_ROWS = {"dien": dien_loss_from_rows}
+
+
+def recsys_loss_from_rows(params: Params, cfg: RecsysConfig, rows: Params,
+                          batch: dict) -> jax.Array:
+    """The mean training loss of a batch from its ``lookup`` rows; of
+    ``params`` only the dense module is read."""
+    return _LOSS_FROM_ROWS.get(cfg.model, bce_loss_from_rows)(
+        params, cfg, rows, batch)
+
+
+def bce_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
+    return bce_loss_from_rows(params, cfg, lookup(params, batch), batch)
+
+
+def dien_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
+    return dien_loss_from_rows(params, cfg, lookup(params, batch), batch)
 
 
 def recsys_loss(params: Params, cfg: RecsysConfig, batch: dict) -> jax.Array:
     """The mean training loss: ``bce_loss``, plus DIEN's auxiliary loss."""
-    return _LOSS.get(cfg.model, bce_loss)(params, cfg, batch)
+    return recsys_loss_from_rows(params, cfg, lookup(params, batch), batch)
 
 
 def sparse_dense_split(params: Params) -> tuple[set[str], set[str]]:
     """Top-level param names belonging to the sparse vs dense module."""
-    sparse = {k for k in params if k in ("embed", "linear")}
+    sparse = {k for k in params if k in SPARSE_KEYS}
     dense = set(params) - sparse
     return sparse, dense

@@ -157,18 +157,30 @@ def test_each_mutation_breaks_the_comparison(case, monkeypatch, mutate):
 
 
 def test_deepfm_trains_on_the_cross_entropy_unchanged():
-    """For DeepFM the trainer differentiates exactly ``bce_loss``: the same
-    jaxpr, so its compiled step is unchanged."""
+    """For DeepFM the trainer differentiates exactly ``bce_loss``, by the
+    dense leaves and by the gathered rows: the same loss, the same dense
+    gradients, and row gradients that sum, id by id, to its gradients of
+    both tables."""
     cfg = RecsysConfig(name="deepfm-test", model="deepfm", num_fields=3,
                        hash_capacity=97, embed_dim=4, mlp_dims=(8,))
     trainer = GBATrainer(cfg, get_optimizer("adam", 1e-3))
-    params = R.init_recsys(jax.random.PRNGKey(0), cfg)
-    batch = {"fields": jnp.zeros((4, 3), jnp.int32),
-             "label": jnp.zeros((4,), jnp.float32)}
-    got = jax.make_jaxpr(trainer._loss_grad_fn)(params, batch)
-    want = jax.make_jaxpr(jax.value_and_grad(
+    params = perturbed(R.init_recsys(jax.random.PRNGKey(0), cfg))
+    rng = np.random.default_rng(1)
+    batch = {"fields": jnp.asarray(rng.integers(0, 9, (4, 3)), jnp.int32),
+             "label": jnp.asarray([1, 0, 1, 0], jnp.float32)}
+    loss, (dense, ids, rows) = jax.jit(trainer._loss_grad_fn)(params, batch)
+    want_loss, want = jax.jit(jax.value_and_grad(
         lambda p, b: R.bce_loss(p, cfg, b)))(params, batch)
-    assert str(got) == str(want)
+    assert float(loss) == float(want_loss)
+    assert set(dense) | set(rows) == set(want)
+    for k, g in dense.items():
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(want[k])):
+            np.testing.assert_allclose(a, b, rtol=1e-6, err_msg=k)
+    assert ids.shape == (4, 3)
+    for k, g in rows.items():
+        table = jnp.zeros(params[k].shape).at[ids].add(g)
+        np.testing.assert_allclose(table, want[k], rtol=1e-6, atol=1e-9,
+                                   err_msg=k)
 
 
 @pytest.mark.parametrize("behavior_len,num_fields", [(11, 2), (2, 2),

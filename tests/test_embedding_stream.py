@@ -12,7 +12,8 @@ from repro.kernels import runtime
 from repro.kernels.embedding_bag import (BLOCK_D, BLOCK_V, CHUNK_E,
                                          embedding_bag, embedding_bag_grad,
                                          embedding_bag_grad_resident,
-                                         lane_dense, stream_vmem_bytes)
+                                         lane_dense, scatter_rows,
+                                         stream_vmem_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -148,6 +149,34 @@ def test_streamed_grad_bit_identical_to_resident(b, f, v, d):
     gtr, cntr = embedding_bag_grad_resident(ids, gout, v)
     assert np.array_equal(np.asarray(gt), np.asarray(gtr))
     assert np.array_equal(np.asarray(cnt), np.asarray(cntr))
+
+
+@pytest.mark.parametrize("n,v,d", [(300, 50, 11), (4096, 2000, 18),
+                                   (1000, 100_003, 1), (257, 700, 260)])
+def test_scatter_rows_sums_each_ids_rows(n, v, d):
+    """One row an id: each table row is the sum of the rows sent to it,
+    and out-of-range ids are dropped."""
+    key = jax.random.PRNGKey(n)
+    ids = jax.random.randint(key, (n,), -3, v + 3)
+    rows = jax.random.normal(jax.random.fold_in(key, 1), (n, d))
+    got = scatter_rows(ids, rows, v)
+    valid = (ids >= 0) & (ids < v)
+    want = jnp.zeros((v, d)).at[jnp.where(valid, ids, v)].add(rows,
+                                                              mode="drop")
+    assert got.shape == (v, d)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_scatter_rows_moves_rows_exactly():
+    """Each id once: the table holds every float32 row bit for bit (three
+    bfloat16 parts, each product with the one-hot exact)."""
+    v, d = 3000, 18
+    key = jax.random.PRNGKey(4)
+    ids = jax.random.permutation(key, v)[:1000]
+    rows = jax.random.normal(jax.random.fold_in(key, 1), (1000, d)) * 1e3
+    got = scatter_rows(ids, rows, v)
+    assert np.array_equal(np.asarray(got[ids]), np.asarray(rows))
 
 
 def test_streamed_grad_parity_1m_vocab():

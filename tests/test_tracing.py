@@ -90,7 +90,9 @@ def test_each_step_records_its_span_and_four_children_in_order(traced):
         # a span is stored as it ends: the children, then the step
         assert [s.name for s in step] == CHILDREN + ["replay.step"]
         parent = step[-1]
-        assert parent.attrs == {"day": 0, "k": k, "stacked": len(set(ds))}
+        # the row scatter sums every id of the step's 4 slots of 32
+        assert parent.attrs == {"day": 0, "k": k, "stacked": len(set(ds)),
+                                "rows": 4 * 32 * CFG.num_fields}
         for child, prev in zip(step[:4], [None] + step[:3]):
             assert child.attrs == {"day": 0, "k": k}
             assert parent.start_ns <= child.start_ns < child.end_ns \
@@ -134,3 +136,4 @@ def test_stacked_steps_and_step_builds():
     assert stats.applied_steps == 8
     assert stats.stacked_steps == 4
     assert stats.step_builds == {(True, 4, True): 0, (True, 4, False): 1}
+    assert stats.scattered_rows == 8 * 4 * 32 * CFG.num_fields

@@ -1,17 +1,19 @@
 """Replay-trainer integration: PS semantics, mode parity, per-ID rescue."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.recsys import CRITEO_DEEPFM
+from repro.configs.recsys import CRITEO_DEEPFM, RecsysConfig
 from repro.core import GBATrainer, default_setups, run_continual
 from repro.core.trainer import evaluate
 from repro.data import make_clickstream
+from repro.models import recsys as R
 from repro.models.recsys import init_recsys
-from repro.optim import get_optimizer
+from repro.optim import Optimizer, get_optimizer
 from repro.sim.cluster import ClusterSpec, Schedule, Slot, simulate
 
 CFG = CRITEO_DEEPFM
@@ -215,3 +217,176 @@ def test_second_gba_replay_traces_and_compiles_nothing():
     assert stats.applied_steps == 2 * len(LAG)
     assert stats.stacked_steps == 2 * (len(LAG) - 1)
     assert events == []
+
+
+# -- the sparse gradient: one row scatter against per-slot table gradients --
+
+ROW_CFGS = {
+    "deepfm": RecsysConfig(name="deepfm-rows", model="deepfm", num_fields=5,
+                           hash_capacity=512, embed_dim=6, mlp_dims=(8,)),
+    "youtubednn": RecsysConfig(name="youtubednn-rows", model="youtubednn",
+                               num_fields=3, hash_capacity=512, embed_dim=6,
+                               mlp_dims=(8,), behavior_len=6),
+    "dien": RecsysConfig(name="dien-rows", model="dien", num_fields=3,
+                         hash_capacity=512, embed_dim=6, mlp_dims=(8, 4),
+                         behavior_len=8),
+}
+ROW_BATCH = 8
+# 4 steps of 4 slots; under GBA (iota 1) step 1 holds a slot one step
+# stale, step 3 one dispatched at step 0 that Eq. (1) drops and the per-ID
+# relaxation partly rescues
+ROW_STEPS = {
+    "gba": [[Slot(i, 0, 0, 1.0) for i in range(4)],
+            [Slot(4, 1, 1, 1.0), Slot(5, 1, 1, 1.0), Slot(6, 0, 0, 1.0),
+             Slot(7, 1, 1, 1.0)],
+            [Slot(8 + i, 2, 2, 1.0) for i in range(4)],
+            [Slot(12, 3, 3, 1.0), Slot(13, 0, 0, 0.0), Slot(14, 3, 3, 1.0),
+             Slot(15, 2, 2, 1.0)]],
+    "sync": [[Slot(4 * k + i, k, k, 1.0) for i in range(4)]
+             for k in range(4)],
+}
+
+
+def _capture_sgd(lr: float = 0.05) -> Optimizer:
+    """SGD whose state is the last aggregated gradient it applied."""
+    return Optimizer(
+        "capture-sgd", lambda p: jax.tree.map(jnp.zeros_like, p),
+        lambda p, g, s: (jax.tree.map(lambda x, y: x - lr * y, p, g), g))
+
+
+def _per_slot_table_step(trainer: GBATrainer, gba: bool, m: int,
+                         shared_src: bool):
+    """The step as it was built before the row scatter: each slot's
+    gradient of the whole table by ``vmap(grad)``, then the ``row_mask``
+    masked (GBA) or ``weights`` weighted M-way sums over
+    ``max(emb_cnt, 1)``."""
+    cfg, cap, iota = trainer.cfg, trainer.cfg.hash_capacity, trainer.iota
+    grad_fn = jax.value_and_grad(lambda p, b: R.recsys_loss(p, cfg, b))
+
+    def step(src, params, opt_state, batches, tokens, weights, step_k,
+             last_update):
+        losses, grads = jax.vmap(grad_fn, in_axes=(
+            None if shared_src else 0, 0))(src, batches)
+        ids = jax.vmap(R.flat_ids)(batches).reshape(m, -1)
+        touched01 = jax.vmap(lambda i: jnp.zeros((cap,)).at[i].add(1.0))(
+            ids) > 0
+        rescued = jnp.int32(0)
+        if gba:
+            slot_ok = (step_k - tokens) <= iota
+            keep_row = jnp.where(slot_ok[:, None], 1.0,
+                                 (last_update[None, :] <= tokens[:, None]
+                                  ).astype(jnp.float32))
+            row_mask = touched01 * keep_row
+            rescued = jnp.sum((~slot_ok) & (row_mask.sum(axis=1) > 0))
+            emb_cnt = row_mask.sum(axis=0)
+        else:
+            emb_cnt = jnp.sum(touched01 * weights[:, None], axis=0)
+        cntc = jnp.maximum(emb_cnt, 1.0)
+        full = {}
+        for k, g in grads.items():
+            if k not in R.SPARSE_KEYS:
+                full[k] = jax.tree.map(
+                    lambda x: jnp.tensordot(weights / m, x, axes=(0, 0)), g)
+                continue
+            if gba:
+                num = jnp.sum(g * (row_mask[..., None] if g.ndim == 3
+                                   else row_mask), axis=0)
+            else:
+                num = jnp.tensordot(weights, g, axes=(0, 0))
+            full[k] = num / (cntc[:, None] if num.ndim > 1 else cntc)
+        params, opt_state = trainer.optimizer.update(params, full, opt_state)
+        last_update = jnp.where(emb_cnt > 0, step_k, last_update)
+        return params, opt_state, last_update, losses, rescued
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("embed_stream", [None, "interpret"])
+@pytest.mark.parametrize("mode", ["gba", "sync"])
+@pytest.mark.parametrize("model", sorted(ROW_CFGS))
+def test_row_scatter_matches_per_slot_table_gradients(model, mode,
+                                                      embed_stream):
+    """The step that differentiates by the gathered rows and scatters them
+    once gives the aggregated ``embed``/``linear`` gradients, the replayed
+    parameters and ``last_update`` of the per-slot table gradients it
+    replaced, through the XLA scatter and through the streamed kernel."""
+    from repro.embeddings import StreamConfig
+
+    cfg = ROW_CFGS[model]
+    stream = make_clickstream(cfg, seed=0, batches_per_day=16,
+                              batch_size=ROW_BATCH)
+    sched = Schedule(mode, ROW_BATCH, ROW_STEPS[mode])
+    opt = _capture_sgd()
+
+    def run(trainer):
+        params = init_recsys(jax.random.PRNGKey(5), cfg)
+        return trainer.replay(params, opt.init(params), sched, stream, day=0)
+
+    new = GBATrainer(cfg, opt, iota=1, embed_stream=(
+        StreamConfig(interpret=True) if embed_stream else None))
+    old = GBATrainer(cfg, opt, iota=1)
+    old._make_step = lambda *variant: _per_slot_table_step(old, *variant)
+    p1, g1, lu1, st1 = run(new)
+    p2, g2, lu2, st2 = run(old)
+
+    # float32 sums in another order: 1e-6 of each element and, where
+    # terms cancel, of the leaf's largest
+    assert set(g1) >= set(R.SPARSE_KEYS) & set(p1)
+    for tree1, tree2 in ((g1, g2), (p1, p2)):
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(tree1),
+                                jax.tree.leaves(tree2)):
+            np.testing.assert_allclose(
+                a, b, rtol=1e-6, atol=1e-6 * float(jnp.max(jnp.abs(b))),
+                err_msg=jax.tree_util.keystr(path))
+    np.testing.assert_array_equal(lu1, lu2)
+    assert st1.embed_rows_rescued == st2.embed_rows_rescued
+    if mode == "gba":
+        assert st1.embed_rows_rescued > 0
+    assert st1.scattered_rows == sum(
+        len(s) for s in sched.steps) * ROW_BATCH * R.ids_per_example(cfg)
+
+
+def _f32_result_dims(hlo: str) -> set[tuple[int, ...]]:
+    """Sorted dims of every f32 array an instruction other than a
+    parameter produces, in a compiled module's text."""
+    out = set()
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%[\w.\-]+ = (.*?) ([a-z][a-z0-9\-]*)\(",
+                     line)
+        if m is None or m.group(2) == "parameter":
+            continue
+        for dims in re.findall(r"f32\[([0-9,]*)\]", m.group(1)):
+            out.add(tuple(sorted(int(d) for d in dims.split(",") if d)))
+    return out
+
+
+@pytest.mark.parametrize("embed_stream", [None, "interpret"])
+@pytest.mark.parametrize("gba,shared_src", [(True, True), (True, False),
+                                            (False, True)])
+def test_compiled_step_holds_no_per_slot_table(gba, shared_src,
+                                               embed_stream):
+    """No f32 buffer of a compiled step is a table per slot, (M, cap, D)
+    or (M * cap, D) in any order of its axes: the sparse gradient grows
+    with the step's ids, not with M copies of the table."""
+    from repro.embeddings import StreamConfig
+
+    cfg, m, lb = ROW_CFGS["deepfm"], 4, ROW_BATCH
+    trainer = GBATrainer(cfg, get_optimizer("adam", 1e-3), embed_stream=(
+        StreamConfig(interpret=True) if embed_stream else None))
+    params = jax.eval_shape(lambda k: init_recsys(k, cfg),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(trainer.optimizer.init, params)
+    src = params if shared_src else jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((m, *x.shape), x.dtype), params)
+    sds = jax.ShapeDtypeStruct
+    batches = {"fields": sds((m, lb, cfg.num_fields), jnp.int32),
+               "label": sds((m, lb), jnp.float32)}
+    hlo = trainer._make_step(gba, m, shared_src).lower(
+        src, params, opt, batches, sds((m,), jnp.int32),
+        sds((m,), jnp.float32), sds((), jnp.int32),
+        sds((cfg.hash_capacity,), jnp.int32)).compile().as_text()
+    cap, d = cfg.hash_capacity, cfg.embed_dim
+    dims = _f32_result_dims(hlo)
+    assert tuple(sorted((cap, d))) in dims      # the one table gradient
+    assert not dims & {tuple(sorted((m, cap, d))),
+                       tuple(sorted((m * cap, d)))}
