@@ -68,7 +68,7 @@ def run() -> list[str]:
             f"thm.gba_floor.stale{stale}", 0.0,
             f"floor={f:.3e};vs_sync={f / sync256:.2f}"))
     us = (time.perf_counter() - t0) * 1e6
-    rows.append(csv_row("thm.done", us, "see_EXPERIMENTS.md"))
+    rows.append(csv_row("thm.done", us, ""))
     return rows
 
 

@@ -74,7 +74,7 @@ def run(base_days: int = 6) -> list[str]:
         auc = run_strategy(strategy, iota)
         rows.append(csv_row(f"decay.{strategy}", 0.0, f"auc={auc:.4f}"))
     us = (time.perf_counter() - t0) * 1e6
-    rows.append(csv_row("decay.done", us, "see EXPERIMENTS.md"))
+    rows.append(csv_row("decay.done", us, ""))
     return rows
 
 

@@ -1,14 +1,15 @@
-"""Benchmark harness: one module per paper table/figure (+ kernels +
-roofline).  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness: one module per paper table/figure (+ kernels).
+Prints ``name,us_per_call,derived`` CSV.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig6,tab52] [--fast]
         [--json [PATH]] [--check [BASELINE]] [--all]
 
-``--json`` additionally writes the kernel + roofline rows (with the derived
-``k=v`` columns parsed into numbers) to ``BENCH_kernels.json`` so the perf
-trajectory is machine-readable across PRs.
+``--json`` additionally writes the kernel, switching and serving rows
+(with the derived ``k=v`` columns parsed into numbers) to
+``BENCH_kernels.json`` so the perf trajectory is machine-readable across
+PRs.
 
-``--check`` compares the fresh kernel/roofline rows against a committed
+``--check`` compares the fresh JSON rows against a committed
 baseline JSON (default ``BENCH_kernels.json``) and exits non-zero on a
 >5x ``us_per_call`` regression (interpret-mode wall time is load noise;
 only catastrophic algorithmic blowups should trip it), any growth of a
@@ -37,7 +38,7 @@ import sys
 import time
 import traceback
 
-JSON_SUITES = ("kernels", "roofline", "switching", "serving")
+JSON_SUITES = ("kernels", "switching", "serving")
 # --check: max allowed us_per_call growth.  Interpret-mode wall time
 # swings ~4x with container/CI load (the bench docstrings call it noise;
 # the derived columns are the claims), so this only catches catastrophic
@@ -179,7 +180,7 @@ def main() -> None:
                     help="reduced sizes for smoke runs")
     ap.add_argument("--json", nargs="?", const="BENCH_kernels.json",
                     default="",
-                    help="write kernel/roofline rows as JSON "
+                    help="write kernel/switching/serving rows as JSON "
                          "(default BENCH_kernels.json)")
     ap.add_argument("--check", nargs="?", const="BENCH_kernels.json",
                     default="",
@@ -189,7 +190,7 @@ def main() -> None:
                     help="include rows for superseded kernels")
     ap.add_argument("--summary", action="store_true",
                     help="print a one-line-per-row table of the gated "
-                         "kernel/roofline rows (scripts/ci.sh)")
+                         "JSON rows (scripts/ci.sh)")
     args = ap.parse_args()
 
     from repro.launch.compile_cache import enable_compile_cache
@@ -199,7 +200,7 @@ def main() -> None:
                             bench_fig3_grad_distribution,
                             bench_fig6_switching,
                             bench_fig78_batch_ablation, bench_kernels,
-                            bench_multitask, bench_tab52_qps, roofline)
+                            bench_multitask, bench_tab52_qps)
 
     suites = [
         ("fig3", lambda: bench_fig3_grad_distribution.run(
@@ -221,7 +222,6 @@ def main() -> None:
         ("decay", lambda: bench_decay_ablation.run(
             base_days=3 if args.fast else 6)),
         ("kernels", lambda: bench_kernels.run(all_rows=args.all)),
-        ("roofline", roofline.run),
         # gated switching trajectory: fixed size regardless of --fast
         # (the gate compares the committed baseline exactly)
         ("switching", bench_fig6_switching.run_switching),

@@ -2,8 +2,8 @@
 
     PYTHONPATH=src python examples/serve_lm.py [--arch starcoder2-3b]
 
-Uses the REDUCED variant of an assigned architecture (the full configs are
-dry-run-only on CPU), serves a batch of 8 requests: prefill the prompts,
+Uses the REDUCED variant of an assigned architecture (the full configs
+need a TPU), serves a batch of 8 requests: prefill the prompts,
 then greedy-decode 32 tokens each through the production decode step.
 """
 import argparse

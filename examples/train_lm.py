@@ -5,8 +5,8 @@
 
 Builds a granite-family dense decoder at the requested scale, streams the
 synthetic LM source, and trains with the GBA train step (M-slot buffer,
-token-control decay) — the same step the multi-pod dry-run lowers.  Loss
-must drop visibly within a few dozen steps.
+token-control decay) — the same step ``launch.steps.build_step`` lowers.
+Loss must drop visibly within a few dozen steps.
 """
 import argparse
 import dataclasses

@@ -2,8 +2,7 @@
 
 Every assigned architecture is expressed as a :class:`ModelConfig`; the four
 assigned input shapes live in :data:`INPUT_SHAPES`.  Configs are plain frozen
-dataclasses so they can be hashed into jit static args and printed into
-EXPERIMENTS.md verbatim.
+dataclasses so they can be hashed into jit static args.
 """
 from __future__ import annotations
 
@@ -60,11 +59,6 @@ class ModelConfig:
     num_image_tokens: int = 0              # patch embeddings per image
     encoder_layers: int = 0                # enc-dec: encoder depth
     encoder_frames: int = 0                # stub audio frame count
-    # perf knobs (hillclimb variants — see EXPERIMENTS.md §Perf)
-    attn_q_chunk: int = 0        # >0: chunk queries, remat body (flash-like)
-    remat_blocks: bool = False   # checkpoint each scanned block (train)
-    loss_seq_chunk: int = 0      # >0: seq-chunked CE loss (no full logits)
-    mamba_split_proj: bool = False  # split fused in_proj (shard-aligned)
     # misc
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     tie_embeddings: bool = False
@@ -91,7 +85,7 @@ class ModelConfig:
 
     @property
     def supports_long_context(self) -> bool:
-        """True if a 500k-token decode is in-regime (see DESIGN.md table)."""
+        """True if a 500k-token decode is in-regime."""
         kinds = set(self.block_pattern) | set(self.prefix_layers)
         if kinds & {"mamba", "mamba_attn"}:
             return True

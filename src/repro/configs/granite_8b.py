@@ -1,7 +1,7 @@
 """IBM Granite 8B code model [arXiv:2405.04324].
 
 36L d_model=4096 32H (GQA kv=8) d_ff=14336 vocab=49152 — llama-arch, full
-causal attention (no sliding window -> long_500k skipped, see DESIGN.md).
+causal attention (no sliding window -> long_500k skipped).
 """
 from repro.configs.base import ModelConfig
 

@@ -1,6 +1,6 @@
 """GBA — the paper's primary contribution: token-controlled global-batch
 gradient aggregation with staleness decay, plus the semi-synchronous
-baselines and the continual-training driver (see DESIGN.md §1)."""
+baselines and the continual-training driver."""
 from repro.core.continual import (ContinualResult, ModeSetup, default_setups,
                                   pretrain_sync, run_continual,
                                   schedule_for_day)

@@ -129,7 +129,7 @@ def aggregate_embedding(ids_stacked: jax.Array, rows_stacked: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# GBA as a first-class train-step transform (used by launch/train + dry-run)
+# GBA as a first-class train-step transform
 # ---------------------------------------------------------------------------
 
 def init_buffer(params: Params, buffer_size: int) -> dict:

@@ -1,4 +1,4 @@
-"""Activation sharding constraints (§Perf iteration 4).
+"""Activation sharding constraints.
 
 GSPMD resolves the sharding conflict at the embedding gather (tokens
 batch-sharded over `data` vs table dim-sharded over `data`) by *replicating
@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 
 _ACT_SHARDING = None
-_EXPERT_SHARDING = None
 
 
 def set_act_spec(sharding) -> None:
@@ -25,23 +24,7 @@ def set_act_spec(sharding) -> None:
     _ACT_SHARDING = sharding
 
 
-def set_expert_spec(sharding) -> None:
-    """sharding for (E, capacity, D) MoE dispatch buffers (expert-parallel:
-    E over `model`), or None."""
-    global _EXPERT_SHARDING
-    _EXPERT_SHARDING = sharding
-
-
 def constrain(x: jax.Array) -> jax.Array:
     if _ACT_SHARDING is None or x.ndim != 3:
         return x
     return jax.lax.with_sharding_constraint(x, _ACT_SHARDING)
-
-
-def constrain_expert(x: jax.Array) -> jax.Array:
-    """Pin (E, cap, D) dispatch buffers to expert-parallel layout so GSPMD
-    lowers the dispatch scatter as a partitioned scatter instead of
-    converting it to dense one-hot contractions (§Perf H3)."""
-    if _EXPERT_SHARDING is None or x.ndim != 3:
-        return x
-    return jax.lax.with_sharding_constraint(x, _EXPERT_SHARDING)

@@ -1,6 +1,6 @@
 """Sharding rules: params / caches / batches -> PartitionSpec trees.
 
-Scheme (DESIGN.md §5): 2-D "fsdp + tensor" sharding on the single-pod
+Scheme: 2-D "fsdp + tensor" sharding on the single-pod
 (data=16, model=16) mesh —
 
   weight matrices    rows over ``data`` (FSDP), cols over ``model`` (TP)
@@ -96,11 +96,9 @@ def _leaf_spec(names: list[str], shape: tuple[int, ...], mesh: Mesh) -> P:
     if name == "wo" and len(core) == 2:
         return spec(_maybe(core[0], mesh, "model"),
                     _maybe(core[1], mesh, "data"))
-    if name in ("in_proj", "w_z", "w_x", "w_B", "w_C", "w_dt"):  # mamba
+    if name == "in_proj":                                   # mamba
         return spec(_maybe(core[0], mesh, "data"),
                     _maybe(core[1], mesh, "model"))
-    if name in ("conv_x", "conv_B", "conv_C"):
-        return spec(None, _maybe(core[1], mesh, "model"))
     if name == "out_proj":
         return spec(_maybe(core[0], mesh, "model"),
                     _maybe(core[1], mesh, "data"))
@@ -119,39 +117,6 @@ def param_specs(params_shapes: Any, mesh: Mesh) -> Any:
         return sp
 
     return jax.tree_util.tree_map_with_path(per_leaf, params_shapes)
-
-
-def serve_param_specs(params_shapes: Any, mesh: Mesh,
-                      hbm_budget: float = 8e9) -> Any:
-    """Inference sharding (§Perf `serve_tp` variant): drop the `data`
-    (FSDP) axis from weight specs — pure tensor parallelism — when the
-    resulting per-device param bytes fit ``hbm_budget``.  Decode steps then
-    read weights locally instead of all-gathering them every token."""
-    pspecs = param_specs(params_shapes, mesh)
-
-    def drop_data(spec):
-        return P(*(None if ax == "data" else ax for ax in spec))
-
-    dropped = jax.tree.map(drop_data, pspecs,
-                           is_leaf=lambda s: isinstance(s, P))
-
-    def per_dev_bytes(shapes, specs) -> float:
-        total = 0.0
-        for leaf, spec in zip(jax.tree.leaves(shapes),
-                              jax.tree.leaves(
-                                  specs, is_leaf=lambda s: isinstance(s, P))):
-            shard = 1
-            for ax in spec:
-                if ax is None:
-                    continue
-                for a in (ax if isinstance(ax, tuple) else (ax,)):
-                    shard *= _axis_size(mesh, a)
-            total += leaf.size * leaf.dtype.itemsize / shard
-        return total
-
-    if per_dev_bytes(params_shapes, dropped) <= hbm_budget:
-        return dropped
-    return pspecs  # too big without FSDP (kimi-k2): keep 2-D sharding
 
 
 def stacked_specs(specs: Any, lead: int = 1) -> Any:
@@ -234,7 +199,7 @@ def wire_state_specs(layout: Any, mesh: Mesh, scheme: str,
 
 def fused_state_specs(layout: Any, mesh: Mesh, pspecs: Any,
                       axis: str = "data") -> dict:
-    """Spec tree for ``launch.steps``'s fused train state: model params
+    """Spec tree for ``launch.programs``'s fused train state: model params
     keep their per-leaf rules (``pspecs``, the forward consumes them),
     while the Adagrad accumulator and the M-slot gradient buffer live
     flat — sliced over ``axis`` for a ShardedFlatLayout, replicated for
@@ -253,7 +218,7 @@ def cache_specs(cache_shapes: Any, cfg: ModelConfig, mesh: Mesh,
                 batch: int) -> Any:
     """Decode-cache PartitionSpecs.  Batch shards over (pod, data) when it
     divides; otherwise (long_500k, B=1) the KV sequence dim shards over
-    ``data`` — sequence-parallel cache, DESIGN.md §5."""
+    ``data`` — sequence-parallel cache."""
     dp = data_axes(mesh)
     dp_size = 1
     for a in dp:

@@ -1,8 +1,8 @@
 """Production meshes (TPU v5e class).
 
 Defined as functions, not module-level constants, so importing this module
-never touches jax device state — the dry-run sets
-``xla_force_host_platform_device_count=512`` before the first mesh build,
+never touches jax device state: a caller can still set
+``xla_force_host_platform_device_count`` before the first mesh build,
 while tests/benches see the 1-device smoke mesh.
 """
 from __future__ import annotations
@@ -28,9 +28,3 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 def make_smoke_mesh() -> Mesh:
     """1-device mesh with production axis names, for CPU smoke tests."""
     return make_mesh((1, 1), ("data", "model"))
-
-
-# v5e-class hardware constants used by the roofline analysis (task spec)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (~4 links/chip usable)

@@ -8,8 +8,7 @@ specs, device_put) choreography by hand.  ``build_programs`` owns that
 choreography: given a model config (or a raw loss function), a GBA config
 and a mode, it returns a :class:`TrainPrograms` bundle holding the jitted
 step(s), the initialized (and, when sharded, device_put) state, the flat
-layout and the wire state.  The old factory names survive in
-``launch.steps`` as thin deprecation shims over the implementations here.
+layout and the wire state.
 
 Modes
 -----
@@ -49,7 +48,7 @@ from repro.optim import Optimizer, get_optimizer
 
 # the paper's GBA mode runs Adam (Tab. 5.1, "Others"); the 1T MoE cannot hold
 # Adam's two f32 moments at 512 chips, so it trains with Adagrad — the very
-# optimizer the paper uses for its async mode (DESIGN.md §5)
+# optimizer the paper uses for its async mode
 ARCH_OPTIMIZER = {"kimi-k2-1t-a32b": "adagrad"}
 ARCH_ACC_DTYPE = {"kimi-k2-1t-a32b": jnp.bfloat16}
 

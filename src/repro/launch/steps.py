@@ -1,31 +1,21 @@
-"""Sharded train / prefill / decode steps + input_specs for every
-(architecture x input-shape) combination.
+"""Sharded prefill / decode steps, abstract inputs and state specs for
+every (architecture x input-shape) combination, and ``build_step``, which
+jits one of them (or the GBA train step) for a mesh.
 
-GBA is a first-class feature of the compiled train step: each step computes
-one buffer slot's gradient (the pod acts as one PS "worker"; the slot's
-local batch is the input-shape global batch), decays it by the token-control
-rule against the current global step, accumulates into the sharded gradient
+Training programs are built in :mod:`repro.launch.programs`
+(``build_programs`` and the factories it wraps); ``build_step`` takes its
+train step from there.  That step computes one buffer slot's gradient
+(the pod acts as one PS "worker"; the slot's local batch is the
+input-shape global batch), decays it by the token-control rule against
+the current global step, accumulates into the sharded gradient
 accumulator, and applies the optimizer every M-th microstep under
-``lax.cond`` — the TPU-SPMD rendering of Algorithm 2's buffer (DESIGN.md
-§2).  Setting ``buffer_size=1, iota=big`` recovers plain synchronous
-training, which is exactly the paper's tuning-free switch.
-
-.. deprecated::
-    The six training-program factories that used to live here
-    (``make_train_step`` / ``init_train_state`` / ``make_fused_train_step``
-    / ``init_fused_train_state`` / ``make_wire_psum_steps`` /
-    ``init_wire_state``, plus ``jit_fused_train_step``) moved to
-    :mod:`repro.launch.programs`; build them through
-    :func:`repro.launch.programs.build_programs` instead.  The names here
-    are thin shims that forward to the same implementations with a
-    ``DeprecationWarning``, so existing call sites keep working
-    bit-for-bit.  Serve-step builders and the dryrun ``build_step``
-    assembly remain canonical here.
+``lax.cond``: Algorithm 2's buffer rendered in TPU SPMD.  Setting
+``buffer_size=1, iota=big`` recovers plain synchronous training, which is
+exactly the paper's tuning-free switch.
 """
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Any
 
 import jax
@@ -34,14 +24,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import GBAConfig, InputShape, ModelConfig
 from repro.distributed import sharding as S
-from repro.distributed.act_sharding import set_act_spec, set_expert_spec
+from repro.distributed.act_sharding import set_act_spec
 from repro.launch import programs as _P
-from repro.launch.programs import (  # noqa: F401  (re-exports)
-    ARCH_ACC_DTYPE,
-    ARCH_OPTIMIZER,
-    _loss_from_batch,
-    make_loss_fn,
-)
+from repro.launch.programs import ARCH_ACC_DTYPE, ARCH_OPTIMIZER
 from repro.models import transformer as T
 from repro.optim import Optimizer, get_optimizer
 
@@ -98,40 +83,6 @@ def _memory_len(cfg: ModelConfig) -> int:
     return 0
 
 
-# ---------------------------------------------------------------------------
-# deprecation shims over repro.launch.programs
-# ---------------------------------------------------------------------------
-
-def _shim(name: str, fn):
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        warnings.warn(
-            f"repro.launch.steps.{name} is deprecated; build training "
-            "programs through repro.launch.programs.build_programs "
-            "(or import the factory from repro.launch.programs).",
-            DeprecationWarning, stacklevel=2)
-        return fn(*args, **kwargs)
-    return wrapper
-
-
-init_train_state = _shim("init_train_state", _P.init_train_state)
-make_train_step = _shim("make_train_step", _P.make_train_step)
-init_fused_train_state = _shim("init_fused_train_state",
-                               _P.init_fused_train_state)
-make_fused_train_step = _shim("make_fused_train_step",
-                              _P.make_fused_train_step)
-jit_fused_train_step = _shim("jit_fused_train_step", _P.jit_fused_train_step)
-make_wire_psum_steps = _shim("make_wire_psum_steps", _P.make_wire_psum_steps)
-init_wire_state = _shim("init_wire_state", _P.init_wire_state)
-
-
-def fused_state_specs(layout, mesh: Mesh, pspecs: Any,
-                      axis: str = "data") -> dict:
-    """PartitionSpecs matching ``init_fused_train_state``'s output —
-    canonical constructor in ``distributed.sharding``."""
-    return S.fused_state_specs(layout, mesh, pspecs, axis)
-
-
 def opt_state_specs(optimizer: Optimizer, pspecs: Any) -> Any:
     if optimizer.name == "adam":
         return {"m": pspecs, "v": pspecs, "count": P()}
@@ -178,15 +129,9 @@ def make_decode_step(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 def build_step(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
-               gba: GBAConfig | None = None, serve_tp: bool = False,
-               moe_ep: bool = False):
+               gba: GBAConfig | None = None):
     """Returns (jitted_fn, abstract_args tuple) ready for .lower()."""
     gba = gba or GBAConfig(local_batch=shape.global_batch, buffer_size=8)
-    if moe_ep and cfg.num_experts \
-            and cfg.num_experts % mesh.shape["model"] == 0:
-        set_expert_spec(NamedSharding(mesh, P("model", None, None)))
-    else:
-        set_expert_spec(None)
     # pin the residual stream to batch-sharded layout (act_sharding docs);
     # long_500k (batch=1) replicates instead
     dp = S.data_axes(mesh)
@@ -197,10 +142,7 @@ def build_step(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
         else P(None, None, None)
     set_act_spec(NamedSharding(mesh, act_spec))
     pshapes = abstract_params(cfg)
-    if serve_tp and shape.kind != "train":
-        pspecs = S.serve_param_specs(pshapes, mesh)
-    else:
-        pspecs = S.param_specs(pshapes, mesh)
+    pspecs = S.param_specs(pshapes, mesh)
     named = lambda tree: jax.tree.map(
         lambda s: NamedSharding(mesh, s), tree,
         is_leaf=lambda s: isinstance(s, P))
@@ -216,7 +158,7 @@ def build_step(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
             functools.partial(_P.init_train_state, optimizer=opt,
                               acc_dtype=acc_dt), pshapes)
         # donate the state like launch.train does — without this the
-        # dryrun-lowered step double-allocates params + opt + acc
+        # lowered step double-allocates params + opt + acc
         # (auditor rule GBA-DON-001)
         fn = jax.jit(_P.make_train_step(cfg, opt, gba),
                      in_shardings=(named(sspecs), named(bspecs),

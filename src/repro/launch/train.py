@@ -4,8 +4,8 @@
         --steps 20 --reduced [--buffer 8] [--iota 4]
 
 On real TPU hardware this launches the sharded GBA train step on the
-production mesh; in this CPU container use ``--reduced`` (smoke variant,
-1-device mesh) — the full configs are exercised by launch.dryrun.
+production mesh; on a CPU use ``--reduced`` (smoke variant, 1-device
+mesh).
 
 ``--vocab N`` runs the sparse-module smoke instead: N-row hashed embedding
 table trained through the DMA-streamed pooled-lookup kernels on the smoke

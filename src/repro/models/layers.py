@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.distributed.act_sharding import constrain_expert
 
 Params = dict[str, Any]
 
@@ -146,37 +145,7 @@ def attention_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
     else:
         mask = None  # cross-attn: attend to all memory tokens
     q = q.reshape(B, S, cfg.num_kv_heads, G, cfg.resolved_head_dim)
-    qc = cfg.attn_q_chunk
-    if qc and S % qc == 0 and S > qc and kv_override is None:
-        # §Perf hillclimb: chunk the queries and remat the chunk body so
-        # neither forward nor backward ever materializes the (S, S) score
-        # tensor.  The chunk is taken with dynamic_slice on the SEQ axis
-        # (keeps the batch sharding intact — reshapes across batch made
-        # GSPMD replicate, see EXPERIMENTS.md §Perf iter 3) and the causal
-        # mask is a (qc, S) broadcast computed inside the body, never a
-        # materialized (nq, B, qc, S) tensor.
-        nq = S // qc
-        col = jnp.arange(S)
-
-        def chunk_body(q_blk, start):
-            row = start + jnp.arange(qc)
-            m2d = row[:, None] >= col[None, :]
-            if window:
-                m2d &= row[:, None] - col[None, :] < window
-            return _sdpa(q_blk, k, v, m2d[None], cfg.attn_softcap)
-
-        chunk_body = jax.checkpoint(chunk_body)
-
-        def scan_body(_, start):
-            q_blk = lax.dynamic_slice_in_dim(q, start, qc, axis=1)
-            return None, chunk_body(q_blk, start)
-
-        _, out_blocks = lax.scan(scan_body, None,
-                                 jnp.arange(nq, dtype=jnp.int32) * qc)
-        out = jnp.moveaxis(out_blocks, 0, 1).reshape(
-            B, S, cfg.num_kv_heads, G, cfg.resolved_head_dim)
-    else:
-        out = _sdpa(q, k, v, mask, cfg.attn_softcap)
+    out = _sdpa(q, k, v, mask, cfg.attn_softcap)
     out = out.reshape(B, S, cfg.num_heads, cfg.resolved_head_dim)
     y = jnp.einsum("bsnh,nhd->bsd", out, p["wo"]).astype(x.dtype)
     if return_kv:
@@ -327,8 +296,8 @@ def moe_fwd(p: Params, cfg: ModelConfig, x: jax.Array
     aux = E * jnp.sum(frac * probs_mean)
 
     # capacity-based dispatch: slot index = rank of the token within its
-    # expert's queue.  Computed by stable sort + histogram (§Perf H3 iter 2:
-    # the textbook cumsum over the (T*K, E) one-hot costs O(T*K*E) — 1.7e15
+    # expert's queue.  Computed by stable sort + histogram (the textbook
+    # cumsum over the (T*K, E) one-hot costs O(T*K*E) — 1.7e15
     # FLOPs/device for kimi-k2, 586x the expert matmuls themselves; the
     # sort-based ranking is O(T*K log T*K) and numerically identical).
     cap = max(1, int(T * K / E * cfg.moe_capacity_factor))
@@ -349,12 +318,10 @@ def moe_fwd(p: Params, cfg: ModelConfig, x: jax.Array
         jnp.where(keep, flat_slot, cap - 1)].add(
             jnp.where(keep[:, None], src, 0).astype(x.dtype),
             mode="drop")
-    expert_in = constrain_expert(expert_in)
 
     h = jnp.einsum("ecd,edf->ecf", expert_in, p["wi_gate"])
     h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", expert_in, p["wi_up"])
     expert_out = jnp.einsum("ecf,efd->ecd", h, p["wo"])         # (E, cap, D)
-    expert_out = constrain_expert(expert_out)
 
     gathered = expert_out[flat_sel, flat_slot]                  # (T*K, D)
     gathered = jnp.where(keep[:, None], gathered, 0)
@@ -389,23 +356,6 @@ def init_mamba(key, cfg: ModelConfig) -> Params:
         "out_norm": init_norm(cfg, d_inner),
         "out_proj": _dense_init(ks[2], (d_inner, D), dt),
     }
-    if cfg.mamba_split_proj:
-        # §Perf variant: one weight matrix per stream.  Mathematically
-        # identical to the fused in_proj, but every projection output is
-        # cleanly sharded — the fused layout forces jnp.split at
-        # shard-misaligned offsets, which GSPMD can only resolve by full
-        # rematerialization (the mamba2 collective-term pathology).
-        return common | {
-            "w_z": _dense_init(ks[0], (D, d_inner), dt),
-            "w_x": _dense_init(ks[3], (D, d_inner), dt),
-            "w_B": _dense_init(ks[4], (D, N), dt),
-            "w_C": _dense_init(ks[5], (D, N), dt),
-            "w_dt": _dense_init(ks[6], (D, H), dt),
-            "conv_x": _dense_init(ks[1], (CONV_W, d_inner), dt, scale=0.5),
-            "conv_B": _dense_init(ks[7], (CONV_W, N), dt, scale=0.5),
-            "conv_C": _dense_init(jax.random.fold_in(ks[7], 1),
-                                  (CONV_W, N), dt, scale=0.5),
-        }
     return common | {
         "in_proj": _dense_init(ks[0], (D, 2 * d_inner + 2 * N + H), dt),
         "conv_w": _dense_init(ks[1], (CONV_W, conv_dim), dt, scale=0.5),
@@ -495,26 +445,15 @@ def mamba_fwd(p: Params, cfg: ModelConfig, x: jax.Array,
     returns the decode cache {ssm, conv} after consuming the sequence."""
     B, S, D = x.shape
     d_inner, H, N = _ssm_dims(cfg)
-    if cfg.mamba_split_proj:
-        z = x @ p["w_z"]
-        xs_raw = x @ p["w_x"]
-        B_raw = x @ p["w_B"]
-        C_raw = x @ p["w_C"]
-        dt_r = x @ p["w_dt"]
-        xbc = jnp.concatenate([xs_raw, B_raw, C_raw], axis=-1)  # cache only
-        xs = jax.nn.silu(_causal_conv(xs_raw, p["conv_x"]))
-        Bm = jax.nn.silu(_causal_conv(B_raw, p["conv_B"]))
-        Cm = jax.nn.silu(_causal_conv(C_raw, p["conv_C"]))
-    else:
-        zxbcdt = x @ p["in_proj"]
-        z, xs, Bm, Cm, dt_r = jnp.split(
-            zxbcdt,
-            [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
-            axis=-1)
-        # causal short conv over (x, B, C)
-        xbc = jnp.concatenate([xs, Bm, Cm], axis=-1)
-        conv = jax.nn.silu(_causal_conv(xbc, p["conv_w"]))
-        xs, Bm, Cm = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
+    zxbcdt = x @ p["in_proj"]
+    z, xs, Bm, Cm, dt_r = jnp.split(
+        zxbcdt,
+        [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
+        axis=-1)
+    # causal short conv over (x, B, C)
+    xbc = jnp.concatenate([xs, Bm, Cm], axis=-1)
+    conv = jax.nn.silu(_causal_conv(xbc, p["conv_w"]))
+    xs, Bm, Cm = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
     dt_h = jax.nn.softplus(dt_r.astype(jnp.float32) + p["dt_bias"])
     xh = xs.reshape(B, S, H, cfg.ssm_head_dim)
     pad = (-S) % cfg.ssm_chunk
@@ -551,26 +490,16 @@ def mamba_decode(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params
     """Single-token recurrent update.  x: (B,1,D)."""
     B = x.shape[0]
     d_inner, H, N = _ssm_dims(cfg)
-    if cfg.mamba_split_proj:
-        xt = x[:, 0]
-        z = xt @ p["w_z"]
-        xbc = jnp.concatenate([xt @ p["w_x"], xt @ p["w_B"],
-                               xt @ p["w_C"]], axis=-1)
-        dt_r = xt @ p["w_dt"]
-        conv_w = jnp.concatenate([p["conv_x"], p["conv_B"], p["conv_C"]],
-                                 axis=-1)
-    else:
-        zxbcdt = x[:, 0] @ p["in_proj"]
-        z, xs, Bm, Cm, dt_r = jnp.split(
-            zxbcdt,
-            [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
-            axis=-1)
-        xbc = jnp.concatenate([xs, Bm, Cm], axis=-1)          # (B, conv_dim)
-        conv_w = p["conv_w"]
+    zxbcdt = x[:, 0] @ p["in_proj"]
+    z, xs, Bm, Cm, dt_r = jnp.split(
+        zxbcdt,
+        [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
+        axis=-1)
+    xbc = jnp.concatenate([xs, Bm, Cm], axis=-1)              # (B, conv_dim)
     conv_hist = jnp.concatenate([cache["conv"],
                                  xbc[:, None, :].astype(cache["conv"].dtype)],
                                 axis=1)                       # (B, CONV_W, C)
-    conv = jnp.einsum("bwc,wc->bc", conv_hist, conv_w)
+    conv = jnp.einsum("bwc,wc->bc", conv_hist, p["conv_w"])
     conv = jax.nn.silu(conv)
     xs, Bm, Cm = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
     dt_h = jax.nn.softplus(dt_r.astype(jnp.float32) + p["dt_bias"])  # (B,H)

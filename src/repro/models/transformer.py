@@ -2,8 +2,8 @@
 norm -> logits, for every assigned architecture family.
 
 The repeated part of the stack runs under ``lax.scan`` over params stacked on
-a leading ``num_repeats`` axis, which keeps the lowered HLO compact enough to
-compile 80 (arch x shape x mesh) dry-run combinations on one CPU core.
+a leading ``num_repeats`` axis, which keeps the lowered HLO compact: its
+size does not grow with depth.
 
 Zamba2's *shared* attention block is faithful to the model card: a single
 set of attention params applied inside every ``mamba_attn`` layer (passed to
@@ -222,9 +222,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             x = constrain(x)
         return (x, aux), None
 
-    if cfg.remat_blocks:
-        # §Perf: save only the block boundary; recompute inside on backward
-        body = jax.checkpoint(body)
     (x, aux), _ = lax.scan(body, (x, aux), params["blocks"])
     x = L.norm_fwd(params["final_norm"], x)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
@@ -398,70 +395,10 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
 
 def lm_loss(params: Params, cfg: ModelConfig, tokens: jax.Array,
             labels: jax.Array, memory: jax.Array | None = None) -> jax.Array:
-    sc = cfg.loss_seq_chunk
-    S = tokens.shape[1]
-    if sc and S % sc == 0 and S > sc:
-        # §Perf iteration 5: never materialize the full (B, S, V) logits —
-        # scan the LM head + CE over sequence chunks with remat.
-        hidden, aux = forward_hidden(params, cfg, tokens, memory=memory)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-
-        def chunk_nll(h_blk, y_blk):
-            logits = jnp.einsum("bsd,dv->bsv", h_blk, head,
-                                preferred_element_type=jnp.float32)
-            if cfg.logit_softcap:
-                logits = jnp.tanh(logits / cfg.logit_softcap) \
-                    * cfg.logit_softcap
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            return -jnp.take_along_axis(
-                logp, y_blk[..., None], axis=-1)[..., 0].sum()
-
-        chunk_nll = jax.checkpoint(chunk_nll)
-
-        def body(tot, start):
-            h_blk = lax.dynamic_slice_in_dim(hidden, start, sc, axis=1)
-            y_blk = lax.dynamic_slice_in_dim(labels, start, sc, axis=1)
-            return tot + chunk_nll(h_blk, y_blk), None
-
-        nq = S // sc
-        total, _ = lax.scan(body, jnp.zeros((), jnp.float32),
-                            jnp.arange(nq, dtype=jnp.int32) * sc)
-        nll_mean = total / (tokens.shape[0] * S)
-        return nll_mean + cfg.router_aux_loss_weight * aux
     logits, aux = forward(params, cfg, tokens, memory=memory)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(nll) + cfg.router_aux_loss_weight * aux
-
-
-def forward_hidden(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                   memory: jax.Array | None = None
-                   ) -> tuple[jax.Array, jax.Array]:
-    """forward() up to the final norm (no logits)."""
-    B, S = tokens.shape
-    x = params["embed"][tokens] * (cfg.d_model ** 0.5 if cfg.tie_embeddings
-                                   else 1.0)
-    x = constrain(x.astype(L.dtype_of(cfg)))
-    positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    aux = jnp.zeros((), jnp.float32)
-    shared = params.get("shared_attn")
-    for i, kind in enumerate(cfg.prefix_layers):
-        x, aux = _layer_fwd(params["prefix"][i], cfg, kind, x, positions,
-                            memory=memory, shared_attn=shared, aux=aux)
-
-    def body(carry, block_p):
-        x, aux = carry
-        for i, kind in enumerate(cfg.block_pattern):
-            x, aux = _layer_fwd(block_p[f"l{i}"], cfg, kind, x, positions,
-                                memory=memory, shared_attn=shared, aux=aux)
-            x = constrain(x)
-        return (x, aux), None
-
-    if cfg.remat_blocks:
-        body = jax.checkpoint(body)
-    (x, aux), _ = lax.scan(body, (x, aux), params["blocks"])
-    return L.norm_fwd(params["final_norm"], x), aux
 
 
 def param_count(params: Params) -> int:

@@ -2,15 +2,18 @@
 family runs one forward + one train step + one decode step on CPU; output
 shapes asserted, no NaNs."""
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import GBAConfig, InputShape
-from repro.launch.steps import (init_train_state, make_train_step,
-                                model_inputs)
+from repro.launch.programs import init_train_state, make_train_step
+from repro.launch.steps import model_inputs
+from repro.models import layers as L
 from repro.models import transformer as T
 from repro.optim import get_optimizer
 
@@ -84,3 +87,71 @@ def test_model_inputs_shapes(arch):
     dec = model_inputs(cfg, InputShape("decode_32k", 32768, 128, "decode"))
     assert dec["tokens"].shape == (128, 1)
     assert "frames" not in dec and "image_embeds" not in dec
+
+
+def _reference_attention(p, cfg, x):
+    """Causal softmax attention written out per query head in float32:
+    RoPE on q and k, each kv head repeated for its query group, an
+    explicit (S, S) mask, the optional tanh score cap."""
+    _, S, _ = x.shape
+    hd = cfg.resolved_head_dim
+    half = hd // 2
+    group = cfg.num_heads // cfg.num_kv_heads
+    q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"])
+    k = jnp.einsum("bsd,dnh->bsnh", x, p["wk"])
+    v = jnp.einsum("bsd,dnh->bsnh", x, p["wv"])
+    inv_freq = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32)
+                                  / half)
+    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+
+    def rotate(t):
+        t1, t2 = t[..., :half], t[..., half:]
+        return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
+                               axis=-1)
+
+    q, k = rotate(q), rotate(k)
+    k = jnp.repeat(k, group, axis=2)
+    v = jnp.repeat(v, group, axis=2)
+    scores = jnp.einsum("bsnh,btnh->bnst", q, k) / math.sqrt(hd)
+    if cfg.attn_softcap:
+        scores = cfg.attn_softcap * jnp.tanh(scores / cfg.attn_softcap)
+    row = jnp.arange(S)[:, None]
+    col = jnp.arange(S)[None, :]
+    mask = col <= row
+    if cfg.sliding_window:
+        mask &= row - col < cfg.sliding_window
+    scores = jnp.where(mask[None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bnst,btnh->bsnh", probs, v)
+    return jnp.einsum("bsnh,nhd->bsd", out, p["wo"])
+
+
+@pytest.mark.parametrize("arch,window,softcap", [
+    ("granite-8b", 0, 0.0),
+    ("gemma2-27b", 8, 0.0),
+    ("gemma2-27b", 0, 50.0),
+], ids=["causal", "sliding_window", "softcap"])
+def test_attention_matches_reference(arch, window, softcap):
+    """``layers.attention_fwd`` (the one attention path) equals a plain
+    softmax attention with an explicit mask, in float32.  The input is
+    scaled so that scores reach the softcap's range; the window and the
+    cap each move the reference by far more than the tolerance."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32",
+                              sliding_window=window, attn_softcap=softcap)
+    kp, kx = jax.random.split(jax.random.PRNGKey(3))
+    p = L.init_attention(kp, cfg)
+    x = 8.0 * jax.random.normal(kx, (B, S, cfg.d_model), jnp.float32)
+    positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    with jax.default_matmul_precision("highest"):
+        got = L.attention_fwd(p, cfg, x, positions, window=window)
+        want = _reference_attention(p, cfg, x)
+        plain = _reference_attention(
+            p, dataclasses.replace(cfg, sliding_window=0, attn_softcap=0.0),
+            x)
+    atol = 1e-5 * float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=atol)
+    if window or softcap:
+        assert float(jnp.abs(want - plain).max()) > 100 * atol

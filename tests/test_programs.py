@@ -1,11 +1,10 @@
-"""build_programs: the unified train-program builder, and the
-deprecation shims in repro.launch.steps that forward to it.
+"""build_programs: the unified train-program builder.
 
 The equivalence tests build the SAME program twice — once through the
-legacy factory names (which must emit DeprecationWarning) and once
-through build_programs — and require bit-identical losses and updated
-parameters.  Both paths jit with donate_argnums=0, so each path gets its
-own freshly initialized (identical-by-PRNG) state.
+``repro.launch.programs`` factories that ``build_programs`` wraps and
+once through ``build_programs`` itself — and require bit-identical
+losses and updated parameters.  Both paths jit with donate_argnums=0, so
+each path gets its own freshly initialized (identical-by-PRNG) state.
 """
 import jax
 import jax.numpy as jnp
@@ -14,8 +13,9 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import GBAConfig
-from repro.launch import steps as steps_mod
-from repro.launch.programs import build_programs
+from repro.launch.programs import (build_programs, init_fused_train_state,
+                                   init_train_state, jit_fused_train_step,
+                                   make_train_step)
 from repro.models import transformer as T
 from repro.optim import get_optimizer
 
@@ -45,52 +45,39 @@ def _assert_trees_equal(a, b):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
-def test_pytree_shim_equivalence():
+def test_pytree_builder_matches_factories():
     cfg, gba, batch = _setup()
     token = jnp.zeros((), jnp.int32)
     opt = get_optimizer("adam", 1e-3)
 
-    with pytest.deprecated_call():
-        legacy_step = steps_mod.make_train_step(cfg, opt, gba)
-    with pytest.deprecated_call():
-        legacy_state = steps_mod.init_train_state(_params(cfg), opt)
-    legacy_state2, legacy_loss = jax.jit(legacy_step)(
-        legacy_state, batch, token)
+    ref_step = make_train_step(cfg, opt, gba)
+    ref_state = init_train_state(_params(cfg), opt)
+    ref_state2, ref_loss = jax.jit(ref_step)(ref_state, batch, token)
 
     progs = build_programs(cfg, gba, mode="pytree", optimizer=opt,
                            params=_params(cfg))
     state2, loss = progs.step(progs.state, batch, token)
 
-    assert float(loss) == float(legacy_loss)
-    _assert_trees_equal(state2["params"], legacy_state2["params"])
-    assert int(state2["gstep"]) == int(legacy_state2["gstep"]) == 1
+    assert float(loss) == float(ref_loss)
+    _assert_trees_equal(state2["params"], ref_state2["params"])
+    assert int(state2["gstep"]) == int(ref_state2["gstep"]) == 1
 
 
-def test_fused_shim_equivalence():
+def test_fused_builder_matches_factories():
     cfg, gba, batch = _setup()
     token = jnp.zeros((), jnp.int32)
 
-    with pytest.deprecated_call():
-        layout, legacy_state = steps_mod.init_fused_train_state(
-            _params(cfg), gba)
-    with pytest.deprecated_call():
-        legacy_step = steps_mod.jit_fused_train_step(cfg, gba, layout)
-    legacy_state2, legacy_loss = legacy_step(legacy_state, batch, token)
+    layout, ref_state = init_fused_train_state(_params(cfg), gba)
+    ref_step = jit_fused_train_step(cfg, gba, layout)
+    ref_state2, ref_loss = ref_step(ref_state, batch, token)
 
     progs = build_programs(cfg, gba, mode="fused", params=_params(cfg))
     state2, loss = progs.step(progs.state, batch, token)
 
-    assert float(loss) == float(legacy_loss)
-    _assert_trees_equal(state2["params"], legacy_state2["params"])
+    assert float(loss) == float(ref_loss)
+    _assert_trees_equal(state2["params"], ref_state2["params"])
     np.testing.assert_array_equal(np.asarray(state2["accum"]),
-                                  np.asarray(legacy_state2["accum"]))
-
-
-def test_shim_warning_points_at_builder():
-    cfg, gba, _ = _setup()
-    opt = get_optimizer("adam", 1e-3)
-    with pytest.warns(DeprecationWarning, match="build_programs"):
-        steps_mod.make_train_step(cfg, opt, gba)
+                                  np.asarray(ref_state2["accum"]))
 
 
 def test_build_programs_validation():

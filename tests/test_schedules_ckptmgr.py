@@ -66,7 +66,7 @@ def test_checkpoint_manager_retention(tmp_path):
 def test_checkpoint_manager_roundtrip_train_state(tmp_path):
     import jax
     from repro.configs import get_config
-    from repro.launch.steps import init_train_state
+    from repro.launch.programs import init_train_state
     from repro.models import transformer as T
     from repro.optim import get_optimizer
     cfg = get_config("mamba2-780m").reduced()
