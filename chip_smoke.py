@@ -8,9 +8,10 @@ Phases (one process, random weights from fixed seeds, data generated
 from seeds; nothing is read from outside the repository):
 
 A. Recsys GBA, the paper's path: ``criteo-deepfm`` at its configured
-   widths trained by ``core.GBATrainer`` with the streamed presence-count
-   kernel, replaying a strained-cluster GBA schedule of 16 slots x local
-   batch 128.  Reference: the first global step with the XLA count path.
+   widths trained by ``core.GBATrainer`` with the streamed row scatter,
+   which also counts each id's contributing slots, replaying a
+   strained-cluster GBA schedule of 16 slots x local batch 128.
+   Reference: the first global step with the XLA scatter path.
 B. LM fused GBA apply: ``mamba2-780m`` at its published widths, cut to 8
    layers, through ``build_programs(mode="fused")`` with M=4.  Reference:
    one apply of the ``gba_apply`` kernel against ``ref.gba_apply_ref``.
@@ -43,8 +44,8 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
 
-# phase A: both count paths give identical integer presence counts, so the
-# first step may differ only by the float rounding of two XLA programs
+# phase A: both paths give identical integer contributor counts, so the
+# first step may differ only by the order of the float32 row sums
 RECSYS_PARAM_TOL = 1e-6
 # phase B: the kernel sums the M rows in worker order and runs Adagrad's
 # sqrt/divide in Mosaic; the oracle is XLA's reduction and elementwise ops
@@ -159,13 +160,14 @@ def phase_recsys(cfg=None, *, num_batches: int = 256, workers: int = 16,
     calls0 = ops.kernel_calls["pooled_lookup_grad"]
     p_kernel, *_ = streamed.replay(params0, optimizer.init(params0), first,
                                    stream, 0)
-    check(ops.kernel_calls["pooled_lookup_grad"] > calls0,
-          "presence counts traced through the sorted-scatter kernel")
+    check(ops.kernel_calls["pooled_lookup_grad"] == calls0,
+          "no pooled-backward kernel traced: the contributor counts ride "
+          "in the row scatter")
     p_xla, *_ = GBATrainer(cfg, optimizer, iota=IOTA).replay(
         params0, optimizer.init(params0), first, stream, 0)
     diff = max_abs_diff(p_kernel, p_xla)
     check(diff <= RECSYS_PARAM_TOL,
-          f"first global step, kernel vs XLA count path: max|dparam|="
+          f"first global step, kernel vs XLA scatter path: max|dparam|="
           f"{diff:.3e} <= {RECSYS_PARAM_TOL:g}")
 
     params, _, _, stats = streamed.replay(

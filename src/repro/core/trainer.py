@@ -45,8 +45,8 @@ import numpy as np
 from repro import tracing
 from repro.configs.recsys import RecsysConfig
 from repro.data.clickstream import ClickStream
-from repro.embeddings.table import (StreamConfig, presence_counts,
-                                    scatter_rows, sparse_grads_to_dense)
+from repro.embeddings.table import (StreamConfig, scatter_rows,
+                                    sparse_grads_to_dense)
 from repro.metrics import StreamingAUC
 from repro.models import recsys as R
 from repro.optim import Optimizer
@@ -106,8 +106,8 @@ class GBATrainer:
     iota: int = 4
     per_id_embedding_decay: bool = True   # Alg. 2 lines 21/23
     history: int = 64
-    # production-capacity knob: when set, the per-slot presence counts and
-    # the row scatter of the sparse gradient run through the streamed
+    # production-capacity knob: when set, the row scatter of the sparse
+    # gradient and each id's contributor count run through the streamed
     # sorted-scatter kernel (O(block) VMEM at any hash_capacity) instead
     # of XLA scatters
     embed_stream: StreamConfig | None = None
@@ -151,12 +151,13 @@ class GBATrainer:
         pair: under GBA 1 for a slot within iota, else whether the id is
         untouched since the slot's token (the per-ID relaxation); in the
         other modes the slot's weight.  The sum is one scatter of the
-        sparse leaves side by side: the streamed sorted-scatter kernel
-        with ``embed_stream`` set, else one XLA scatter-add.  It runs
-        under ``jax.named_scope("embedding")``, the rest of the GBA
-        aggregation under ``"aggregate"``, and the optimizer and
-        ``last_update`` stamp under ``"apply"``; the models name their own
-        ``embedding`` and ``dense`` ops.
+        sparse leaves side by side, which also sums each id's factor over
+        the slots that touched it, its contributor count: the streamed
+        sorted-scatter kernel with ``embed_stream`` set, else XLA
+        scatters.  It runs under ``jax.named_scope("embedding")``, the
+        rest of the GBA aggregation under ``"aggregate"``, and the
+        optimizer and ``last_update`` stamp under ``"apply"``; the models
+        name their own ``embedding`` and ``dense`` ops.
         """
         cap = self.cfg.hash_capacity
         iota = self.iota
@@ -165,8 +166,7 @@ class GBATrainer:
         stream = self.embed_stream
         in_axes = (None, 0) if shared_src else (0, 0)
 
-        def aggregate(dense_g, batches, ids, tokens, weights, step_k,
-                      last_update):
+        def aggregate(dense_g, ids, tokens, weights, step_k, last_update):
             # dense module: Alg. 2 line 22 — weighted sum / N_a (= m)
             wm = (weights / m).astype(jnp.float32)
             agg = jax.tree.map(
@@ -174,39 +174,14 @@ class GBATrainer:
                                         axes=(0, 0)).astype(g.dtype),
                 dense_g)
 
-            # sparse module: per-ID treatment (Alg. 2 lines 21/23)
-            ids_all = self._flat_ids(batches, m)
-            if stream is not None:
-                # streamed counts: offsetting slot i's ids by i*cap turns
-                # the M per-slot histograms into ONE sorted-scatter kernel
-                # launch over an (M*cap)-row id space — a single sort, no
-                # XLA one-hot scatter, O(block) VMEM at any capacity
-                slot_offset = (jnp.arange(m, dtype=jnp.int32) * cap)[:, None]
-                present = presence_counts(
-                    ids_all + slot_offset, m * cap,
-                    stream=stream).reshape(m, cap)
-            else:
-                present = jax.vmap(
-                    lambda ids: jnp.zeros((cap,),
-                                          jnp.float32).at[ids].add(1.0)
-                )(ids_all)
-            touched01 = (present > 0).astype(jnp.float32)       # (M, cap)
-            rescued = jnp.int32(0)
+            # sparse module: each (slot, id) row's factor (Alg. 2 lines
+            # 21/23), ids (M, n_ids)
             if gba:
                 # per-ID relaxation: a slot dropped by Eq.(1) may still
-                # contribute rows whose IDs were untouched since its token
-                slot_ok = (step_k - tokens) <= iota             # (M,)
-                id_fresh = last_update[None, :] <= tokens[:, None]
-                keep_row = jnp.where(slot_ok[:, None], 1.0,
-                                     id_fresh.astype(jnp.float32))
-                row_mask = touched01 * keep_row                 # (M, cap)
-                rescued = jnp.sum(
-                    ((~slot_ok) & (jnp.sum(row_mask, axis=1) > 0)
-                     ).astype(jnp.int32))
-                emb_cnt = jnp.sum(row_mask, axis=0)
-                # keep_row at each (slot, id) row (a row is touched); a
-                # step with no slot past iota keeps every row and reads
+                # contribute rows whose IDs were untouched since its token;
+                # a step with no slot past iota keeps every row and reads
                 # no last_update
+                slot_ok = (step_k - tokens) <= iota             # (M,)
                 factor = jax.lax.cond(
                     jnp.all(slot_ok),
                     lambda: jnp.ones(ids.shape, jnp.float32),
@@ -214,43 +189,53 @@ class GBATrainer:
                         slot_ok[:, None], 1.0,
                         (last_update[ids] <= tokens[:, None]
                          ).astype(jnp.float32)))
+                rescued = jnp.sum(
+                    ((~slot_ok) & jnp.any(factor > 0, axis=1)
+                     ).astype(jnp.int32))
             else:
-                # same denominator semantics as the GBA path: an ID's
-                # contributor count is the number of SLOTS that touched
-                # it (Alg. 2 line 23), not its occurrence count
-                emb_cnt = jnp.sum(touched01 * weights[:, None], axis=0)
                 factor = jnp.broadcast_to(weights[:, None], ids.shape)
-            return agg, factor, emb_cnt, rescued
+                rescued = jnp.int32(0)
+            return agg, factor, rescued
 
         def scatter(ids, factor, row_g, params):
             """Each sparse leaf's sum of factor x row gradient, as one
-            scatter of the leaves' rows side by side.  ``ids`` is
-            (M, B, n_ids), each leaf's rows ``ids.shape + leaf row``."""
+            scatter of the leaves' rows side by side, and each id's
+            contributor count: the sum of ``factor`` over the distinct
+            slots that touched it (Alg. 2 line 23), not its occurrence
+            count.  ``ids`` is (M, B, n_ids), each leaf's rows
+            ``ids.shape + leaf row``."""
             cols = {k: g.reshape(ids.shape + (-1,)) for k, g in row_g.items()}
+            factor = factor.reshape(ids.shape)
             rows = jnp.concatenate(list(cols.values()), axis=-1) * \
-                factor.reshape(ids.shape + (1,))
+                factor[..., None]
             if stream is None:
                 table, _ = sparse_grads_to_dense(ids, rows, cap)
+                # factor is one value a (slot, id) pair, and >= 0
+                flat = ids.reshape(m, -1)
+                emb_cnt = jnp.zeros((m, cap), jnp.float32).at[
+                    jnp.arange(m)[:, None], flat].max(
+                        factor.reshape(m, -1)).sum(axis=0)
             else:
-                table = scatter_rows(ids, rows, cap, stream=stream)
+                table, emb_cnt = scatter_rows(ids, rows, cap, factor,
+                                              stream=stream)
             out, start = {}, 0
             for k, c in cols.items():
                 width = c.shape[-1]
                 out[k] = table[:, start:start + width].reshape(
                     params[k].shape)
                 start += width
-            return out
+            return out, emb_cnt
 
         def step(src_params, params, opt_state, batches, tokens, weights,
                  step_k, last_update):
             losses, (dense_g, ids, row_g) = jax.vmap(
                 grad_fn, in_axes=in_axes)(src_params, batches)
             with jax.named_scope("aggregate"):
-                grads, factor, emb_cnt, rescued = aggregate(
-                    dense_g, batches, ids.reshape(m, -1), tokens, weights,
-                    step_k, last_update)
+                grads, factor, rescued = aggregate(
+                    dense_g, ids.reshape(m, -1), tokens, weights, step_k,
+                    last_update)
             with jax.named_scope("embedding"):
-                table_g = scatter(ids, factor, row_g, params)
+                table_g, emb_cnt = scatter(ids, factor, row_g, params)
             with jax.named_scope("aggregate"):
                 # divide by #slots that touched the ID (Alg. 2 line 23);
                 # baselines divide by the same rule for parity

@@ -89,7 +89,7 @@ def _pooled_bag_fwd(table, hashed_ids, meta):
 def _pooled_bag_bwd(meta, hashed_ids, g):
     # VJP of sum-pool = unnormalized scatter-add of g rows; the per-ID
     # counts the kernel co-produces belong to Alg. 2's aggregation rule,
-    # not to autodiff — they are recomputed where needed (presence_counts)
+    # not to autodiff, and are dropped here
     s = meta.stream
     gtable, _ = ops.pooled_lookup_grad(
         hashed_ids, g.astype(jnp.float32), meta.capacity, block_v=s.block_v,
@@ -128,16 +128,19 @@ def presence_counts(hashed_ids: jax.Array, capacity: int, *,
     return counts
 
 
-def scatter_rows(ids: jax.Array, rows: jax.Array, capacity: int, *,
-                 stream: StreamConfig | None = None) -> jax.Array:
+def scatter_rows(ids: jax.Array, rows: jax.Array, capacity: int,
+                 counts: jax.Array | None = None, *,
+                 stream: StreamConfig | None = None):
     """Each id's sum of the rows sent to it: ids (...) int32, rows
     ``ids.shape + (D,)`` -> (capacity, D), through the streamed
     sorted-scatter kernel: one sort, O(block) VMEM at any capacity, work
     that grows with the ids and not with any per-row copy of the
-    table."""
+    table.  With ``counts`` (shaped like ``ids``, one value a (leading
+    index, id) pair), also each id's sum of ``counts`` over its distinct
+    pairs: ``(table, per_id)``."""
     s = stream or StreamConfig()
     return embedding_bag.scatter_rows(
-        ids, rows, capacity, block_v=s.block_v, chunk_e=s.chunk_e,
+        ids, rows, capacity, counts, block_v=s.block_v, chunk_e=s.chunk_e,
         interpret=s.interpret)
 
 

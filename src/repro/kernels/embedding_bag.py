@@ -32,14 +32,16 @@ vocabulary size ``V`` and the entry count ``E = B*F``.
   ``(BLOCK_V, W) @ (W, BLOCK_D)``; per-ID contributor counts (Alg. 2
   line 23) fall out of the same one-hot mask.
 
-* row scatter (``scatter_rows``): the backward with one row an id and
-  no counts, D-major in and out: the sorted rows stream as ``(D, W)``
+* row scatter (``scatter_rows``): the backward with one row an id,
+  D-major in and out: the sorted rows stream as ``(D, W)``
   windows, entries on lanes as the ids travel, each folded in as
   ``rows_t @ onehot^T`` in three one-pass bfloat16 products, into the
   ``(D, V)`` gradient; a narrow table pads D to 16 sublanes, not 128
   lanes.  A trainer that differentiates by gathered rows sums a step's
   rows into the table gradient with it, under a jitted entry of its own
-  name.
+  name, and counts each id's contributing slots in the same pass: a
+  per-entry count rides as one more row column, kept at the first entry
+  of each (slot, id) pair of the sorted stream and zeroed at the rest.
 
 TPU layout rules shape the streams.  The TPU DMAs HBM in whole 128-lane
 tiles, so ids travel lane-major (``(1, E)`` / ``(2, E)`` rows) and each
@@ -611,8 +613,8 @@ def _scatter_rows_kernel(offsets_ref, ids_hbm, rows_t_hbm, gtable_t_ref,
 
 
 # A compiled program names each kernel call after the jitted entry that
-# made it, so the pooled backward (and the presence counts) and the row
-# scatter stand apart in a trace.
+# made it, so the pooled backward and the row scatter stand apart in a
+# trace.
 @functools.partial(
     jax.jit,
     static_argnames=("capacity", "block_v", "block_d", "chunk_e",
@@ -654,18 +656,33 @@ def _embedding_bag_grad_streamed(ids: jax.Array, grad_out: jax.Array,
 @functools.partial(
     jax.jit, static_argnames=("capacity", "block_v", "chunk_e", "interpret"))
 def _scatter_rows_streamed(ids: jax.Array, rows: jax.Array, capacity: int,
-                           *, block_v: int, chunk_e: int, interpret: bool
-                           ) -> jax.Array:
+                           counts: jax.Array | None = None, *, block_v: int,
+                           chunk_e: int, interpret: bool):
     d = rows.shape[-1]
+    e = ids.size
     sorted_ids, order, offsets, cap_pad, nvb = _sorted_entries(
         ids, capacity, block_v, chunk_e)
+    if counts is not None:
+        # the counts ride as one more row column, in the same gather
+        rows = jnp.concatenate(
+            [rows.astype(jnp.float32),
+             counts.astype(jnp.float32).reshape(ids.shape + (1,))], axis=-1)
     # gathered where they lie, with no flattening copy, and turned
     # D-major: the entries on lanes, as the ids travel
     rows_t = rows[jnp.unravel_index(order, ids.shape)].astype(jnp.float32).T
-    meta = scatter_rows_launch_meta(ids.size, capacity, d, block_v=block_v,
-                                    chunk_e=chunk_e)
+    if counts is not None:
+        # one count a distinct (leading index, id) pair: the sort is
+        # stable, so a pair's entries lie side by side, and all but the
+        # first are zeroed
+        lead = order // (e // ids.shape[0])
+        s = sorted_ids[:e]
+        first = jnp.concatenate([jnp.ones((1,), bool),
+                                 (s[1:] != s[:-1]) | (lead[1:] != lead[:-1])])
+        rows_t = rows_t.at[d].set(jnp.where(first, rows_t[d], 0.0))
+    meta = scatter_rows_launch_meta(e, capacity, rows_t.shape[0],
+                                    block_v=block_v, chunk_e=chunk_e)
     d_pad = meta.inputs[1].array_shape[0]
-    rows_t = jnp.pad(rows_t, ((0, d_pad - d),
+    rows_t = jnp.pad(rows_t, ((0, d_pad - rows_t.shape[0]),
                               (0, sorted_ids.shape[0] - rows_t.shape[1])))
     (gtable_t,) = pl.pallas_call(
         functools.partial(_scatter_rows_kernel, block_v=block_v,
@@ -684,19 +701,29 @@ def _scatter_rows_streamed(ids: jax.Array, rows: jax.Array, capacity: int,
                    for o in meta.outputs],
         interpret=interpret,
     )(offsets, sorted_ids.reshape(1, -1), rows_t)
-    return gtable_t[:d, :capacity].T
+    table = gtable_t[:d, :capacity].T
+    if counts is None:
+        return table
+    return table, gtable_t[d, :capacity]
 
 
-def scatter_rows(ids: jax.Array, rows: jax.Array, capacity: int, *,
+def scatter_rows(ids: jax.Array, rows: jax.Array, capacity: int,
+                 counts: jax.Array | None = None, *,
                  block_v: int | None = None, chunk_e: int | None = None,
-                 interpret: bool | None = None) -> jax.Array:
+                 interpret: bool | None = None):
     """Sum rows into a table: ids (...), rows ``ids.shape + (D,)`` ->
     (capacity, D), each row the sum of the rows whose id it is.
+
+    With ``counts`` (shaped like ``ids``), returns ``(table, per_id)``:
+    ``per_id[v]`` sums ``counts`` over the distinct (leading index of
+    ``ids``, v) pairs, one entry a pair, so a pair's ``counts`` must be
+    the same at all its entries.  The counts are summed exactly as one
+    more row column.
 
     The backward's sort and streamed segment reduce with one row an id,
     D-major throughout.  Out-of-range ids are dropped."""
     return _scatter_rows_streamed(
-        ids, rows, capacity, block_v=block_v or BLOCK_V,
+        ids, rows, capacity, counts, block_v=block_v or BLOCK_V,
         chunk_e=chunk_e or CHUNK_E, interpret=runtime.resolve(interpret))
 
 

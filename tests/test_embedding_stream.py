@@ -1,6 +1,8 @@
 """DMA-streamed embedding kernels: parity at V >> BLOCK_V, block-boundary
 edge cases, bit-exactness vs the PR-1 VMEM-resident backward, the
 differentiable table-level wrapper, and the interpret-mode resolution."""
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -177,6 +179,70 @@ def test_scatter_rows_moves_rows_exactly():
     rows = jax.random.normal(jax.random.fold_in(key, 1), (1000, d)) * 1e3
     got = scatter_rows(ids, rows, v)
     assert np.array_equal(np.asarray(got[ids]), np.asarray(rows))
+
+
+def _distinct_pair_sums(ids: np.ndarray, counts: np.ndarray,
+                        v: int) -> np.ndarray:
+    """Each in-range id's sum of ``counts`` over its distinct (leading
+    index, id) pairs, one entry a pair."""
+    lead = ids.reshape(ids.shape[0], -1)
+    cnt = counts.reshape(ids.shape[0], -1)
+    out = np.zeros(v, np.float64)
+    for slot_ids, slot_cnt in zip(lead, cnt):
+        pairs = {}
+        for i, c in zip(slot_ids.tolist(), slot_cnt.tolist()):
+            if 0 <= i < v:
+                pairs.setdefault(i, c)
+        for i, c in pairs.items():
+            out[i] += c
+    return out
+
+
+def _pair_counts_case(case: str):
+    """``(ids, counts, v, d)``: ids led by their slot, counts one value a
+    (slot, id) pair."""
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    values = np.array([1.0])
+    if case == "repeat_in_slot":     # few ids, so each repeats in a slot
+        v, d, ids = 300, 11, rng.integers(0, 20, (3, 40))
+    elif case == "across_slots":     # ids 5 and 7 in every slot
+        v, d, ids = 300, 18, rng.integers(0, 300, (8, 10))
+        ids[:, :2] = (5, 7)
+    elif case == "zero_fractional":
+        v, d, ids = 300, 11, rng.integers(0, 50, (6, 30))
+        values = np.array([0.0, 0.25, 0.5, 1.0, 2.75])
+    elif case == "block_boundary":   # ids on both sides of two boundaries
+        v, d = 3 * BLOCK_V, 18
+        ids = rng.choice([BLOCK_V - 2, BLOCK_V - 1, BLOCK_V, BLOCK_V + 1,
+                          2 * BLOCK_V - 1, 2 * BLOCK_V], (4, 64))
+        values = np.array([0.0, 0.5, 1.0])
+    elif case == "out_of_range":     # dropped, as their rows are
+        v, d, ids = 200, 11, rng.integers(-3, 203, (5, 50))
+    else:                            # (M, B, n_ids), as a step holds them
+        v, d, ids = 700, 18, rng.integers(0, 100, (4, 8, 26))
+        values = np.array([0.0, 1.0])
+    pair_value = rng.choice(values, (ids.shape[0], v + 6))
+    slot = np.arange(ids.shape[0]).reshape((-1,) + (1,) * (ids.ndim - 1))
+    counts = pair_value[slot, ids + 3]
+    return ids.astype(np.int32), counts.astype(np.float32), v, d
+
+
+@pytest.mark.parametrize("case", ["repeat_in_slot", "across_slots",
+                                  "zero_fractional", "block_boundary",
+                                  "out_of_range", "step_shape"])
+def test_scatter_rows_counts_each_distinct_pair_once(case):
+    """With ``counts``, each id's second output is the exact sum of
+    ``counts`` over the distinct (leading index, id) pairs, one entry a
+    pair and out-of-range ids dropped; the table is bit for bit the one
+    scattered without ``counts``."""
+    ids, counts, v, d = _pair_counts_case(case)
+    rows = jax.random.normal(jax.random.PRNGKey(v), ids.shape + (d,))
+    table, per_id = scatter_rows(ids, rows, v, counts)
+    assert per_id.shape == (v,) and per_id.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(per_id, np.float64),
+                                  _distinct_pair_sums(ids, counts, v))
+    assert np.array_equal(np.asarray(table),
+                          np.asarray(scatter_rows(ids, rows, v)))
 
 
 def test_streamed_grad_parity_1m_vocab():

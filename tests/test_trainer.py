@@ -106,21 +106,31 @@ def test_history_ring_clamps_counted():
     assert stats.history_clamps >= 1
 
 
-def test_streamed_presence_counts_match_default_path():
-    """GBATrainer(embed_stream=...) routes the per-slot presence counts
-    through the DMA-streamed sorted-scatter kernel; the replayed parameters
-    must match the XLA one-hot-scatter path exactly (same counts, same
-    masks, same updates)."""
+# one step in two has a zero-weight slot, whose ids the count leaves out
+SYNC_ZERO_WEIGHT = [[Slot(k * 3 + i, k, k, 0.0 if (i, k % 2) == (1, 1)
+                          else 1.0) for i in range(3)] for k in range(4)]
+
+
+@pytest.mark.parametrize("mode", ["gba", "sync"])
+def test_streamed_presence_counts_match_default_path(mode):
+    """GBATrainer(embed_stream=...) counts each id's contributing slots in
+    the streamed row scatter's own sort; the XLA path counts them with a
+    per-slot scatter.  ``last_update`` and the rescued rows must match
+    exactly, the replayed parameters up to the order of the float32 row
+    sums."""
     from repro.embeddings import StreamConfig
 
     cfg = dataclasses.replace(CRITEO_DEEPFM, name="criteo-deepfm-tiny",
                               hash_capacity=2048, mlp_dims=(32, 16))
     stream = make_clickstream(cfg, seed=0, batches_per_day=16, batch_size=32)
     opt = get_optimizer("sgd", 0.05)
-    # a schedule with real staleness so the per-ID relaxation path runs
-    steps = [[Slot(k * 3 + i, max(0, k - i), k, 1.0 if i < 2 else 0.0)
-              for i in range(3)] for k in range(4)]
-    sched = Schedule("gba", 32, steps)
+    if mode == "gba":
+        # real staleness, so the per-ID relaxation path runs
+        steps = [[Slot(k * 3 + i, max(0, k - i), k, 1.0 if i < 2 else 0.0)
+                  for i in range(3)] for k in range(4)]
+    else:
+        steps = SYNC_ZERO_WEIGHT
+    sched = Schedule(mode, 32, steps)
 
     def run(embed_stream):
         params = init_recsys(jax.random.PRNGKey(2), cfg)
@@ -132,6 +142,7 @@ def test_streamed_presence_counts_match_default_path():
     p1, lu1, st1 = run(None)
     p2, lu2, st2 = run(StreamConfig())
     assert st1.embed_rows_rescued == st2.embed_rows_rescued
+    assert (st1.embed_rows_rescued > 0) == (mode == "gba")
     np.testing.assert_array_equal(np.asarray(lu1), np.asarray(lu2))
     for (path, a), (_, b) in zip(
             jax.tree_util.tree_leaves_with_path(p1),
@@ -344,6 +355,57 @@ def test_row_scatter_matches_per_slot_table_gradients(model, mode,
         assert st1.embed_rows_rescued > 0
     assert st1.scattered_rows == sum(
         len(s) for s in sched.steps) * ROW_BATCH * R.ids_per_example(cfg)
+
+
+@pytest.mark.parametrize("embed_stream", [None, "interpret"])
+def test_rescued_from_factor_matches_slot_table_masks(embed_stream):
+    """The rows a slot past iota keeps, counted from its (slot, id)
+    factors, equal the count of the ``(M, cap)`` masks they replaced,
+    step by step, on a schedule whose stale slots hold both ids untouched
+    since their token and ids touched since."""
+    from repro.embeddings import StreamConfig
+
+    cfg = ROW_CFGS["deepfm"]
+    cap = cfg.hash_capacity
+    stream = make_clickstream(cfg, seed=0, batches_per_day=16,
+                              batch_size=ROW_BATCH)
+    trainer = GBATrainer(cfg, get_optimizer("sgd", 0.05), iota=1,
+                         embed_stream=(StreamConfig(interpret=True)
+                                       if embed_stream else None))
+    calls = []
+    make_step = trainer._make_step
+
+    def recording(gba, m, shared_src):
+        fn = make_step(gba, m, shared_src)
+
+        def step(*args):
+            out = fn(*args)
+            calls.append((args[3], args[4], args[6], args[7], out[4]))
+            return out
+        return step
+
+    trainer._make_step = recording
+    params = init_recsys(jax.random.PRNGKey(5), cfg)
+    trainer.replay(params, trainer.optimizer.init(params),
+                   Schedule("gba", ROW_BATCH, ROW_STEPS["gba"]), stream,
+                   day=0)
+
+    mixed = 0
+    for batches, tokens, step_k, last_update, rescued in calls:
+        ids = np.asarray(jax.vmap(R.flat_ids)(batches)).reshape(
+            len(tokens), -1)
+        tokens, last_update = np.asarray(tokens), np.asarray(last_update)
+        touched = np.zeros((len(tokens), cap), bool)
+        touched[np.arange(len(tokens))[:, None], ids] = True
+        slot_ok = (int(step_k) - tokens) <= trainer.iota
+        keep_row = np.where(slot_ok[:, None], True,
+                            last_update[None, :] <= tokens[:, None])
+        row_mask = touched & keep_row
+        assert int(rescued) == int(np.sum(~slot_ok & row_mask.any(axis=1)))
+        for i in np.flatnonzero(~slot_ok):
+            fresh = last_update[ids[i]] <= tokens[i]
+            mixed += bool(fresh.any() and not fresh.all())
+    assert mixed > 0
 
 
 def _f32_result_dims(hlo: str) -> set[tuple[int, ...]]:
